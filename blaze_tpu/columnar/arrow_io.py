@@ -151,8 +151,7 @@ def _numeric_zero_copy(arr, dtype: T.DataType, cap: int) -> Optional[Column]:
                          offset=arr.offset * itemsize)
     if cap > n:
         # pad on HOST: one upload DMA total. Padding on device costs an
-        # eager scatter dispatch per column — ~250ms each on a
-        # remote-attached chip vs ~mms for the host memcpy.
+        # eager scatter dispatch per column; the host memcpy is cheaper.
         full = np.zeros((cap,), dtype.np_dtype())
         full[:n] = view
         view = full

@@ -45,6 +45,22 @@ def bucket_capacity(n: int) -> int:
     return cap
 
 
+def nonzero_i32(mask: Array, size: int, fill_value: int = 0) -> Array:
+    """`jnp.nonzero(mask, size=size, fill_value=fill_value)[0]` in 32-bit
+    arithmetic: int32 (size,) positions of the True entries in order,
+    padded with `fill_value`, truncated past `size`.
+
+    jnp.nonzero runs two cumsums and a div/mod in the default int, which
+    under x64 is int64 — emulated on TPU, where that program took 56 s to
+    compile at 2^21 rows against 11 s for this form (one reading, PR 21
+    chip run). One int32 cumsum ranks the True rows; one scatter places
+    their positions (False rows and overflow aim past the end and drop)."""
+    pos = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    out = jnp.full((size,), fill_value, jnp.int32)
+    return out.at[jnp.where(mask, pos, size)].set(
+        jnp.arange(mask.shape[0], dtype=jnp.int32), mode="drop")
+
+
 def bucket_width(w: int) -> int:
     """Round string byte-width up to a power-of-two bucket (min 4).
 
@@ -373,9 +389,7 @@ class ColumnBatch:
         """
         mask = keep & self.row_mask()
         n = jnp.sum(mask, dtype=jnp.int32)
-        (idx,) = jnp.nonzero(mask, size=self.capacity, fill_value=0)
-        out = self.take(idx, n)
-        return out
+        return self.take(nonzero_i32(mask, self.capacity), n)
 
     def normalized(self) -> "ColumnBatch":
         return self.with_columns(self.schema, [c.normalized() for c in self.columns])

@@ -40,33 +40,7 @@ import time
 from typing import BinaryIO, Iterator, List, Optional
 
 import numpy as np
-
-try:
-    import zstandard
-except ModuleNotFoundError:  # pragma: no cover - environment-dependent
-    # zlib-backed shim with the same API surface so the engine's framing
-    # (shuffle/spill/broadcast) still runs where the zstd wheel is
-    # absent. Frames are NOT zstd-interoperable in this mode: every
-    # process of a cluster must agree on the codec, which holds because
-    # the fallback only engages when the wheel is missing machine-wide.
-    import zlib as _zlib
-
-    class _ZlibCompressor:
-        def __init__(self, level=1, **_kw):
-            self.level = min(max(int(level), 1), 9)
-
-        def compress(self, raw):
-            return _zlib.compress(raw, self.level)
-
-    class _ZlibDecompressor:
-        def decompress(self, comp, max_output_size=0):
-            return _zlib.decompress(comp)
-
-    class _ZstdShim:
-        ZstdCompressor = _ZlibCompressor
-        ZstdDecompressor = _ZlibDecompressor
-
-    zstandard = _ZstdShim()
+import zstandard
 
 from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
 from blaze_tpu.columnar.types import Schema, TypeKind

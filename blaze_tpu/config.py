@@ -125,18 +125,19 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     Knob("max_string_width", 4096,
          doc="Cap on the bucketed fixed string width."),
     Knob("memory_budget", 0,
-         doc="HBM budget for MemManager in bytes; 0 = derive from device "
-             "memory stats."),
+         doc="HBM budget for MemManager in bytes; 0 = 0.7 x the device's "
+             "memory_stats() bytes_limit (1 GiB on the CPU test "
+             "platform)."),
     Knob("spill_dir", "/tmp/blaze_tpu_spill", env="BLAZE_TPU_SPILL_DIR",
          doc="Directory for host spill files (MemManager/SpillFile)."),
     Knob("zstd_level", 1,
-         doc="Compression level for shuffle/spill/broadcast frames (ref "
-             "uses zstd level 1; this build's frame codec is zlib at the "
-             "same level knob)."),
+         doc="zstd compression level for shuffle/spill/broadcast frames "
+             "(ref uses level 1)."),
     Knob("enable_stage_compiler", True,
          doc="Whole-stage single-dispatch compiler "
-             "(runtime/stage_compiler.py): amortizes the ~90ms-per-"
-             "dispatch cost of remote-attached TPUs."),
+             "(runtime/stage_compiler.py): one dispatch and one result "
+             "pull per stage instead of several host round trips per "
+             "batch."),
     Knob("dense_agg_range", 1 << 16,
          doc="Dense grouped-agg key range for the MXU one-hot path "
              "(<= 2^16: 256x256 byte decomposition); stages whose keys "

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+import threading
 from typing import List, Optional
 
 import numpy as np
@@ -76,19 +77,11 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.bn_init.restype = ctypes.c_int
     lib.bn_init.argtypes = [ctypes.c_int64]
     lib.bn_last_error.restype = ctypes.c_char_p
-    try:  # older .so builds predate the category symbol
-        lib.bn_last_error_category.restype = ctypes.c_int
-        lib.bn_last_error_category.argtypes = []
-    except AttributeError:
-        pass
-    try:  # older .so builds predate the kill-flag symbols
-        for kname in ("bn_request_kill", "bn_clear_kill",
-                      "bn_kill_requested"):
-            kfn = getattr(lib, kname)
-            kfn.restype = ctypes.c_int
-            kfn.argtypes = []
-    except AttributeError:
-        pass
+    for kname in ("bn_last_error_category", "bn_request_kill",
+                  "bn_clear_kill", "bn_kill_requested"):
+        kfn = getattr(lib, kname)
+        kfn.restype = ctypes.c_int
+        kfn.argtypes = []
     lib.bn_free_buffer.argtypes = [ctypes.POINTER(ctypes.c_uint8)]
     for name, argtypes in [
         ("bn_hash_i32", [ctypes.c_void_p] * 2 + [ctypes.c_int64,
@@ -114,41 +107,24 @@ def available() -> bool:
 
 def last_error_category() -> int:
     """bn_last_error_category wire code for this thread's last native
-    failure (0 when the loaded .so predates the symbol)."""
-    lib = _load()
-    try:
-        return int(lib.bn_last_error_category())
-    except AttributeError:
-        return 0
+    failure."""
+    return int(_load().bn_last_error_category())
 
 
 def request_kill() -> None:
     """bn_request_kill: cooperatively cancel running native tasks (the
-    C-ABI mirror of the supervisor's per-attempt kill flag). No-op when
-    the loaded .so predates the symbol."""
-    lib = _load()
-    try:
-        lib.bn_request_kill()
-    except AttributeError:
-        pass
+    C-ABI mirror of the supervisor's per-attempt kill flag)."""
+    _load().bn_request_kill()
 
 
 def clear_kill() -> None:
     """bn_clear_kill: re-arm after a kill so the next task may run."""
-    lib = _load()
-    try:
-        lib.bn_clear_kill()
-    except AttributeError:
-        pass
+    _load().bn_clear_kill()
 
 
 def kill_requested() -> bool:
     """bn_kill_requested: whether the native kill flag is set."""
-    lib = _load()
-    try:
-        return int(lib.bn_kill_requested()) > 0
-    except AttributeError:
-        return False
+    return int(_load().bn_kill_requested()) > 0
 
 
 def _native_error(what: str, rc: int) -> Exception:
@@ -321,33 +297,45 @@ class NativeShuffleWriter:
         self.P = num_partitions
         self._w = self._lib.bn_shuffle_new(num_partitions,
                                            spill_dir.encode(), mem_budget)
+        # MemManager.mem_used() walks a SNAPSHOT of every task's consumers
+        # from whichever thread is growing: it can reach this writer while
+        # its owner closes it — C must never see the freed/NULL handle
+        self._handle_lock = threading.Lock()
 
     def push(self, partition: int, frame: bytes) -> None:
-        rc = self._lib.bn_shuffle_push(self._w, partition, frame,
-                                       len(frame))
+        with self._handle_lock:
+            rc = self._lib.bn_shuffle_push(self._w, partition, frame,
+                                           len(frame))
         if rc != 0:
             raise _native_error("bn_shuffle_push", rc)
 
     def mem_used(self) -> int:
-        return self._lib.bn_shuffle_mem_used(self._w)
+        with self._handle_lock:
+            return self._lib.bn_shuffle_mem_used(self._w) if self._w else 0
 
     def spill(self) -> None:
-        rc = self._lib.bn_shuffle_spill(self._w)
+        # host-driven release() may call this from another task's thread
+        with self._handle_lock:
+            rc = self._lib.bn_shuffle_spill(self._w) if self._w else 0
         if rc != 0:
             raise _native_error("bn_shuffle_spill", rc)
 
     def commit(self, data_path: str, index_path: str) -> List[int]:
         lengths = (ctypes.c_int64 * self.P)()
-        rc = self._lib.bn_shuffle_commit(self._w, data_path.encode(),
+        with self._handle_lock:
+            w = self._w
+        # owner-thread only, and long: not held across the file writes
+        rc = self._lib.bn_shuffle_commit(w, data_path.encode(),
                                          index_path.encode(), lengths)
         if rc != 0:
             raise _native_error("bn_shuffle_commit", rc)
         return list(lengths)
 
     def close(self) -> None:
-        if self._w:
-            self._lib.bn_shuffle_free(self._w)
-            self._w = None
+        with self._handle_lock:
+            if self._w:
+                self._lib.bn_shuffle_free(self._w)
+                self._w = None
 
     def __del__(self):
         try:

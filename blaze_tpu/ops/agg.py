@@ -30,7 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from blaze_tpu.columnar import types as T
-from blaze_tpu.columnar.batch import Column, ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    Column, ColumnBatch, bucket_capacity, nonzero_i32,
+)
 from blaze_tpu.columnar.types import DataType, Field, Schema, TypeKind
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
@@ -627,8 +629,7 @@ class AggExec(Operator):
         perm, sgid = sorted_ops[-1], sorted_ops[0]
         starts = jnp.concatenate([
             jnp.ones((1,), jnp.bool_), sgid[1:] != sgid[:-1]])
-        (gstart,) = jnp.nonzero(starts & (sgid < 2**30), size=cap,
-                                fill_value=0)
+        gstart = nonzero_i32(starts & (sgid < 2**30), cap)
         row_idx = perm[jnp.clip(gstart, 0, cap - 1)]
         picked = x.take(jnp.clip(row_idx, 0, cap - 1))
         has = _seg_any(x.valid_mask() & layout.row_mask, layout)

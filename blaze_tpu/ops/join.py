@@ -38,7 +38,9 @@ import jax
 import jax.numpy as jnp
 
 from blaze_tpu.columnar import types as T
-from blaze_tpu.columnar.batch import Column, ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    Column, ColumnBatch, bucket_capacity, nonzero_i32,
+)
 from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
@@ -198,7 +200,7 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
 
     csum_b = jnp.cumsum(is_build.astype(jnp.int32))
     csum_p = jnp.cumsum(is_probe.astype(jnp.int32))
-    (run_start_idx,) = jnp.nonzero(starts, size=cap, fill_value=cap - 1)
+    run_start_idx = nonzero_i32(starts, cap, fill_value=cap - 1)
     zb = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_b])
     zp = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_p])
     # per-run: build rows before the run, and totals in run
@@ -903,7 +905,7 @@ def _any_by_index(idx: Array, flag: Array, out_size: int) -> Array:
     run_any = seg.segmented_scan(sf, starts, lambda a, b: a | b)
     is_last = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones((1,), jnp.bool_)])
     # map run results back: gather via sorted compaction of (key,last,any)
-    (last_pos,) = jnp.nonzero(is_last, size=out_size, fill_value=0)
+    last_pos = nonzero_i32(is_last, out_size)
     keys_at = sk[last_pos]
     any_at = run_any[last_pos]
     # scatter-free dense build: out[keys_at[r]] = any_at[r]; keys_at sorted

@@ -1,8 +1,8 @@
 """Dense-key grouped aggregation on the MXU (one-hot matmul accumulate).
 
 The sort-based agg path (ops/agg.py) is general but leans on `lax.sort` and
-scatters — both weak primitives on TPU (a 2M-row sort is ~100ms; a 2M-row
-scatter ~250ms). When the grouping key is integral with a bounded range —
+scatters — both weak primitives on TPU (observed before this round: a
+2M-row sort ~100ms, a 2M-row scatter ~250ms). When the grouping key is integral with a bounded range —
 the common TPC-DS shape: surrogate keys like ss_item_sk — grouped sums and
 counts become ONE-HOT MATMULS: decompose key k into (hi, lo) parts, then
 
@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from blaze_tpu.runtime import compile_service
+
 Array = jax.Array
 
 CHUNK_BITS = 8
@@ -88,34 +90,41 @@ _BIAS8 = np.uint64(128 * ((1 << 64) - 1) // 255)    # 8-chunk (i64 path)
 _I32_EXACT_ROWS = 1 << 23   # 127 * 2^23 < 2^31: s32 block-exactness bound
 
 
-def _pick_tile(n: int, gh: int, pgl: int):
-    """Largest T whose kernel fits the scoped-vmem stack.
+_SCOPED_VMEM = 16 << 20     # Mosaic's default scoped-vmem stack, bytes
+_MAX_PLANES = 64            # beyond this nothing was ever compiled
 
-    Calibrated on-chip against the TRANSPOSED kernel. Two resident
-    terms: the s32 accumulator+output (2*gh*pgl*4 — independent of T)
-    and the per-tile operands (~T*(pgl+gh) bytes). Measured envelope:
-    P=7/16 @ T=4096, P=24 @ T=2048, P=29 @ T=1024 all compile; P=29 @
-    T=2048 and P=33 @ any T fail — i.e. accumulator alone must stay
-    <= ~16M and the combined total <= ~20M. T floors at 1024 (the
-    smaller-tile regime is untested-territory that ALSO failed at
-    P=29/T=512); T=4096 measured fastest where it fits.
+
+def _pick_tile(n: int, gh: int, pgl: int):
+    """Largest T whose kernel fits the scoped-vmem stack, or None.
+
+    Two resident terms: the s32 accumulator + output block
+    (2*gh*pgl*4 bytes, independent of T) and the per-tile operands.
+    Calibrated on a v5e with jax 0.9.0 / libtpu 0.0.34 (PR 21 chip runs)
+    from the allocation sizes Mosaic reports when it refuses a kernel,
+    n = 2^21: at gh = 512 the per-tile term measured 1290-1450 bytes per
+    tile row (1536 bounds it: P <= 20 @ T=4096, 26 @ 2048, 29 @ 1024
+    compile; 24 @ 4096, 27 @ 2048, 30 @ 1024 are refused), at gh = 256 it
+    measured 860-975 (P = 52 @ 4096, 58 @ 2048, 61 @ 1024 refused; 40 @
+    4096 compiles), at gh <= 64 everything up to P = 64 @ T=8192
+    compiles. 2*gh + 512 bytes per tile row is the line through those
+    two bounds (1536 at gh = 512, 1024 at gh = 256). Everything this
+    admits must compile —
+    chip_smoke.py compiles and checks the edges of this envelope and
+    there is no fallback behind it, so re-calibrate here when a compiler
+    update moves them. T = 4096 was the fastest tile where it fits, and
+    T floors at 1024 (observed before this round).
 
     A double-buffered producer/consumer split (build tile i+1's operands
-    while tile i's dot runs — PROFILE_r04 remaining-headroom item) was
-    built and MEASURED SLOWER in round 5: the extra scratch pushes
-    T=4096 past the 16M scoped-vmem limit (16.62M), and at T=2048 the
-    pipelined kernel ran 7.5ms vs the serial kernel's 5.4ms per 2^21-row
-    batch (P=7). The serial kernel already runs at ~91% of the s8 matmul
-    roofline (5.4ms vs 4.9ms floor = 2*n*R*P / 394 TOPS) — round 4's
-    "19% MXU" figure divided by a mistaken 80ms/rep floor; the correct
-    floor for 64 batches at P=7 is ~313ms/rep."""
-    acc2 = 2 * gh * pgl * 4
-    if acc2 > 16 << 20:
+    while tile i's dot runs) was built and measured slower before this
+    round: the extra scratch pushed T=4096 past the scoped-vmem limit,
+    and at T=2048 the pipelined kernel ran 7.5ms vs the serial kernel's
+    5.4ms per 2^21-row batch (P=7), which is ~91% of the s8 matmul
+    roofline (4.9ms = 2*n*R*P / 394 TOPS)."""
+    if pgl > _MAX_PLANES * _GL:
         return None
+    acc2 = 2 * gh * pgl * 4
     for T in (4096, 2048, 1024):
-        if n % T:
-            continue
-        if acc2 + T * (pgl + gh) <= 20 << 20:
+        if n % T == 0 and acc2 + T * (2 * gh + 512) <= _SCOPED_VMEM:
             return T
     return None
 
@@ -276,7 +285,11 @@ def _accumulate_planes(keys: Array, valid: Array, words, recipe, gh: int,
     P = len(recipe)
     ok = valid & (keys >= 0) & (keys < rng)
     kc = jnp.clip(keys, 0, rng - 1).astype(jnp.int32)
-    if _use_pallas(n, gh, P * _GL):
+    pallas = _use_pallas(n, gh, P * _GL)
+    # trace-time tally of which formulation this program got: a chip run
+    # must be able to tell the kernel from its portable stand-in
+    compile_service.note_agg_trace(pallas)
+    if pallas:
         acc = _pallas_accumulate(kc, ok.astype(jnp.int32), words, recipe,
                                  gh)
     else:

@@ -531,9 +531,9 @@ class IpcReaderExec(Operator):
                                        name="shuffle_read")
             # host-level coalescing: serialized frames decode to numpy and
             # accumulate toward the macro-batch byte target, then upload
-            # ONCE — a per-frame upload+dispatch costs a fixed ~90ms
-            # round trip each on a remote-attached chip. Device-resident
-            # items (the mesh exchange path) pass through unchanged.
+            # ONCE — a per-frame upload+dispatch is a host round trip
+            # each. Device-resident items (the mesh exchange path) pass
+            # through unchanged.
             hsup = host_sort.host_supported(self._schema)
             target = adaptive_target_bytes()
             pending: list = []

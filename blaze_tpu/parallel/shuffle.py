@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from blaze_tpu.columnar.batch import Column, ColumnBatch, StringData
+from blaze_tpu.columnar.batch import (
+    Column, ColumnBatch, StringData, nonzero_i32,
+)
 from blaze_tpu.exprs.hash import SPARK_SHUFFLE_SEED, hash_columns, pmod
 
 Array = jax.Array
@@ -117,8 +119,7 @@ def staged_all_to_all(batch: ColumnBatch, pid: Array, axis_name: str,
     # compact live rows to the front (padding content is garbage otherwise)
     mask = recv_valid
     n = jnp.sum(mask, dtype=jnp.int32)
-    (idx,) = jnp.nonzero(mask, size=P * quota, fill_value=0)
-    out = received.take(idx, n)
+    out = received.take(nonzero_i32(mask, P * quota), n)
     total_overflow = lax.psum(overflow, axis_name)
     return out, total_overflow
 
@@ -155,10 +156,7 @@ def mesh_shuffle_batch_grouped(batch: ColumnBatch,
     """
     P, k = num_partitions, parts_per_device
     pid = partition_ids(batch, key_indices, P)
-    # lax.axis_size is newer-jax only; psum of a literal 1 is evaluated
-    # statically at trace time on every version, same result
-    dsize = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-             else lax.psum(1, axis_name))
+    dsize = lax.axis_size(axis_name)
     # owner device of each row; padding rows carry the sentinel group D
     owner = jnp.where(pid >= P, jnp.int32(dsize), pid // k)
     received, overflow = staged_all_to_all(batch, owner, axis_name, dsize,

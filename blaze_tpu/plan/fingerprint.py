@@ -66,15 +66,6 @@ def _digest(tokens: List[str]) -> str:
         :_HEX_CHARS]
 
 
-def _is_repeated(fd) -> bool:
-    # protobuf >= 5.x deprecates FieldDescriptor.label in favor of the
-    # is_repeated property; support both without tripping the warning
-    rep = getattr(fd, "is_repeated", None)
-    if rep is not None and not callable(rep):
-        return bool(rep)
-    return fd.label == fd.LABEL_REPEATED
-
-
 def _walk(msg, out: List[str]) -> None:
     desc = getattr(msg, "DESCRIPTOR", None)
     if desc is None:  # plain scalar (shouldn't happen at the top level)
@@ -93,12 +84,12 @@ def _walk(msg, out: List[str]) -> None:
             continue
         out.append(fd.name)
         if fd.type == fd.TYPE_MESSAGE:
-            if _is_repeated(fd):
+            if fd.is_repeated:
                 for v in val:
                     _walk(v, out)
             else:
                 _walk(val, out)
-        elif _is_repeated(fd):
+        elif fd.is_repeated:
             out.extend(str(v) for v in val)
         elif fd.name.endswith(_RESOURCE_ID_SUFFIX):
             out.append(str(val).rsplit("/", 1)[-1])

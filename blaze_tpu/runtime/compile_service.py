@@ -1,8 +1,7 @@
 """Compile service: shape canonicalization, persistent manifest, pre-warm.
 
-The engine's cold wall-clock is dominated by first-ever-shape XLA compiles
-(PROFILE_r05: 43-325s/cell cold vs <30s warm on the chip): every
-(operator, key-count, dtype-mix, capacity) combination is its own jit
+The engine's cold wall-clock is dominated by first-ever-shape XLA compiles:
+every (operator, key-count, dtype-mix, capacity) combination is its own jit
 program, and before this module nothing pre-warmed, bounded, or even
 recorded the shape population.  This subsystem owns that population
 end-to-end (the step from ad-hoc `jit_cache.get_or_compile` calls to a
@@ -35,8 +34,9 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
 
 * **Telemetry** — a process-global `MetricsSet` with
   compile_count / compile_ns / cache_hits / cache_misses /
-  canonicalization_waste_rows / stage_attempts / stage_compiled and the
-  derived whole_stage_coverage_pct, exported as an extra `MetricNode`
+  canonicalization_waste_rows / stage_attempts / stage_compiled /
+  agg_pallas_traces / agg_xla_traces and the derived
+  whole_stage_coverage_pct, exported as an extra `MetricNode`
   child by `executor.metric_tree` and as a summary line by
   `tracing.metric_report`.
 """
@@ -66,6 +66,7 @@ TELEMETRY.reset()
 _COUNTERS = (
     "compile_count", "compile_ns", "cache_hits", "cache_misses",
     "canonicalization_waste_rows", "stage_attempts", "stage_compiled",
+    "agg_pallas_traces", "agg_xla_traces",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -100,6 +101,12 @@ def note_stage_attempt() -> None:
 def note_stage_compiled() -> None:
     TELEMETRY.add("stage_compiled", 1)
     _coverage_update()
+
+
+def note_agg_trace(pallas: bool) -> None:
+    """ops/mxu_agg traced one grouped-accumulate program: the Pallas
+    kernel, or the portable XLA formulation."""
+    TELEMETRY.add("agg_pallas_traces" if pallas else "agg_xla_traces", 1)
 
 
 def telemetry_summary() -> str:
@@ -264,31 +271,25 @@ def fingerprint() -> str:
 
 
 def default_manifest_path() -> Optional[str]:
-    """Manifest lives next to the persistent XLA cache, per platform.
+    """Manifest lives in the resolved persistent-cache directory.
 
     Resolution order: BLAZE_TPU_COMPILE_MANIFEST env ("off" disables),
-    else `<configured platform cache dir>/compile_manifest.json`, else
-    (cache not configured) the would-be default platform dir so `--warm`
-    runs have a stable home even on the CPU gate.
+    else `<jax_compilation_cache_dir>/compile_manifest.json`, else (the
+    uncached CPU test platform) the checkout's `.jax_cache` so `--warm`
+    runs have a stable home there too.
     """
     env = os.environ.get("BLAZE_TPU_COMPILE_MANIFEST", "")
     if env == "off":
         return None
     if env:
         return env
+    import jax
+
     import blaze_tpu
 
-    d = getattr(blaze_tpu, "_XLA_CACHE_DIR", None)
-    if d is None:
-        base = os.environ.get("BLAZE_TPU_XLA_CACHE", "")
-        if base == "off":
-            return None
-        import jax
-
-        d = os.path.join(
-            base or os.path.expanduser("~/.cache/blaze_tpu_xla_dev"),
-            jax.default_backend())
-    return os.path.join(d, "compile_manifest.json")
+    return os.path.join(jax.config.jax_compilation_cache_dir
+                        or blaze_tpu._DEFAULT_CACHE_DIR,
+                        "compile_manifest.json")
 
 
 class ShapeRegistry:

@@ -83,7 +83,7 @@ class FaultError(RuntimeError):
 
 class RetryableError(FaultError):
     """Transient: a bounded retry with backoff is expected to succeed
-    (lost device tunnel round trip, interrupted I/O, flaky fetch)."""
+    (lost device round trip, interrupted I/O, flaky fetch)."""
 
     category = "retryable"
 

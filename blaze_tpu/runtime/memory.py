@@ -45,9 +45,32 @@ class MemConsumer:
         return 0
 
 
+# share of the device's memory the manager budgets (ref:
+# spark.blaze.memoryFraction=0.7, BASELINE.md set-up)
+_DEVICE_MEMORY_FRACTION = 0.7
+
+
+def _device_budget() -> int:
+    """conf.memory_budget=0: derive the budget from the device. The CPU
+    test platform reports no limit and keeps a fixed 1 GiB; an
+    accelerator that reports none is an error — a guessed budget makes
+    sort/agg spill and the in-HBM exchange divert to files silently."""
+    import jax
+
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return 1 << 30
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            "memory_stats()['bytes_limit']; set conf.memory_budget")
+    return int(limit * _DEVICE_MEMORY_FRACTION)
+
+
 class MemManager:
     def __init__(self, total: Optional[int] = None) -> None:
-        self.total = total or conf.memory_budget or (1 << 30)
+        self.total = total or conf.memory_budget or _device_budget()
         self._consumers: List[MemConsumer] = []
         self._lock = threading.Lock()
         # serializes consumer-STATE mutation against host-driven spills
@@ -342,20 +365,28 @@ class MemManager:
         return freed
 
 
-_global = MemManager()
+# built at first use, not at import: the default budget reads the
+# device, and importing the package must not initialize a backend
+_global: Optional[MemManager] = None
+_global_lock = threading.Lock()
 
 
 def get_manager(ctx=None) -> MemManager:
+    global _global
     if ctx is not None and getattr(ctx, "mem_manager", None) is not None:
         return ctx.mem_manager
-    return _global
+    with _global_lock:
+        if _global is None:
+            _global = MemManager()
+        return _global
 
 
 def init(total: int) -> MemManager:
     """Ref: MemManager::init(overhead x memoryFraction), exec.rs:68-71."""
     global _global
-    _global = MemManager(total)
-    return _global
+    with _global_lock:
+        _global = mgr = MemManager(total)
+    return mgr
 
 
 def close_all_quietly(closeables, what: str) -> None:

@@ -1,6 +1,6 @@
 """Bounded, memory-charged asynchronous stream pipelining.
 
-PROFILE_r05.md shows the per-task critical path is a strict serial
+Unpipelined, the per-task critical path is a strict serial
 chain — parquet decode -> h2d upload -> compute -> d2h pull ->
 serialize/compress -> shuffle write — so the device idles while the
 host does I/O and vice versa. The supervisor (runtime/supervisor.py)

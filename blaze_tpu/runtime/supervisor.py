@@ -64,7 +64,6 @@ import sys
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import Any, Callable, Dict, FrozenSet, List, Optional, Tuple
 
 from blaze_tpu import config
@@ -998,8 +997,7 @@ class Supervisor:
                     + self._ABANDON_GRACE
             try:
                 results[i] = fut.result(timeout=timeout)
-            except (TimeoutError, FutureTimeoutError):
-                # (futures.TimeoutError is a distinct class until py3.11)
+            except TimeoutError:
                 # non-cooperative hang: kill (in case it ever wakes),
                 # abandon the thread, relay as a deadline failure
                 task.cancelled = True

@@ -64,9 +64,10 @@ def run_plan(root: SparkPlan, num_partitions: int = 4,
     no overflow possible).
 
     run_info: optional dict populated with execution-path counters
-    ("mesh_stages", "file_stages", "broadcast_stages") so callers — the
-    multichip dryrun, tests — can assert WHICH transport carried each
-    exchange rather than trusting the result alone.
+    ("mesh_stages", "file_stages", "broadcast_stages", and "mesh_devices"
+    — the fewest devices any mesh exchange's output sat on) so callers — the
+    multichip dryrun, chip_smoke.py, tests — can assert WHICH transport
+    carried each exchange rather than trusting the result alone.
 
     When conf.trace_enabled, the whole run is a "query" span in the
     engine trace (runtime/trace.py) and every stage/task below inherits
@@ -442,6 +443,9 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                             shuffle_bytes[stage.stage_id] = \
                                 stats.get("bytes", 0)
                             run_info["mesh_stages"] += 1
+                            ndev = stats.get("devices", 1)
+                            run_info["mesh_devices"] = min(
+                                run_info.get("mesh_devices", ndev), ndev)
                             sp.set(transport="mesh",
                                    bytes=stats.get("bytes", 0),
                                    **monitor.stage_span_attrs(
@@ -753,7 +757,7 @@ def _pool_stage_rids(stage: Stage) -> Optional[List[str]]:
         nonlocal servable
         for fd, val in msg.ListFields():
             if fd.type == fd.TYPE_MESSAGE:
-                vals = val if _is_repeated_field(fd) else (val,)
+                vals = val if fd.is_repeated else (val,)
                 for v in vals:
                     walk(v)
             elif fd.name == "provider_resource_id":
@@ -768,14 +772,6 @@ def _pool_stage_rids(stage: Stage) -> Optional[List[str]]:
 
     walk(stage.plan)
     return rids if servable else None
-
-
-def _is_repeated_field(fd) -> bool:
-    # protobuf >= 5.x deprecates FieldDescriptor.label (plan/fingerprint)
-    rep = getattr(fd, "is_repeated", None)
-    if rep is not None and not callable(rep):
-        return bool(rep)
-    return fd.label == fd.LABEL_REPEATED
 
 
 def _run_shuffle_stage_pooled(stage: Stage, stages: List[Stage],

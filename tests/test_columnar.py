@@ -56,6 +56,24 @@ def test_compact():
     assert r["s"] == [b"r0", b"r2", b"r4", b"r6", b"r8"]
 
 
+@pytest.mark.parametrize("n,size,fill,density", [
+    (1000, 1000, 0, 0.4), (1000, 300, 7, 0.5), (64, 128, 63, 0.9),
+    (1024, 1024, 1023, 0.0), (1024, 1024, 0, 1.0)])
+def test_nonzero_i32_equals_jnp_nonzero(n, size, fill, density):
+    """The 32-bit formulation against the library one it replaces:
+    ordering, padding with fill_value, truncation past size."""
+    import jax
+    import jax.numpy as jnp
+
+    from blaze_tpu.columnar.batch import nonzero_i32
+
+    mask = jnp.asarray(np.random.default_rng(n + size).random(n) < density)
+    (want,) = jnp.nonzero(mask, size=size, fill_value=fill)
+    got = jax.jit(lambda m: nonzero_i32(m, size, fill))(mask)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_arrow_roundtrip():
     rb = pa.record_batch({
         "i": pa.array([1, None, 3], pa.int32()),

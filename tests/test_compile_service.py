@@ -26,7 +26,7 @@ SCHEMA = Schema([Field("x", INT64)])
 def _subprocess_env(tmp_path):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["BLAZE_TPU_XLA_CACHE"] = str(tmp_path / "xla")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     return env
 

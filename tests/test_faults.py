@@ -39,7 +39,7 @@ def _clean_faults():
     (OSError(errno.ECONNRESET, "reset"), "retryable"),
     (OSError(errno.EINTR, "interrupted"), "retryable"),
     (OSError(errno.ENOENT, "missing"), "fatal"),
-    (RuntimeError("UNAVAILABLE: device tunnel"), "retryable"),
+    (RuntimeError("UNAVAILABLE: device link"), "retryable"),
     (NotImplementedError("no such op"), "plan"),
     (ValueError("boom"), "fatal"),
     (KeyError("k"), "fatal"),

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from blaze_tpu.parallel.stage_exchange import _shard_map as shard_map
 
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.batch import ColumnBatch
@@ -58,7 +57,7 @@ def test_mesh_shuffle_roundtrip(rng, rows_per_dev):
                                            quota=LOCAL_CAP)
         return out.columns, out.num_rows[None], overflow[None]
 
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(P("p"), P("p")),
         out_specs=(P("p"), P("p"), P("p"))))

@@ -1,8 +1,8 @@
 """Whole-stage single-dispatch execution (runtime/stage_compiler.py) and the
 MXU dense grouped aggregation (ops/mxu_agg.py).
 
-The stage compiler exists because remote-attached TPUs pay ~90ms per
-dispatch; correctness contract: identical results to the streaming executor,
+The stage compiler exists because the streaming executor pays several
+dispatches and a host round trip per batch; correctness contract: identical results to the streaming executor,
 with range/null violations falling back to it transparently.
 """
 

@@ -16,3 +16,28 @@ def test_validator_matrix(tmp_path):
         f"{r.query}[{r.mode}]: {r.diff or ''} {r.error or ''}"
         for r in failures)
     assert not failures, msg
+
+
+def test_cell_served_by_a_fallback_fails(tmp_path, monkeypatch):
+    """An oracle-equal answer is not a PASS when run_info says the ladder,
+    a retry or the row interpreter produced it — unless the cell asked for
+    faults or forced spill."""
+    from blaze_tpu.spark import validator
+
+    real = validator.run_plan
+
+    def degraded(*a, run_info, **kw):
+        out = real(*a, run_info=run_info, **kw)
+        run_info["ladder_rung"] = 3
+        run_info["bytes_copied_fallback"] = 4096
+        return out
+
+    monkeypatch.setattr(validator, "run_plan", degraded)
+    (cell,) = run_matrix(str(tmp_path), rows=1500,
+                         queries=["q1_scan_filter_project"])
+    assert not cell.ok and "served by a fallback" in cell.diff
+    assert cell.run_info["ladder_rung"] == 3
+    (spilled,) = run_matrix(str(tmp_path), rows=1500,
+                            queries=["q1_scan_filter_project"],
+                            spill_budget=1 << 30)
+    assert spilled.ok

@@ -123,7 +123,7 @@ def main() -> int:
     env.update({
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "BLAZE_TPU_XLA_CACHE": cache,
+        "JAX_COMPILATION_CACHE_DIR": cache,
         "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
     })
     child = [sys.executable, os.path.abspath(__file__), "--child-matrix",
