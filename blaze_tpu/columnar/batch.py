@@ -405,6 +405,23 @@ class ColumnBatch:
         cached = getattr(self, "_host_numpy", None)
         if cached is not None:
             return cached
+        if not conf.trace_enabled:
+            return self._pull_numpy()
+        from blaze_tpu.runtime import memory, trace
+
+        # a d2h span; the batch run_plan returned remembers its query
+        # (the caller pulls after that query's context is popped), and
+        # its pull is the query's `final` one. bytes: np.asarray pulls a
+        # column's whole capacity, which is what batch_nbytes counts
+        qid = getattr(self, "_query_id", None)
+        with trace.context(query_id=qid), \
+                trace.span("d2h", what="to_numpy", final=qid is not None,
+                           bytes=memory.batch_nbytes(self)) as sp:
+            out = self._pull_numpy()
+            sp.set(rows=len(next(iter(out.values()), ())))
+        return out
+
+    def _pull_numpy(self) -> Dict[str, object]:
         n = int(self.num_rows)
         out: Dict[str, object] = {}
         for f, c in zip(self.schema, self.columns):
@@ -416,7 +433,7 @@ class ColumnBatch:
                     [c.data.elements],
                     jnp.asarray(int(offs[n]), jnp.int32),
                     c.data.elements.capacity)
-                elems = esub.to_numpy()["e"]
+                elems = esub._pull_numpy()["e"]
                 if f.dtype.kind == TypeKind.MAP:
                     # entries are (key, value) structs -> dict per row
                     vals = [dict(elems[offs[i]:offs[i + 1]]) if valid[i]
@@ -440,7 +457,7 @@ class ColumnBatch:
                     Schema([Field(sf.name, sf.dtype)
                             for sf in c.dtype.fields]),
                     list(c.data.children), self.num_rows, c.capacity)
-                cols = sub.to_numpy()
+                cols = sub._pull_numpy()
                 vals = [tuple(cols[sf.name][i] for sf in c.dtype.fields)
                         if valid[i] else None for i in range(n)]
                 out[f.name] = vals

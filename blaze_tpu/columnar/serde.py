@@ -45,7 +45,7 @@ import zstandard
 from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
 from blaze_tpu.columnar.types import Schema, TypeKind
 from blaze_tpu.config import conf
-from blaze_tpu.runtime import faults, monitor
+from blaze_tpu.runtime import faults, monitor, trace
 
 MAGIC = b"BTB1"
 DICT_SENTINEL = 0xFFFFFFFF  # impossible plain string `total` (frames < 2 GiB)
@@ -236,11 +236,17 @@ def host_batch_nbytes(hb: HostBatch) -> int:
 def to_host(batch: ColumnBatch) -> HostBatch:
     if conf.fault_injection_spec:
         faults.inject("device.get")
-    n = int(batch.num_rows)
-    hb = HostBatch(batch.schema, [_host_col(c, n) for c in batch.columns],
-                   n)
-    if conf.monitor_enabled:
-        monitor.count_copy("ffi", host_batch_nbytes(hb))
+    # the d2h span starts before the row-count pull: that pull is where
+    # the host waits for the device to finish the batch
+    with trace.span("d2h", what="to_host") as sp:
+        n = int(batch.num_rows)
+        hb = HostBatch(batch.schema,
+                       [_host_col(c, n) for c in batch.columns], n)
+        if conf.monitor_enabled or conf.trace_enabled:
+            nbytes = host_batch_nbytes(hb)
+            sp.set(rows=n, bytes=nbytes)
+            if conf.monitor_enabled:
+                monitor.count_copy("ffi", nbytes)
     return hb
 
 

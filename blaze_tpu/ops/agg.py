@@ -417,15 +417,22 @@ class AggExec(Operator):
             def run(b: ColumnBatch) -> ColumnBatch:
                 ngroups = len(self._group_fields)
                 specs = [SortSpec(i) for i in range(ngroups)]
-                sb = sort_batch(b, specs)
-                layout = seg.group_layout(sb, list(range(ngroups)))
-                gcols = [sb.columns[i].take(
-                    jnp.clip(layout.start_idx, 0, sb.capacity - 1))
-                    for i in range(ngroups)]
+                # the phases of the collapse, as named scopes (the sort
+                # brings its own: sort.encode_keys / sort / permute)
+                with jax.named_scope("collapse.sort"):
+                    sb = sort_batch(b, specs)
+                with jax.named_scope("collapse.group_layout"):
+                    layout = seg.group_layout(sb, list(range(ngroups)))
+                with jax.named_scope("collapse.group_keys"):
+                    gcols = [sb.columns[i].take(
+                        jnp.clip(layout.start_idx, 0, sb.capacity - 1))
+                        for i in range(ngroups)]
                 if raw_input:
-                    scols = self._accumulate_raw(sb, layout, ngroups)
+                    with jax.named_scope("collapse.accumulate_raw"):
+                        scols = self._accumulate_raw(sb, layout, ngroups)
                 else:
-                    scols = self._merge_state(sb, layout, ngroups)
+                    with jax.named_scope("collapse.merge_state"):
+                        scols = self._merge_state(sb, layout, ngroups)
                 return ColumnBatch(self._state_schema, gcols + scols,
                                    layout.num_groups, sb.capacity)
 
@@ -444,7 +451,8 @@ class AggExec(Operator):
         for call in self.aggs:
             ins = sb.columns[ci:ci + len(call.inputs)]
             ci += len(call.inputs)
-            out.extend(self._acc_one(call, ins, layout))
+            with jax.named_scope(call.fn):
+                out.extend(self._acc_one(call, ins, layout))
         return out
 
     def _acc_one(self, call: AggCall, ins: List[Column], layout

@@ -78,6 +78,12 @@ class Operator:
     def name(self) -> str:
         return type(self).__name__
 
+    def label(self) -> str:
+        """`FilterExec` -> `filter`: the operator inside program names and
+        named scopes (from the class, never from data)."""
+        name = type(self).__name__
+        return (name[:-4] if name.endswith("Exec") else name).lower()
+
     def tree_string(self, indent: int = 0) -> str:
         s = "  " * indent + self.name() + "\n"
         return s + "".join(c.tree_string(indent + 1) for c in self.children)
@@ -120,7 +126,9 @@ def add_compute_split(op: Operator, ns: int, device: bool) -> None:
     so metric_report and the query doctor can tell a jit-dispatched
     chain from a host-kernel chain (digests/JSON/UDF) without parsing
     plan shapes. The executor calls this once per fused batch — ops that
-    never fuse simply have a zero split."""
+    never fuse simply have a zero split. `elapsed_device_ns` is host
+    time round an asynchronous dispatch, not device time: device time
+    comes from the profiler trace only."""
     op.metrics.add("elapsed_device_ns" if device else "elapsed_host_ns",
                    ns)
 

@@ -364,20 +364,27 @@ def host_to_device(hb: HostBatch, capacity: Optional[int] = None):
 
     from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
     from blaze_tpu.config import conf
-    from blaze_tpu.runtime import faults
+    from blaze_tpu.runtime import faults, trace
 
     if conf.fault_injection_spec:
         faults.inject("device.put")
-    if conf.monitor_enabled:
-        from blaze_tpu.columnar.serde import host_batch_nbytes
-        from blaze_tpu.runtime import monitor
-
-        monitor.count_copy("ffi", host_batch_nbytes(hb))
     n = hb.num_rows
-    cap = capacity or bucket_capacity(n)
-    cols = [_upload_col(c, f, n, cap)
-            for c, f in zip(hb.cols, hb.schema.fields)]
-    return ColumnBatch(hb.schema, cols, jnp.asarray(n, jnp.int32), cap)
+    # an h2d span: the host's time in the call (padding to capacity +
+    # enqueue of the transfers), not the transfer itself
+    with trace.span("h2d", rows=n, what="host_to_device") as sp:
+        if conf.monitor_enabled or conf.trace_enabled:
+            from blaze_tpu.columnar.serde import host_batch_nbytes
+
+            nbytes = host_batch_nbytes(hb)
+            sp.set(bytes=nbytes)
+            if conf.monitor_enabled:
+                from blaze_tpu.runtime import monitor
+
+                monitor.count_copy("ffi", nbytes)
+        cap = capacity or bucket_capacity(n)
+        cols = [_upload_col(c, f, n, cap)
+                for c, f in zip(hb.cols, hb.schema.fields)]
+        return ColumnBatch(hb.schema, cols, jnp.asarray(n, jnp.int32), cap)
 
 
 # ---------------------------------------------------------------------------

@@ -155,88 +155,104 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
     capB, capP = build.capacity, probe.capacity
     cap = capB + capP
 
-    # common string word counts so both sides emit identical key layouts
-    # (extra zero words never change relative order, so this stays
-    # consistent with the build-side sort done at natural width)
-    swords: List[Optional[int]] = []
-    for bc, pc in zip(build_cols, probe_cols):
-        b, p = build.columns[bc], probe.columns[pc]
-        if b.is_string:
-            swords.append(max((b.data.width + 7) // 8,
-                              (p.data.width + 7) // 8))
-        else:
-            swords.append(None)
-    bkeys = _join_sort_keys(build, build_cols, null_safe, force_flags, 0,
-                            swords)
-    pkeys = _join_sort_keys(probe, probe_cols, null_safe, force_flags, 1,
-                            swords)
-    live = jnp.concatenate([build.row_mask(), probe.row_mask()])
-    keys = []
-    for b, p in zip(bkeys, pkeys):
-        assert b.dtype == p.dtype, (b.dtype, p.dtype)
-        keys.append(jnp.concatenate([b, p]))
-    tag = jnp.concatenate([jnp.zeros((capB,), jnp.uint8),
-                           jnp.ones((capP,), jnp.uint8)])
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    with jax.named_scope("match.keys"):
+        # common string word counts so both sides emit identical key
+        # layouts
+        # (extra zero words never change relative order, so this stays
+        # consistent with the build-side sort done at natural width)
+        swords: List[Optional[int]] = []
+        for bc, pc in zip(build_cols, probe_cols):
+            b, p = build.columns[bc], probe.columns[pc]
+            if b.is_string:
+                swords.append(max((b.data.width + 7) // 8,
+                                  (p.data.width + 7) // 8))
+            else:
+                swords.append(None)
+        bkeys = _join_sort_keys(build, build_cols, null_safe, force_flags, 0,
+                                swords)
+        pkeys = _join_sort_keys(probe, probe_cols, null_safe, force_flags, 1,
+                                swords)
+        live = jnp.concatenate([build.row_mask(), probe.row_mask()])
+        keys = []
+        for b, p in zip(bkeys, pkeys):
+            assert b.dtype == p.dtype, (b.dtype, p.dtype)
+            keys.append(jnp.concatenate([b, p]))
+        tag = jnp.concatenate([jnp.zeros((capB,), jnp.uint8),
+                               jnp.ones((capP,), jnp.uint8)])
+        pos = jnp.arange(cap, dtype=jnp.int32)
 
-    sorted_ops = jax.lax.sort(tuple(keys) + (tag, pos),
-                              num_keys=len(keys) + 1, is_stable=True)
-    skeys = sorted_ops[:len(keys)]
-    stag, spos = sorted_ops[-2], sorted_ops[-1]
+    with jax.named_scope("match.merge_sort"):
+        sorted_ops = jax.lax.sort(tuple(keys) + (tag, pos),
+                                  num_keys=len(keys) + 1, is_stable=True)
+        skeys = sorted_ops[:len(keys)]
+        stag, spos = sorted_ops[-2], sorted_ops[-1]
 
-    # run boundaries over the *encoded* keys (flags included -> exact
-    # equality). Keys [0]=liveness and [1]=null-disable participate: dead
-    # rows form their own trailing region, null-key rows split per side.
-    eq = jnp.ones((cap,), jnp.bool_)
-    for k in skeys:
-        eq = eq & (k == jnp.roll(k, 1))
-    starts = (~eq).at[0].set(True)
-    slive = live[spos]
-    starts = starts & slive  # dead rows clump at the end; gid garbage there
+    with jax.named_scope("match.run_starts"):
+        # run boundaries over the *encoded* keys (flags included -> exact
+        # equality). Keys [0]=liveness and [1]=null-disable participate:
+        # dead rows form their own trailing region, null-key rows split per
+        # side.
+        eq = jnp.ones((cap,), jnp.bool_)
+        for k in skeys:
+            eq = eq & (k == jnp.roll(k, 1))
+        starts = (~eq).at[0].set(True)
+        slive = live[spos]
+        # dead rows clump at the end; gid garbage there
+        starts = starts & slive
 
-    gid = jnp.cumsum(starts.astype(jnp.int32)) - 1
-    is_build = (stag == 0) & slive
-    is_probe = (stag == 1) & slive
+    with jax.named_scope("match.run_cumsums"):
+        gid = jnp.cumsum(starts.astype(jnp.int32)) - 1
+        is_build = (stag == 0) & slive
+        is_probe = (stag == 1) & slive
 
-    csum_b = jnp.cumsum(is_build.astype(jnp.int32))
-    csum_p = jnp.cumsum(is_probe.astype(jnp.int32))
-    run_start_idx = nonzero_i32(starts, cap, fill_value=cap - 1)
-    zb = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_b])
-    zp = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_p])
-    # per-run: build rows before the run, and totals in run
-    run_b_before = zb[run_start_idx]
-    num_runs = jnp.sum(starts, dtype=jnp.int32)
-    run_end_idx = jnp.concatenate([run_start_idx[1:],
-                                   jnp.full((1,), cap, jnp.int32)])
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    # runs are contiguous; run r spans [run_start_idx[r], run_start_idx[r+1])
-    # (the final run ends where dead rows begin = total live count)
-    total_live = jnp.sum(live, dtype=jnp.int32)
-    run_end_idx = jnp.where(slot == num_runs - 1, total_live, run_end_idx)
-    run_b_total = zb[jnp.clip(run_end_idx, 0, cap)] - run_b_before
-    run_p_total = zp[jnp.clip(run_end_idx, 0, cap)] - zp[run_start_idx]
+        csum_b = jnp.cumsum(is_build.astype(jnp.int32))
+        csum_p = jnp.cumsum(is_probe.astype(jnp.int32))
 
-    # broadcast run data back to rows
-    gid_c = jnp.clip(gid, 0, cap - 1)
-    row_start = run_b_before[gid_c]
-    row_bcnt = run_b_total[gid_c]
-    row_pcnt = run_p_total[gid_c]
+    with jax.named_scope("match.run_start_idx"):
+        run_start_idx = nonzero_i32(starts, cap, fill_value=cap - 1)
 
-    # per-probe-row (original order): sort by (not-probe, original pos)
-    not_probe = jnp.where(is_probe, jnp.uint8(0), jnp.uint8(1))
-    ppos = jnp.where(is_probe, spos - capB, jnp.int32(0))
-    back = jax.lax.sort((not_probe, ppos, row_start, row_bcnt),
-                        num_keys=2, is_stable=True)
-    start_p = back[2][:capP]
-    cnt_p = back[3][:capP]
+    with jax.named_scope("match.run_totals"):
+        zb = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_b])
+        zp = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_p])
+        # per-run: build rows before the run, and totals in run
+        run_b_before = zb[run_start_idx]
+        num_runs = jnp.sum(starts, dtype=jnp.int32)
+        run_end_idx = jnp.concatenate([run_start_idx[1:],
+                                       jnp.full((1,), cap, jnp.int32)])
+        slot = jnp.arange(cap, dtype=jnp.int32)
+        # runs are contiguous; run r spans
+        # [run_start_idx[r], run_start_idx[r+1])
+        # (the final run ends where dead rows begin = total live count)
+        total_live = jnp.sum(live, dtype=jnp.int32)
+        run_end_idx = jnp.where(slot == num_runs - 1, total_live,
+                                run_end_idx)
+        run_b_total = zb[jnp.clip(run_end_idx, 0, cap)] - run_b_before
+        run_p_total = zp[jnp.clip(run_end_idx, 0, cap)] - zp[run_start_idx]
+
+    with jax.named_scope("match.run_broadcast"):
+        # broadcast run data back to rows
+        gid_c = jnp.clip(gid, 0, cap - 1)
+        row_start = run_b_before[gid_c]
+        row_bcnt = run_b_total[gid_c]
+        row_pcnt = run_p_total[gid_c]
+
+    with jax.named_scope("match.to_probe_order"):
+        # per-probe-row (original order): sort by (not-probe, original pos)
+        not_probe = jnp.where(is_probe, jnp.uint8(0), jnp.uint8(1))
+        ppos = jnp.where(is_probe, spos - capB, jnp.int32(0))
+        back = jax.lax.sort((not_probe, ppos, row_start, row_bcnt),
+                            num_keys=2, is_stable=True)
+        start_p = back[2][:capP]
+        cnt_p = back[3][:capP]
 
     # per-build-row (sorted-build order): build rows' probe-match counts.
     # sorted-by-key order of build rows == their order within the merged
     # sort restricted to build rows (same comparator, stable) -> compact.
-    not_build = jnp.where(is_build, jnp.uint8(0), jnp.uint8(1))
-    backb = jax.lax.sort((not_build, slot, row_pcnt), num_keys=2,
-                         is_stable=True)
-    bmatch = backb[2][:capB]
+    with jax.named_scope("match.to_build_order"):
+        not_build = jnp.where(is_build, jnp.uint8(0), jnp.uint8(1))
+        backb = jax.lax.sort((not_build, slot, row_pcnt), num_keys=2,
+                             is_stable=True)
+        bmatch = backb[2][:capB]
 
     # probe rows beyond num_rows: zero counts
     start_p = jnp.where(probe.row_mask(), start_p, 0)

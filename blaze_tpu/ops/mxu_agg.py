@@ -234,6 +234,7 @@ def _pallas_accumulate(keys: Array, ok: Array, words, recipe,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((gh, pgl), jnp.int32),
         scratch_shapes=[pltpu.VMEM((gh, pgl), jnp.int32)],
+        name="mxu_agg_accumulate",
     )(m)
 
 

@@ -31,7 +31,7 @@ from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
 from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
-from blaze_tpu.runtime import resources
+from blaze_tpu.runtime import resources, trace
 
 logger = logging.getLogger(__name__)
 
@@ -148,11 +148,25 @@ class ParquetScanExec(Operator):
                                      pf.num_row_groups - len(groups))
                     if not groups:
                         continue
-                    for rb in pf.iter_batches(batch_size=batch_rows,
+                    batches = pf.iter_batches(batch_size=batch_rows,
                                               row_groups=groups,
-                                              columns=names):
+                                              columns=names)
+                    while True:
+                        # read + decode happen in the reader's next():
+                        # pull each record batch inside its span (the
+                        # pull that finds the end is a span of 0 rows)
+                        with trace.span("scan_decode", file=path) as sp:
+                            rb = next(batches, None)
+                            if rb is None:
+                                break
+                            sp.set(rows=rb.num_rows, bytes=rb.nbytes)
                         ctx.check_running()
-                        with self.metrics.timer("io_time_ns"):
+                        # io_time_ns and the h2d span are the host's time
+                        # in the call (arrow -> numpy staging + enqueue of
+                        # the transfer), not the transfer itself
+                        with self.metrics.timer("io_time_ns"), \
+                                trace.span("h2d", rows=rb.num_rows,
+                                           bytes=rb.nbytes, what="scan"):
                             batch = self._to_device(rb, part_values)
                         self.metrics.add("bytes_scanned", rb.nbytes)
                         yield batch

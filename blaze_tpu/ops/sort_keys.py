@@ -132,11 +132,13 @@ def batch_sort_keys(batch: ColumnBatch, specs: Sequence[SortSpec],
     The leading liveness key forces padding rows (>= num_rows) to the end
     regardless of direction/null flags, so sorted outputs stay front-compact.
     """
-    mask = batch.row_mask()
-    keys: List[Array] = [jnp.where(mask, jnp.uint8(0), jnp.uint8(1))]
-    for spec in specs:
-        keys.extend(encode_column(batch.columns[spec.col], spec.asc,
-                                  spec.nulls_first, mask, max_string_words))
+    with jax.named_scope("sort.encode_keys"):
+        mask = batch.row_mask()
+        keys: List[Array] = [jnp.where(mask, jnp.uint8(0), jnp.uint8(1))]
+        for spec in specs:
+            keys.extend(encode_column(batch.columns[spec.col], spec.asc,
+                                      spec.nulls_first, mask,
+                                      max_string_words))
     return keys
 
 
@@ -157,9 +159,11 @@ def permute_by_keys(batch: ColumnBatch, keys: List[Array]) -> ColumnBatch:
     extended-precision emulation and multiplies compile time (measured
     ~56s -> ~30s for a 2^21 sort by dropping payload operands); gathers
     compile in ~2s and run as fast."""
-    iota = jnp.arange(batch.capacity, dtype=jnp.int32)
-    out = jax.lax.sort(tuple(keys) + (iota,), num_keys=len(keys),
-                       is_stable=True)
-    perm = out[len(keys)]
-    new_cols = [c.take(perm) for c in batch.columns]
+    with jax.named_scope("sort.sort"):
+        iota = jnp.arange(batch.capacity, dtype=jnp.int32)
+        out = jax.lax.sort(tuple(keys) + (iota,), num_keys=len(keys),
+                           is_stable=True)
+        perm = out[len(keys)]
+    with jax.named_scope("sort.permute"):
+        new_cols = [c.take(perm) for c in batch.columns]
     return ColumnBatch(batch.schema, new_cols, batch.num_rows, batch.capacity)

@@ -28,7 +28,7 @@ from blaze_tpu.columnar.types import Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.ops.base import ExecContext
 from blaze_tpu.plan import plan_pb2 as pb
-from blaze_tpu.runtime import resources
+from blaze_tpu.runtime import resources, trace
 from blaze_tpu.runtime.executor import execute_plan
 
 
@@ -132,19 +132,31 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
 
             return run
 
-        sb, bounds = jit_cache.get_or_compile(key, make)(batch)
-        bounds = np.asarray(bounds)
-        for p in range(Pn):
-            n = int(bounds[p + 1]) - int(bounds[p])
-            if n:
-                recv_parts[p].append(slice_batch(sb, int(bounds[p]), n))
+        # one `exchange` span per macro-batch: dispatch, the bounds pull
+        # (where the host waits for the grouping) and the slicing
+        with trace.span("exchange", transport="local", partitions=Pn,
+                        capacity=batch.capacity) as sp:
+            sb, bounds = jit_cache.get_or_compile(key, make)(batch)
+            bounds = np.asarray(bounds)
+            for p in range(Pn):
+                n = int(bounds[p + 1]) - int(bounds[p])
+                if n:
+                    recv_parts[p].append(
+                        slice_batch(sb, int(bounds[p]), n))
+            sp.set(rows=int(bounds[Pn]) - int(bounds[0]))
         return True
 
     def exchange_batch(batch: ColumnBatch) -> bool:
         """Exchange one batch over the mesh; False on quota overflow."""
         if use_d == 1:
             return exchange_local(batch)
+        with trace.span("exchange", transport="mesh", partitions=Pn,
+                        devices=use_d, capacity=batch.capacity) as sp:
+            return exchange_mesh(batch, sp)
+
+    def exchange_mesh(batch: ColumnBatch, sp) -> bool:
         n = int(batch.num_rows)
+        sp.set(rows=n)
         per = max(1, -(-n // use_d))
         cap = bucket_capacity(per)
         # quota: rows one device may send one OWNER device (k partitions)
@@ -263,7 +275,15 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         from blaze_tpu.ops.shuffle import read_shuffle_partition_host
 
         for b in recv_parts[partition]:
-            yield jax.tree_util.tree_map(_unshard, b)
+            if mesh is None:  # one device: _unshard passes through
+                yield jax.tree_util.tree_map(_unshard, b)
+                continue
+            # the span closes before the yield: a span must never stay
+            # open across a generator's suspension
+            with trace.span("exchange", transport="unshard",
+                            partitions=Pn, capacity=b.capacity):
+                b = jax.tree_util.tree_map(_unshard, b)
+            yield b
         for data, index in file_outputs:
             if host_supported(schema):
                 yield from read_shuffle_partition_host(data, index,

@@ -307,8 +307,7 @@ class ShapeRegistry:
 
     # -- jit_cache observer protocol -----------------------------------
     def observe(self, event: str, key, ns: int) -> None:
-        kind = key[0] if (isinstance(key, tuple) and key
-                          and isinstance(key[0], str)) else "other"
+        kind = jit_cache.kind_of(key)
         kid = repr(key)
         with self._lock:
             e = self.entries.get(kid)
@@ -351,8 +350,7 @@ class ShapeRegistry:
 
     def attach_replay(self, key, payload: Dict[str, Any],
                       source: str) -> None:
-        kind = key[0] if (isinstance(key, tuple) and key
-                          and isinstance(key[0], str)) else "other"
+        kind = jit_cache.kind_of(key)
         kid = repr(key)
         with self._lock:
             e = self.entries.setdefault(kid, {

@@ -10,7 +10,10 @@ loop:
   critical path   `compute_critical_path(record, records)` decomposes a
                   query's wall time into an ADDITIVE breakdown:
                   admission wait, fair-scheduler queue wait, compile,
-                  device compute, host compute, serde encode/decode,
+                  fused dispatch (host time round the asynchronous jit
+                  dispatch of fused chains — not device time, which only
+                  the profiler trace has), host compute, serde
+                  encode/decode,
                   shuffle I/O, spill, retry/backoff, speculation waste,
                   result merge, residual. Task-thread terms are measured
                   wall-clock per category (monitor.count_time) and can
@@ -67,7 +70,11 @@ TERMS = (
     "admission_wait",     # service: parked in the admission waiting room
     "sched_queue",        # FairScheduler: submitted -> dispatched
     "compile",            # compile_service: XLA compile time
-    "device_compute",     # executor: jit-safe fused-chain batch time
+    "fused_dispatch",     # executor: HOST time round the asynchronous
+                          # dispatch of a jit-safe fused chain's batches
+                          # (enqueue, or a wait for a free slot in the
+                          # device queue); never device time, which comes
+                          # from the profiler trace only
     "host_compute",       # executor: host-path fused-chain batch time
     "serde_encode",       # columnar/serde: encode (compress + frame)
     "serde_decode",       # columnar/serde: decode (read + decompress)
@@ -84,7 +91,7 @@ TERMS = (
 _COUNTER_TERMS = (
     ("sched_queue_ms", "sched_queue"),
     ("compile_ms", "compile"),
-    ("device_compute_ms", "device_compute"),
+    ("fused_dispatch_ms", "fused_dispatch"),
     ("host_compute_ms", "host_compute"),
     ("serde_encode_ms", "serde_encode"),
     ("serde_decode_ms", "serde_decode"),

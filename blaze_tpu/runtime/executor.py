@@ -14,6 +14,8 @@ import time
 
 from typing import Callable, Optional
 
+import jax
+
 from blaze_tpu.columnar.batch import ColumnBatch
 from blaze_tpu.config import conf
 from blaze_tpu.ops.base import (
@@ -259,6 +261,10 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
     top, source, chain = _fused_chain(op)
     jit = all(c.jit_safe() for c in chain)
     key = ("fused", jit, top.plan_key())
+    # the catch-all kind says which chain it is: operator class names, in
+    # execution order, from the plan's structure only
+    names = [c.label() for c in chain]
+    program = ".".join(["fused"] + names)
 
     def make():
         from blaze_tpu.exprs.compiler import cse_scope
@@ -270,8 +276,8 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
             # evaluate once; a chain-wide scope would retain every
             # intermediate batch in the memo until the chain ends (ops
             # build fresh batches, so cross-op hits can't happen anyway)
-            for fn in fns:
-                with cse_scope():
+            for label, fn in zip(names, fns):
+                with cse_scope(), jax.named_scope(label):
                     batch = fn(batch)
             return batch
 
@@ -281,7 +287,7 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
         for batch in source.execute(ctx):
             ctx.check_running()
             fused = jit_cache.get_or_compile(key + batch.shape_key(), make,
-                                             jit=jit)
+                                             jit=jit, name=program)
             t0 = time.perf_counter_ns()
             with op.metrics.timer():
                 out = fused(batch)
@@ -289,9 +295,11 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
             add_compute_split(op, batch_ns, device=jit)
             if conf.monitor_enabled:
                 # unjitted chains (host kernels: digests/JSON/UDF) bill
-                # host_compute; fused jit dispatch bills device_compute
+                # host_compute; a fused jit dispatch bills fused_dispatch:
+                # host time round an asynchronous dispatch, NOT device
+                # time (that comes from the profiler trace only)
                 monitor.count_time(
-                    "device_compute" if jit else "host_compute", batch_ns)
+                    "fused_dispatch" if jit else "host_compute", batch_ns)
             yield out
 
     return count_stream(op, gen())

@@ -10,11 +10,15 @@ structural key — same plan + same shape bucket => zero recompiles.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
-from typing import Callable, Dict, Hashable
+from typing import Callable, Dict, Hashable, Optional
 
 import jax
+
+from blaze_tpu.config import conf
+from blaze_tpu.runtime import trace
 
 _lock = threading.Lock()
 _cache: Dict[Hashable, Callable] = {}
@@ -39,9 +43,44 @@ def _notify(event: str, key: Hashable, ns: int = 0) -> None:
             pass
 
 
+_NAME_MAX = 64
+
+
+def kind_of(key: Hashable) -> str:
+    """The program kind of a cache key: its first element when the key is
+    a tuple that starts with a string, else "other" — the vocabulary of
+    compile_service's per-kind statistics and, through `_named`, of the
+    device trace's module names."""
+    if isinstance(key, tuple) and key and isinstance(key[0], str):
+        return key[0]
+    return "other"
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """`fn` under `name`, for jax.jit to name the HLO module after
+    (`jit_<name>`): a thin wrapper, so a function shared between keys
+    (executor's `pack`) is never renamed in place. functools.wraps keeps
+    `__wrapped__`, which is where jax.jit resolves static_argnames /
+    donate_argnames against the real signature."""
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def get_or_compile(key: Hashable, make_fn: Callable[[], Callable],
-                   jit: bool = True, **jit_kwargs) -> Callable:
+                   jit: bool = True, name: Optional[str] = None,
+                   **jit_kwargs) -> Callable:
     """Return a jitted function for `key`, building it once.
+
+    The program is named by its kind (`kind_of(key)`): every module in a
+    device trace reads `jit_<kind>(<hash>)`. Kinds are the fixed strings
+    already in the keys. A catch-all kind may pass `name` to say more
+    (`fused.filter.project`): it must start with the kind, come from the
+    plan's structure only (never a literal, a shape or data: a name is
+    part of the persistent cache's key) and is cut to a fixed length.
 
     `jit=False` caches the bare callable instead: used for pipelines with
     host-evaluated expressions (digests/JSON/UDF), which run op-at-a-time
@@ -60,40 +99,54 @@ def get_or_compile(key: Hashable, make_fn: Callable[[], Callable],
     from blaze_tpu.runtime import faults
 
     faults.inject("jit.compile")
-    built = jax.jit(make_fn(), **jit_kwargs) if jit else make_fn()
     if jit:
-        built = _with_stale_exec_retry(key, built, make_fn, jit_kwargs)
-        built = _with_first_call_timer(key, built)
+        kind = kind_of(key)
+        name = (name or kind)[:_NAME_MAX]
+
+        def build():
+            return jax.jit(_named(make_fn(), name), **jit_kwargs)
+
+        built = _with_stale_exec_retry(key, build(), build)
+        built = _with_first_call_timer(key, built, kind)
+    else:
+        built = make_fn()
     with _lock:
         return _cache.setdefault(key, built)
 
 
-def _with_first_call_timer(key, fn):
+def _with_first_call_timer(key, fn, kind):
     """Report the first invocation's wall time as this key's compile cost.
 
     jax compiles lazily at the first jitted call, so the first-call wall
     clock is trace + XLA build (+ the first dispatch enqueue; the result
     is NOT blocked on — blocking here would serialize the engine's async
     dispatch pipelines, and compile time dwarfs enqueue time anyway).
-    """
-    import functools
 
+    With tracing on, every call is also a `dispatch` span (kind;
+    first_call on the compiling one): the host's time in the call, which
+    returns when the work is enqueued — never the device's time. Off, the
+    steady path pays one attribute read and builds no span object.
+    """
     done = []
 
     @functools.wraps(fn)
     def timed(*args, **kwargs):
         if done:
-            return fn(*args, **kwargs)
+            if not conf.trace_enabled:
+                return fn(*args, **kwargs)
+            with trace.span("dispatch", program=kind):
+                return fn(*args, **kwargs)
         done.append(True)
-        t0 = time.perf_counter_ns()
-        out = fn(*args, **kwargs)
-        _notify("compiled", key, time.perf_counter_ns() - t0)
+        with trace.span("dispatch", program=kind, first_call=True):
+            t0 = time.perf_counter_ns()
+            out = fn(*args, **kwargs)
+            _notify("compiled", key, time.perf_counter_ns() - t0)
         return out
 
     return timed
 
 
-def _with_stale_exec_retry(key, fn, make_fn, jit_kwargs):
+def _with_stale_exec_retry(key, fn, build):
     """Self-healing wrapper for a rare XLA dispatch inconsistency.
 
     Re-executing a cached jitted fn on inputs with identical pytree /
@@ -103,9 +156,8 @@ def _with_stale_exec_retry(key, fn, make_fn, jit_kwargs):
     executable's captured-constant accounting goes stale). A fresh trace
     of the same program always succeeds, so on that specific error we
     evict, rebuild once, and re-dispatch — correctness is unaffected and
-    steady-state cost is zero."""
-    import functools
-
+    steady-state cost is zero. `build` makes the jitted program anew,
+    under the same name."""
     with _lock:
         holder = _retry.setdefault(key, [fn])
 
@@ -121,7 +173,7 @@ def _with_stale_exec_retry(key, fn, make_fn, jit_kwargs):
             with _lock:
                 _stats["stale_exec_rebuilds"] = \
                     _stats.get("stale_exec_rebuilds", 0) + 1
-                holder[0] = jax.jit(make_fn(), **jit_kwargs)
+                holder[0] = build()
             return holder[0](*args, **kwargs)
 
     return wrapped

@@ -137,14 +137,14 @@ def count_copy(boundary: str, nbytes: int, moved: Optional[int] = None
 # boundary-time categories (runtime/doctor.py critical-path terms):
 # each lands in the run ledger as "<category>_ms" and on stage spans.
 TIME_CATEGORIES = ("sched_queue", "serde_encode", "serde_decode",
-                   "shuffle_io", "spill", "device_compute",
+                   "shuffle_io", "spill", "fused_dispatch",
                    "host_compute", "retry_backoff")
 
 
 def count_time(category: str, ns: int, qid: Optional[str] = None,
                sid: Optional[Any] = None) -> None:
     """Account `ns` wall nanoseconds of `category` work (serde encode,
-    spill I/O, device compute, ...) against the attributed query/stage —
+    spill I/O, fused dispatch, ...) against the attributed query/stage —
     the time-domain twin of count_copy, feeding the doctor's additive
     critical-path breakdown. Attribution follows count_copy (trace
     context, then the runner-registered active query) unless qid/sid are

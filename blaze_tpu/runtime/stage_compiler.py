@@ -96,15 +96,16 @@ def _walk_chain(node: Operator):
 
 
 def _build_steps(chain: List[MapLikeOp]):
-    """("mask", predicate fns) | ("map", batch fn) per chain op."""
+    """("mask", predicate fns, label) | ("map", batch fn, label) per chain
+    op; the label is the operator's name, for the program's named scopes."""
     from blaze_tpu.ops.basic import FilterExec
 
     steps = []
     for op in chain:
         if isinstance(op, FilterExec):
-            steps.append(("mask", list(op._fns)))
+            steps.append(("mask", list(op._fns), op.label()))
         else:
-            steps.append(("map", op.make_batch_fn()))
+            steps.append(("map", op.make_batch_fn(), op.label()))
     return steps
 
 
@@ -114,8 +115,8 @@ def _apply_steps(steps, b: ColumnBatch):
     from blaze_tpu.exprs.compiler import cse_scope
 
     mask = b.row_mask()
-    for kind, fn in steps:
-        with cse_scope():
+    for kind, fn, label in steps:
+        with cse_scope(), jax.named_scope(label):
             if kind == "map":
                 b = fn(b)
             else:
@@ -411,8 +412,9 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
         kmins32 = [np.int64(m).astype(np.int32) for m in kmins]
 
         def run(*batches):
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *batches)
+            with jax.named_scope("scan.stack"):
+                stacked = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *batches)
             # single pass: dense MXU accumulation (oob set when the
             # memoized kmins/spans no longer cover the data, or keys go
             # null — either triggers re-probe + recompile in the caller).
@@ -456,6 +458,9 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
 
             def step(carry, b):
                 b, live = apply_chain(b)
+                # named scopes from here: agg.keys, agg.inputs,
+                # agg.digitize, agg.accumulate (the chain's operators
+                # name their own, _apply_steps)
                 # composite keys pack into one dense index. Bounds are
                 # checked exactly in int64, but the packed index itself is
                 # computed in int32: in-range offsets (< span <= R <= 2^16)
@@ -464,109 +469,115 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
                 # and an int64 producer chain feeding the pallas kernel's
                 # key input materializes through a lane-padded layout that
                 # costs ~30ms/batch (measured; see mxu_agg pallas notes)
-                packed = jnp.zeros((b.capacity,), jnp.int32)
-                inb = live
-                keys_valid = live
-                null_key = jnp.array(False)
-                for i, gfn in enumerate(group_fns):
-                    g = gfn(b)
-                    keys_valid = keys_valid & g.valid_mask()
-                    null_key = null_key | jnp.any(live & ~g.valid_mask())
-                    off64 = g.data.astype(jnp.int64) - kmins[i]
-                    inb = inb & g.valid_mask() & (off64 >= 0) & \
-                        (off64 < spans[i])
-                    off32 = g.data.astype(jnp.int32) - kmins32[i]
-                    packed = packed + jnp.clip(
-                        off32, 0, spans[i] - 1) * jnp.int32(strides[i])
-                carry["oob"] = carry["oob"] | null_key | \
-                    jnp.any(keys_valid & ~inb)
-                k = jnp.clip(packed, 0, R - 1)
-                # every aggregate plane rides ONE matmul (mxu_agg
-                # .grouped_multi); non-nullable inputs reuse the presence
-                # plane for their counts (validity is a trace-time
-                # property, so this specializes per program)
-                specs = [("count", jnp.ones_like(inb))]
-                slots = []  # per call: (sum_spec_idx|None, cnt_spec_idx|None)
-                for i, call in enumerate(calls):
-                    vcol = input_fns[i](b)
-                    if vcol.validity is None:
-                        ci = None  # reuse presence
-                    else:
-                        specs.append(("count", vcol.validity))
-                        ci = len(specs) - 1
-                    si = None
-                    if call.fn in ("sum", "avg"):
-                        data = vcol.data
-                        if sum_is_float[i]:
-                            data = data.astype(jnp.float64)
+                with jax.named_scope("agg.keys"):
+                    packed = jnp.zeros((b.capacity,), jnp.int32)
+                    inb = live
+                    keys_valid = live
+                    null_key = jnp.array(False)
+                    for i, gfn in enumerate(group_fns):
+                        g = gfn(b)
+                        keys_valid = keys_valid & g.valid_mask()
+                        null_key = null_key | jnp.any(live & ~g.valid_mask())
+                        off64 = g.data.astype(jnp.int64) - kmins[i]
+                        inb = inb & g.valid_mask() & (off64 >= 0) & \
+                            (off64 < spans[i])
+                        off32 = g.data.astype(jnp.int32) - kmins32[i]
+                        packed = packed + jnp.clip(
+                            off32, 0, spans[i] - 1) * jnp.int32(strides[i])
+                    carry["oob"] = carry["oob"] | null_key | \
+                        jnp.any(keys_valid & ~inb)
+                    k = jnp.clip(packed, 0, R - 1)
+                with jax.named_scope("agg.inputs"):
+                    # every aggregate plane rides ONE matmul (mxu_agg
+                    # .grouped_multi); non-nullable inputs reuse the presence
+                    # plane for their counts (validity is a trace-time
+                    # property, so this specializes per program)
+                    specs = [("count", jnp.ones_like(inb))]
+                    # per call: (sum_spec_idx|None, cnt_spec_idx|None)
+                    slots = []
+                    for i, call in enumerate(calls):
+                        vcol = input_fns[i](b)
+                        if vcol.validity is None:
+                            ci = None  # reuse presence
                         else:
-                            data = data.astype(jnp.int64)
-                        vv = (jnp.ones_like(inb) if vcol.validity is None
-                              else vcol.validity)
-                        specs.append(("sum", data, vv))
-                        si = len(specs) - 1
-                    elif call.fn in _MM_FNS:
-                        vv = inb & vcol.valid_mask()
-                        v = vcol.data
-                        red = (jax.ops.segment_min if call.fn == "min"
-                               else jax.ops.segment_max)
-                        comb = (jnp.minimum if call.fn == "min"
-                                else jnp.maximum)
-                        if jnp.issubdtype(v.dtype, jnp.floating):
-                            # Spark NaN order: NaN is the GREATEST value
-                            # (segment.seg_min/seg_max semantics)
-                            nn = vv & ~jnp.isnan(v)
-                            if call.fn == "min":
-                                sent = jnp.asarray(jnp.inf, v.dtype)
-                                vm = jnp.where(nn, v, sent)
-                                flag = nn  # any_nonnan
+                            specs.append(("count", vcol.validity))
+                            ci = len(specs) - 1
+                        si = None
+                        if call.fn in ("sum", "avg"):
+                            data = vcol.data
+                            if sum_is_float[i]:
+                                data = data.astype(jnp.float64)
                             else:
-                                sent = jnp.asarray(-jnp.inf, v.dtype)
-                                vm = jnp.where(vv & ~jnp.isnan(v), v, sent)
-                                flag = vv & jnp.isnan(v)  # has_nan
-                            carry[f"nanflag{i}"] = carry[f"nanflag{i}"] | (
-                                jax.ops.segment_max(
-                                    flag.astype(jnp.int32), k,
-                                    num_segments=R) > 0)
-                        else:
-                            info = jnp.iinfo(v.dtype)
-                            sent = jnp.asarray(
-                                info.max if call.fn == "min" else info.min,
-                                v.dtype)
-                            vm = jnp.where(vv, v, sent)
-                        carry[f"mm{i}"] = comb(
-                            carry[f"mm{i}"], red(vm, k, num_segments=R))
-                    elif call.fn in _FIRST_FNS:
-                        pres = (inb if call.fn == "first"
-                                else inb & vcol.valid_mask())
-                        iota = jnp.arange(b.capacity, dtype=jnp.int32)
-                        idx = jax.ops.segment_min(
-                            jnp.where(pres, iota, jnp.int32(b.capacity)),
-                            k, num_segments=R)
-                        bhas = idx < b.capacity
-                        gi = jnp.clip(idx, 0, b.capacity - 1)
-                        bval = vcol.data[gi]
-                        prev = carry[f"fok{i}"]
-                        carry[f"fv{i}"] = jnp.where(
-                            prev, carry[f"fv{i}"],
-                            jnp.where(bhas, bval,
-                                      jnp.zeros((), bval.dtype)))
-                        if call.fn == "first":
-                            bvalid = vcol.valid_mask()[gi] & bhas
-                            carry[f"fvalid{i}"] = jnp.where(
-                                prev, carry[f"fvalid{i}"], bvalid)
-                        carry[f"fok{i}"] = prev | bhas
-                    slots.append((si, ci))
-                words, recipe, layout, weights, bad_vals = \
-                    mxu_agg.digitize(inb, specs,
-                                     fixed_scales=spec_fixed_scales)
+                                data = data.astype(jnp.int64)
+                            vv = (jnp.ones_like(inb) if vcol.validity is None
+                                  else vcol.validity)
+                            specs.append(("sum", data, vv))
+                            si = len(specs) - 1
+                        elif call.fn in _MM_FNS:
+                            vv = inb & vcol.valid_mask()
+                            v = vcol.data
+                            red = (jax.ops.segment_min if call.fn == "min"
+                                   else jax.ops.segment_max)
+                            comb = (jnp.minimum if call.fn == "min"
+                                    else jnp.maximum)
+                            if jnp.issubdtype(v.dtype, jnp.floating):
+                                # Spark NaN order: NaN is the GREATEST value
+                                # (segment.seg_min/seg_max semantics)
+                                nn = vv & ~jnp.isnan(v)
+                                if call.fn == "min":
+                                    sent = jnp.asarray(jnp.inf, v.dtype)
+                                    vm = jnp.where(nn, v, sent)
+                                    flag = nn  # any_nonnan
+                                else:
+                                    sent = jnp.asarray(-jnp.inf, v.dtype)
+                                    vm = jnp.where(vv & ~jnp.isnan(v), v, sent)
+                                    flag = vv & jnp.isnan(v)  # has_nan
+                                carry[f"nanflag{i}"] = carry[f"nanflag{i}"] | (
+                                    jax.ops.segment_max(
+                                        flag.astype(jnp.int32), k,
+                                        num_segments=R) > 0)
+                            else:
+                                info = jnp.iinfo(v.dtype)
+                                sent = jnp.asarray(
+                                    info.max if call.fn == "min" else info.min,
+                                    v.dtype)
+                                vm = jnp.where(vv, v, sent)
+                            carry[f"mm{i}"] = comb(
+                                carry[f"mm{i}"], red(vm, k, num_segments=R))
+                        elif call.fn in _FIRST_FNS:
+                            pres = (inb if call.fn == "first"
+                                    else inb & vcol.valid_mask())
+                            iota = jnp.arange(b.capacity, dtype=jnp.int32)
+                            idx = jax.ops.segment_min(
+                                jnp.where(pres, iota, jnp.int32(b.capacity)),
+                                k, num_segments=R)
+                            bhas = idx < b.capacity
+                            gi = jnp.clip(idx, 0, b.capacity - 1)
+                            bval = vcol.data[gi]
+                            prev = carry[f"fok{i}"]
+                            carry[f"fv{i}"] = jnp.where(
+                                prev, carry[f"fv{i}"],
+                                jnp.where(bhas, bval,
+                                          jnp.zeros((), bval.dtype)))
+                            if call.fn == "first":
+                                bvalid = vcol.valid_mask()[gi] & bhas
+                                carry[f"fvalid{i}"] = jnp.where(
+                                    prev, carry[f"fvalid{i}"], bvalid)
+                            carry[f"fok{i}"] = prev | bhas
+                        slots.append((si, ci))
+                with jax.named_scope("agg.digitize"):
+                    words, recipe, layout, weights, bad_vals = \
+                        mxu_agg.digitize(inb, specs,
+                                         fixed_scales=spec_fixed_scales)
                 # non-finite float inputs (or fixed-scale overflow when
                 # data drifted past the probed magnitude) can't ride
                 # digit planes — treat like out-of-range keys: flag and
                 # let the caller re-probe / fall back
                 carry["oob"] = carry["oob"] | bad_vals
-                acc_b = mxu_agg.accumulate_raw(k, inb, words, recipe, R)
-                carry["acc"] = carry["acc"] + acc_b.astype(jnp.int64)
+                with jax.named_scope("agg.accumulate"):
+                    acc_b = mxu_agg.accumulate_raw(k, inb, words, recipe,
+                                                   R)
+                    carry["acc"] = carry["acc"] + acc_b.astype(jnp.int64)
                 trace_info["layout"] = layout
                 trace_info["slots"] = slots
                 return carry, None
@@ -576,8 +587,9 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
             # recombine ONCE per stage (2^-s applied here, not per
             # batch), then assemble output rows (dense slots ->
             # compacted groups)
-            outs = mxu_agg.finalize(carry["acc"], trace_info["layout"], R,
-                                    scales=spec_fixed_scales)
+            with jax.named_scope("agg.finalize"):
+                outs = mxu_agg.finalize(carry["acc"], trace_info["layout"],
+                                        R, scales=spec_fixed_scales)
             pres = outs[0]
             slots = trace_info["slots"]
             cap = bucket_capacity(R)
@@ -688,8 +700,10 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
                         None))
                     cols.append(Column(T.BOOLEAN, _pad(cnt > 0, cap),
                                        None))
-            out = ColumnBatch(schema, cols, jnp.asarray(R, jnp.int32), cap)
-            out = out.compact(_pad(present, cap))
+            with jax.named_scope("agg.compact_groups"):
+                out = ColumnBatch(schema, cols, jnp.asarray(R, jnp.int32),
+                                  cap)
+                out = out.compact(_pad(present, cap))
             # oob + num_rows in ONE tiny array: each host pull is a round
             # trip
             flags = jnp.stack([carry["oob"].astype(jnp.int32),
@@ -806,8 +820,9 @@ def _run_chain_stage(root: Operator, chain: List[MapLikeOp],
         steps = _build_steps(chain)
 
         def run(*batches):
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *batches)
+            with jax.named_scope("scan.stack"):
+                stacked = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *batches)
 
             def step(_, b):
                 b, mask = _apply_steps(steps, b)

@@ -22,6 +22,26 @@ compilation makes its own instrumentation blind (arxiv 1703.08219):
               supervisor copies the driver's context into pool/
               speculation threads).
 
+              A span record is {id, parent, kind, ts, dur, <ids>, attrs}:
+              `id` is process-unique, `parent` the id of the innermost
+              span open where this one was opened (it rides the same
+              context stack as the ids, so it crosses the supervisor's
+              and the prefetch pool's threads with them), and a span's
+              self time is its `dur` less the union of the spans that
+              name it as parent (records federated from an executor
+              process keep that process's ids beside their `exec`
+              stamp). Kinds, where each is opened, and the
+              benchmark metric that reads it: SPAN_KINDS below. No span
+              reads a device value for an attr or waits on a result:
+              `dispatch`/`h2d` are the host's time in the call, device
+              time comes from the profiler trace only. While a span is
+              open it also holds a jax.profiler.TraceAnnotation
+              "blaze:<kind>" (inert without a profiler session), so a
+              device trace taken over the block carries the program's
+              spans on /host:CPU, on the clock the device plane is laid
+              against; the `clock_anchor` event pairs TRACE.clock() with
+              TRACE.wall() once per process for traces taken elsewhere.
+
   events      `event(kind, **attrs)` records a point: retries, ladder
               rungs, heartbeat misses, deadline kills, speculation
               launch/win/loss, breaker trips, fault injections, spills,
@@ -67,6 +87,7 @@ ID_KEYS = ("query_id", "tenant_id", "stage_id", "task_id", "attempt_id")
 
 _ctx = threading.local()
 _qid_seq = itertools.count(1)
+_span_seq = itertools.count(1)  # next() is atomic under the GIL
 
 
 def new_query_id() -> str:
@@ -205,6 +226,8 @@ EVENT_KINDS = (
     "compile_compiled",     # compile_service: fresh XLA compilation
     "compile_hit",          # compile_service: persistent-cache hit
     "compile_miss",         # compile_service: persistent-cache miss
+    "clock_anchor",         # trace: (TRACE.clock(), TRACE.wall()) pair, once
+                            # per process, to lay spans on a foreign trace
     "capacity_changed",     # service: admission capacity recomputed on
                             # executor-pool membership change
     "control_reconnect",    # executor_pool: worker resumed its control
@@ -284,8 +307,23 @@ EVENT_KINDS = (
 )
 
 SPAN_KINDS = (
+    "collect",       # local_runner._run_result_stage: tasks done -> result
+                     # batch (pulls, host sort, merge, re-upload); collect_s
+    "d2h",           # serde.to_host / ColumnBatch.to_numpy: a device->host
+                     # pull (blocks until the device has the value); the
+                     # caller's last pull is marked final; collect_s
+    "dispatch",      # jit_cache: one call of a cached program, host time
+                     # of the (asynchronous) dispatch; first_call compiles
+    "exchange",      # stage_exchange: one batch through the in-HBM
+                     # exchange, or one unshard of its output; exchange_s
+    "h2d",           # parquet scan / host_sort.host_to_device: host time
+                     # of staging + enqueue, not the transfer; upload_s,
+                     # first_upload_ms
+    "plan",          # local_runner: tagging, conversion, stage split
     "profile",       # trace.profiled_span: device profiler capture
-    "query",         # local_runner: one per query
+    "query",         # local_runner: one per query; query_self_share
+    "scan_decode",   # ops/parquet: one record batch pulled from the
+                     # parquet reader (read + decode); scan_decode_s
     "stage",         # executor: shuffle-map/broadcast/result stage
     "task_attempt",  # supervisor: one per (task, attempt)
 )
@@ -329,9 +367,13 @@ def reset_histograms() -> None:
 
 
 def reset() -> None:
-    """Clear the global log + histograms (test/bench isolation)."""
+    """Clear the global log + histograms (test/bench isolation); the
+    next run_plan records a fresh `clock_anchor` (the old one went
+    with the ring)."""
+    global _anchored
     TRACE.reset()
     reset_histograms()
+    _anchored = False
 
 
 # -- recording ---------------------------------------------------------------
@@ -371,10 +413,12 @@ class _Span:
     """Live span handle: `attrs` may be mutated (or set()) before exit —
     the stage spans learn their transport only after the mesh attempt."""
 
-    __slots__ = ("kind", "attrs", "ids", "t0", "wall0", "_cm", "error")
+    __slots__ = ("id", "kind", "attrs", "ids", "t0", "wall0", "_cm",
+                 "_ann", "error")
 
     def __init__(self, kind: str, ids: Dict[str, Any],
                  attrs: Dict[str, Any]) -> None:
+        self.id = next(_span_seq)
         self.kind = kind
         self.ids = ids
         self.attrs = attrs
@@ -382,6 +426,7 @@ class _Span:
         self.t0 = 0
         self.wall0 = 0
         self._cm = None
+        self._ann = None
 
     def set(self, **kw) -> "_Span":
         self.attrs.update(kw)
@@ -415,9 +460,15 @@ class _SpanCM:
 
     def __enter__(self) -> _Span:
         sp = self.span
+        sp._ann = _annotation(sp)
+        sp._ann.__enter__()
         sp.t0 = TRACE.clock()
         sp.wall0 = TRACE.wall()
-        cm = context(**sp.ids)
+        # the span's id rides the context stack as `parent`: records
+        # opened inside (here or on a thread the context is replayed on)
+        # name this span, and this span's own record, built after the
+        # pop below, names the one around it
+        cm = context(parent=sp.id, **sp.ids)
         cm.__enter__()
         sp._cm = cm
         return sp
@@ -427,8 +478,11 @@ class _SpanCM:
         log = TRACE
         dur = log.clock() - sp.t0
         sp._cm.__exit__(etype, exc, tb)
+        sp._ann.__exit__(etype, exc, tb)
         rec = _base_record("span", sp.kind, dict(sp.attrs))
         rec.update({k: v for k, v in sp.ids.items() if v is not None})
+        rec["id"] = sp.id
+        rec.setdefault("parent", None)
         rec["ts"] = sp.t0
         rec["wall"] = sp.wall0
         rec["dur"] = dur
@@ -438,6 +492,35 @@ class _SpanCM:
             rec["error"] = sp.error
         log.append(rec)
         return False
+
+
+_anchored = False
+
+
+def anchor_clock() -> None:
+    """Record the (TRACE.clock(), TRACE.wall()) pair as a `clock_anchor`
+    event, once per process (and once more after a reset(), which clears
+    the ring it lives in); run_plan calls it before its query span. An
+    exported span file (`ts` monotonic) can then be laid on a trace taken
+    elsewhere (profiler traces carry wall-clock nanoseconds). It carries
+    no query id, so per-query readers never see it."""
+    global _anchored
+    if _anchored or not conf.trace_enabled:
+        return
+    _anchored = True
+    event("clock_anchor", pid=os.getpid())  # its ts and wall are the pair
+
+
+def _annotation(sp: "_Span"):
+    """The profiler annotation "blaze:<kind>" a span holds open beside
+    itself, so a jax.profiler trace taken over it shows the span on
+    /host:CPU on the profiler's own clock. TraceMe is inert while no
+    profiler session is active; only reached with tracing on."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(
+        "blaze:" + sp.kind, span_id=sp.id,
+        **{k: str(v) for k, v in sp.ids.items() if v is not None})
 
 
 def span(kind: str, **attrs):
@@ -602,7 +685,8 @@ def export_chrome_trace(path: str,
     for rec in recs:
         pid = pid_of(rec)
         tid = tid_of(rec, pid)
-        args = {k: rec[k] for k in ID_KEYS if k in rec}
+        args = {k: rec[k] for k in ID_KEYS + ("id", "parent")
+                if rec.get(k) is not None}
         args.update(rec.get("attrs") or {})
         if rec.get("error"):
             args["error"] = rec["error"]

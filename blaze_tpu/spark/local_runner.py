@@ -128,14 +128,20 @@ def run_plan(root: SparkPlan, num_partitions: int = 4,
         # queries live at once, monitor/history attribution must read
         # the per-thread context — the single-slot _active_qid fallback
         # can't name this thread's query
+        trace.anchor_clock()
         with trace.context(query_id=qid, tenant_id=tenant or None):
             with trace.profiled_span("run_plan"):
                 with trace.span("query", query_id=qid,
                                 num_partitions=num_partitions,
                                 mesh_exchange=mesh_exchange):
-                    return _run_plan_inner(root, num_partitions, work_dir,
-                                           mesh_exchange, mesh_quota,
-                                           run_info, session)
+                    out = _run_plan_inner(root, num_partitions, work_dir,
+                                          mesh_exchange, mesh_quota,
+                                          run_info, session)
+                    # the caller's last pull runs after this context is
+                    # popped: the batch remembers whose it is, so
+                    # to_numpy records its d2h span under this query
+                    out._query_id = qid
+                    return out
     finally:
         supervisor_mod._current.session = prev_session
         # the flight recorder needs the query's wall-clock start for its
@@ -203,128 +209,132 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
     run_info.setdefault("map_tasks_run", 0)
     from blaze_tpu.config import conf
 
-    # task setup reclaims dead writers' leftovers (artifact temps in the
-    # work dirs via BlazeShuffleManager, spill files here), and the
-    # trace export dir is bounded to conf.history_retention_runs
-    # (ledger.jsonl lines + trace_<qid>.json files — it grew without
-    # limit before)
-    artifacts.sweep_orphans([conf.spill_dir])
-    # driver-crash recovery (runtime/journal.py): replay incomplete
-    # journals once per process — verified stage commits land in the
-    # resume map each shuffle-map stage consults below
-    journal.ensure_recovery_scan()
-    if conf.trace_export_dir:
-        trace.rotate_export_dir()
-    telemetry_before = faults.TELEMETRY.snapshot()
-    from blaze_tpu.runtime import pipeline
+    # the `plan` span: tagging, conversion, stage split and the set-up
+    # of the stage loop, up to the first stage
+    with trace.span("plan") as psp:
+        # task setup reclaims dead writers' leftovers (artifact temps in the
+        # work dirs via BlazeShuffleManager, spill files here), and the
+        # trace export dir is bounded to conf.history_retention_runs
+        # (ledger.jsonl lines + trace_<qid>.json files — it grew without
+        # limit before)
+        artifacts.sweep_orphans([conf.spill_dir])
+        # driver-crash recovery (runtime/journal.py): replay incomplete
+        # journals once per process — verified stage commits land in the
+        # resume map each shuffle-map stage consults below
+        journal.ensure_recovery_scan()
+        if conf.trace_export_dir:
+            trace.rotate_export_dir()
+        telemetry_before = faults.TELEMETRY.snapshot()
+        from blaze_tpu.runtime import pipeline
 
-    pipeline_before = pipeline.TELEMETRY.snapshot()
-    from blaze_tpu.spark import converters, fallback
+        pipeline_before = pipeline.TELEMETRY.snapshot()
+        from blaze_tpu.spark import converters, fallback
 
-    # per-query resource namespace: concurrent queries both number their
-    # stages from 0, so every shuffle/broadcast registry key is prefixed
-    # with this query's id ("<qid>/shuffle:<sid>")
-    ns = f"{run_info['query_id']}/" if run_info.get("query_id") else ""
-    with _convert_lock:
-        apply_strategy(root)
-        converters.drain_exports()  # discard stale prior conversions
-        stages = plan_stages(root, default_partitions=num_partitions,
-                             namespace=run_info.get("query_id", ""))
-        # Register a row-export iterator for every FFI-bridged
-        # (NeverConvert) subtree — the ConvertToNativeBase.scala:59-98
-        # handshake: the subtree runs on the row engine (fallback.py)
-        # and feeds native FfiReaderExec.
-        exports = converters.drain_exports()
-    for rid, subtree in exports.items():
-        def provider(partition, nparts, _p=subtree):
-            return fallback.export_iterator(_p, partition, nparts)
-        resources.put(rid, provider)
-    # pre-AQE query fingerprint: pins the journal's plan record AND keys
-    # the autopilot's persisted overlay — stable across runs of the same
-    # plan and known before execution (post-AQE shapes are not)
-    query_fp = fingerprint_query([fingerprint_plan(s.plan)
-                                  for s in stages])
-    jnl = (None if run_info.get("stream")
-           else journal.journal_for(run_info.get("query_id", "")))
-    if jnl is not None:
-        # the plan record pins what this journal is a log OF: the
-        # pre-AQE query fingerprint plus the stage skeleton (per-stage
-        # fingerprints — the resume keys — are journaled with each
-        # stage_commit, computed after AQE re-optimization)
-        jnl.plan(fingerprint=query_fp,
-                 num_partitions=num_partitions,
-                 stages=[{"stage_id": s.stage_id, "kind": s.kind,
-                          "num_partitions": s.num_partitions,
-                          "plan_proto": base64.b64encode(
-                              s.plan.SerializeToString()).decode()}
-                         for s in stages])
-    # -- conf overlays + self-tuning autopilot -------------------------
-    # resolve base -> tenant -> per-fingerprint -> per-query pin
-    # (config.resolve_overlay validates each layer against KNOBS); the
-    # values ride a thread-local scope around the stage loop below —
-    # supervisor tasks replay it around every attempt — and the record
-    # with per-value provenance is stamped into run_info for the
-    # ledger / history / flight dossiers
-    from blaze_tpu import config
+        # per-query resource namespace: concurrent queries both number their
+        # stages from 0, so every shuffle/broadcast registry key is prefixed
+        # with this query's id ("<qid>/shuffle:<sid>")
+        ns = f"{run_info['query_id']}/" if run_info.get("query_id") else ""
+        with _convert_lock:
+            apply_strategy(root)
+            converters.drain_exports()  # discard stale prior conversions
+            stages = plan_stages(root, default_partitions=num_partitions,
+                                 namespace=run_info.get("query_id", ""))
+            # Register a row-export iterator for every FFI-bridged
+            # (NeverConvert) subtree — the ConvertToNativeBase.scala:59-98
+            # handshake: the subtree runs on the row engine (fallback.py)
+            # and feeds native FfiReaderExec.
+            exports = converters.drain_exports()
+        for rid, subtree in exports.items():
+            def provider(partition, nparts, _p=subtree):
+                return fallback.export_iterator(_p, partition, nparts)
+            resources.put(rid, provider)
+        # pre-AQE query fingerprint: pins the journal's plan record AND keys
+        # the autopilot's persisted overlay — stable across runs of the same
+        # plan and known before execution (post-AQE shapes are not)
+        query_fp = fingerprint_query([fingerprint_plan(s.plan)
+                                      for s in stages])
+        jnl = (None if run_info.get("stream")
+               else journal.journal_for(run_info.get("query_id", "")))
+        if jnl is not None:
+            # the plan record pins what this journal is a log OF: the
+            # pre-AQE query fingerprint plus the stage skeleton (per-stage
+            # fingerprints — the resume keys — are journaled with each
+            # stage_commit, computed after AQE re-optimization)
+            jnl.plan(fingerprint=query_fp,
+                     num_partitions=num_partitions,
+                     stages=[{"stage_id": s.stage_id, "kind": s.kind,
+                              "num_partitions": s.num_partitions,
+                              "plan_proto": base64.b64encode(
+                                  s.plan.SerializeToString()).decode()}
+                             for s in stages])
+        # -- conf overlays + self-tuning autopilot -------------------------
+        # resolve base -> tenant -> per-fingerprint -> per-query pin
+        # (config.resolve_overlay validates each layer against KNOBS); the
+        # values ride a thread-local scope around the stage loop below —
+        # supervisor tasks replay it around every attempt — and the record
+        # with per-value provenance is stamped into run_info for the
+        # ledger / history / flight dossiers
+        from blaze_tpu import config
 
-    fp_overlay: Dict[str, object] = {}
-    canary_knob = ""
-    if conf.autopilot_enabled and conf.autopilot_dir:
-        from blaze_tpu.runtime import autopilot
+        fp_overlay: Dict[str, object] = {}
+        canary_knob = ""
+        if conf.autopilot_enabled and conf.autopilot_dir:
+            from blaze_tpu.runtime import autopilot
 
-        fp_overlay, canary_knob = autopilot.overlay_for(query_fp)
-    resolved = config.resolve_overlay(
-        tenant=run_info.get("tenant_id") or None,
-        fingerprint_overlay=fp_overlay or None,
-        pin=run_info.get("conf_pins") or None)
-    if canary_knob:
-        resolved.canary = True
-        resolved.canary_knob = canary_knob
-    if resolved.values or (conf.autopilot_enabled and conf.autopilot_dir):
-        run_info["autopilot"] = dict(resolved.as_record(),
-                                     fingerprint=query_fp)
-    if fp_overlay:
-        trace.event("autopilot_apply", fingerprint=query_fp,
-                    overlay_hash=resolved.hash or "",
-                    canary=bool(canary_knob), canary_knob=canary_knob,
-                    knobs=",".join(sorted(fp_overlay)))
-    work_dir = work_dir or tempfile.mkdtemp(prefix="blaze_tpu_stages_")
-    os.makedirs(work_dir, exist_ok=True)
+            fp_overlay, canary_knob = autopilot.overlay_for(query_fp)
+        resolved = config.resolve_overlay(
+            tenant=run_info.get("tenant_id") or None,
+            fingerprint_overlay=fp_overlay or None,
+            pin=run_info.get("conf_pins") or None)
+        if canary_knob:
+            resolved.canary = True
+            resolved.canary_knob = canary_knob
+        if resolved.values or (conf.autopilot_enabled and conf.autopilot_dir):
+            run_info["autopilot"] = dict(resolved.as_record(),
+                                         fingerprint=query_fp)
+        if fp_overlay:
+            trace.event("autopilot_apply", fingerprint=query_fp,
+                        overlay_hash=resolved.hash or "",
+                        canary=bool(canary_knob), canary_knob=canary_knob,
+                        knobs=",".join(sorted(fp_overlay)))
+        work_dir = work_dir or tempfile.mkdtemp(prefix="blaze_tpu_stages_")
+        os.makedirs(work_dir, exist_ok=True)
 
-    # the shuffle-manager drop-in tracks map outputs (MapStatus) and
-    # serves reduce-side readers — the role BlazeShuffleManager plays as
-    # spark.shuffle.manager in deployment
-    from blaze_tpu.spark.shuffle_manager import BlazeShuffleManager
+        # the shuffle-manager drop-in tracks map outputs (MapStatus) and
+        # serves reduce-side readers — the role BlazeShuffleManager plays as
+        # spark.shuffle.manager in deployment
+        from blaze_tpu.spark.shuffle_manager import BlazeShuffleManager
 
-    shuffle_mgr = BlazeShuffleManager(work_dir)
-    # AQE statistics: completed shuffles' total bytes + partition counts
-    shuffle_bytes: Dict[int, int] = {}
-    shuffle_parts: Dict[int, int] = {}
+        shuffle_mgr = BlazeShuffleManager(work_dir)
+        # AQE statistics: completed shuffles' total bytes + partition counts
+        shuffle_bytes: Dict[int, int] = {}
+        shuffle_parts: Dict[int, int] = {}
 
-    from blaze_tpu.spark.aqe import apply_dynamic_join_selection
+        from blaze_tpu.spark.aqe import apply_dynamic_join_selection
 
-    # the task supervisor owns this query's worker pool, watchdog (hang
-    # detection + deadlines), straggler speculation and the per-operator
-    # circuit breaker (runtime/supervisor.py); disabled it degrades each
-    # stage to the sequential inline path. Under the service the session
-    # routes tasks through the SHARED fair scheduler and carries the
-    # admission-stamped query deadline; breaker state stays per-query
-    # (one CircuitBreaker per Supervisor, one Supervisor per run_plan).
-    sup = Supervisor(run_info, session=session)
-    # process-isolated executors (runtime/executor_pool.py): when a pool
-    # is active, eligible shuffle-map stages ship their task plans to
-    # worker PROCESSES (crash containment) instead of the thread pool;
-    # the pool failing degrades back to the in-process path below
-    from blaze_tpu.runtime import executor_pool
+        # the task supervisor owns this query's worker pool, watchdog (hang
+        # detection + deadlines), straggler speculation and the per-operator
+        # circuit breaker (runtime/supervisor.py); disabled it degrades each
+        # stage to the sequential inline path. Under the service the session
+        # routes tasks through the SHARED fair scheduler and carries the
+        # admission-stamped query deadline; breaker state stays per-query
+        # (one CircuitBreaker per Supervisor, one Supervisor per run_plan).
+        sup = Supervisor(run_info, session=session)
+        # process-isolated executors (runtime/executor_pool.py): when a pool
+        # is active, eligible shuffle-map stages ship their task plans to
+        # worker PROCESSES (crash containment) instead of the thread pool;
+        # the pool failing degrades back to the in-process path below
+        from blaze_tpu.runtime import executor_pool
 
-    pool = executor_pool.active()
-    # live-introspection taps (runtime/progress.py): conditional import
-    # once per run, one is-None check per stage — zero work when off
-    if conf.progress_enabled:
-        from blaze_tpu.runtime import progress
-    else:
-        progress = None
-    qid = run_info.get("query_id", "")
+        pool = executor_pool.active()
+        # live-introspection taps (runtime/progress.py): conditional import
+        # once per run, one is-None check per stage — zero work when off
+        if conf.progress_enabled:
+            from blaze_tpu.runtime import progress
+        else:
+            progress = None
+        qid = run_info.get("query_id", "")
+        psp.set(stages=len(stages))
     _ov = None
     try:
         if resolved.values:
@@ -1042,11 +1052,7 @@ def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
     """`parts` is the upstream exchange's partition count (_input_tasks) —
     NOT the global default: an 8-way repartition read with 4 tasks would
     silently drop half the shuffle partitions."""
-    from blaze_tpu.columnar import serde
     from blaze_tpu.ops import host_sort
-    from blaze_tpu.ops.basic import GlobalLimitExec
-    from blaze_tpu.ops.sort import SortExec, truncate
-    from blaze_tpu.ops.sort_keys import sort_batch
     from blaze_tpu.runtime.stage_compiler import try_run_stage
 
     op = decode_plan(stage.plan)
@@ -1083,6 +1089,22 @@ def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
     batches: List[ColumnBatch] = []
     for lst in sup.run_tasks(("result", stage.stage_id), specs):
         batches.extend(lst)
+    # the collect layer: from the tasks' return to the result batch
+    # (pulls, host sort, merge, re-upload)
+    with trace.span("collect", partitions=parts, batches=len(batches),
+                    host_sorted=split is not None) as sp:
+        return _collect_result(stage, op, split, batches, parts, sup,
+                               run_info, sp)
+
+
+def _collect_result(stage: Stage, op, split, batches: List[ColumnBatch],
+                    parts: int, sup: Supervisor, run_info,
+                    sp) -> ColumnBatch:
+    from blaze_tpu.columnar import serde
+    from blaze_tpu.ops import host_sort
+    from blaze_tpu.ops.basic import GlobalLimitExec
+    from blaze_tpu.ops.sort import SortExec, truncate
+    from blaze_tpu.ops.sort_keys import sort_batch
 
     if split is not None:
         specs, limit, _ = split
@@ -1103,6 +1125,7 @@ def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
             if limit is not None:
                 perm = perm[:limit]
             hb = host_sort.host_take(hb, perm)
+            sp.set(rows=hb.num_rows)
             out = host_sort.host_to_device(hb)
             out._host_numpy = host_sort.host_to_pylike(hb)
             return out

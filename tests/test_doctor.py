@@ -73,7 +73,7 @@ def _task_span(sid, tid, dur_ms, attrs=None, error=None):
 def test_breakdown_sums_to_wall_exactly():
     cp = doctor.compute_critical_path(_rec(
         total=1000.0, admission=500.0,
-        counters={"serde_encode_ms": 100.0, "device_compute_ms": 300.0,
+        counters={"serde_encode_ms": 100.0, "fused_dispatch_ms": 300.0,
                   "compile_ms": 50.0}))
     assert cp["total_ms"] == 1500.0
     assert abs(sum(cp["terms"].values()) - cp["total_ms"]) < 0.01
@@ -89,11 +89,11 @@ def test_concurrent_terms_scale_into_the_span():
     # breakdown STILL sums to the measured wall time
     cp = doctor.compute_critical_path(_rec(
         total=1000.0,
-        counters={"device_compute_ms": 2800.0, "serde_decode_ms": 200.0}))
+        counters={"fused_dispatch_ms": 2800.0, "serde_decode_ms": 200.0}))
     assert cp["parallel_scale"] == pytest.approx(1000.0 / 3000.0, rel=1e-3)
     assert abs(sum(cp["terms"].values()) - cp["total_ms"]) < 0.01
     assert cp["terms"]["residual"] == 0.0
-    assert cp["top_term"] == "device_compute"
+    assert cp["top_term"] == "fused_dispatch"
 
 
 def test_longest_chain_per_stage_is_deterministic():
@@ -128,7 +128,7 @@ def test_small_clean_queries_stay_finding_free():
     # never page the oncall
     findings = doctor.diagnose(_rec(
         total=90.0,
-        counters={"serde_encode_ms": 30.0, "device_compute_ms": 40.0,
+        counters={"serde_encode_ms": 30.0, "fused_dispatch_ms": 40.0,
                   "compile_ms": 10.0}))
     assert findings == []
 
