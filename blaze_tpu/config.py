@@ -160,20 +160,24 @@ _DECLARATIONS: Tuple[Knob, ...] = (
              "(ops/common.adaptive_batch_rows).",
          step=2.0, min=16 << 10, max=1 << 30, geometric=True),
     Knob("max_batch_rows", 1 << 21,
-         doc="Hard row cap on adaptive macro-batches."),
+         doc="Hard row cap on adaptive macro-batches. Its capacity bucket "
+             "is also where shape canonicalization's power-of-four rungs "
+             "stop: a batch at or above it is never repadded."),
     Knob("aqe_broadcast_threshold", 10 << 20,
          doc="AQE dynamic join selection: a planned SMJ whose shuffled "
              "input came in under this many bytes becomes a broadcast "
              "join (Spark autoBroadcastJoinThreshold analog; 0 "
              "disables)."),
     Knob("enable_compile_canonicalization", True,
-         doc="Compile-service shape canonicalization: above "
-             "canonical_pow2_limit, power-of-two capacity buckets "
-             "collapse onto power-of-four rungs, halving the large end "
-             "of the compiled-program shape space."),
+         doc="Compile-service shape canonicalization: between "
+             "canonical_pow2_limit and the full macro-batch capacity "
+             "(max_batch_rows' bucket), power-of-two capacity buckets "
+             "collapse onto power-of-four rungs, halving the "
+             "data-dependent part of the compiled-program shape space."),
     Knob("canonical_pow2_limit", 1 << 14,
          doc="Capacity above which canonicalization switches to "
-             "power-of-four rungs."),
+             "power-of-four rungs (up to the full macro-batch capacity, "
+             "where each bucket is its own rung again)."),
     Knob("profiler_dir", "", env="BLAZE_TPU_PROFILE_DIR",
          doc="JAX profiler trace output dir ('' disables) — consumed by "
              "trace.profiled_span (jax.profiler TensorBoard captures "

@@ -156,12 +156,20 @@ def canonical_capacity(n: int) -> int:
     power-of-four rungs anchored at the limit: 2^14, 2^16, 2^18, ... —
     each rung absorbs two pow2 buckets, halving the large end of the
     shape space where compiles are slowest.
+
+    The rungs stop at the full macro-batch capacity,
+    bucket_capacity(conf.max_batch_rows): from there up the bucket is its
+    own rung.  That capacity is no data-dependent shape — a scan emits it
+    for every batch but its tail, so a program that sees it runs once per
+    macro-batch, and padding it doubles that program's device work on
+    every batch to save one compile per program kind.
     """
     from blaze_tpu.columnar.batch import bucket_capacity
 
     cap = bucket_capacity(n)
     limit = int(conf.canonical_pow2_limit)
-    if not conf.enable_compile_canonicalization or cap <= limit or limit <= 0:
+    if (not conf.enable_compile_canonicalization or cap <= limit
+            or limit <= 0 or cap >= bucket_capacity(conf.max_batch_rows)):
         return cap
     base_exp = limit.bit_length() - 1
     exp = cap.bit_length() - 1
@@ -265,6 +273,7 @@ def fingerprint() -> str:
         "float_sum_digit_planes": conf.float_sum_digit_planes,
         "canonicalization": conf.enable_compile_canonicalization,
         "canonical_pow2_limit": conf.canonical_pow2_limit,
+        "max_batch_rows": conf.max_batch_rows,
     }
     blob = json.dumps(payload, sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()[:16]

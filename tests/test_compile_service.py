@@ -100,6 +100,126 @@ def test_sort_correct_at_bucket_boundaries(rng):
         np.testing.assert_array_equal(got, np.sort(data.astype(np.float32)))
 
 
+# The power-of-four rungs stop at the full macro-batch capacity,
+# bucket_capacity(conf.max_batch_rows): (max_batch_rows, rows, rung).
+# None = the default (2^21).  1 << 17 sits an odd number of doublings above
+# the limit (as 2^21 does), 1 << 18 an even number, 100_000 is no power of
+# two (its bucket, 2^17, is the bound).
+_MACRO_CASES = [
+    (None, 1 << 19, 1 << 20), (None, 1 << 20, 1 << 20),
+    (None, 1 << 21, 1 << 21), (None, (1 << 21) + 1, 1 << 22),
+    (None, 1 << 22, 1 << 22), (None, 1 << 23, 1 << 23),
+    (1 << 17, 1 << 15, 1 << 16), (1 << 17, 1 << 16, 1 << 16),
+    (1 << 17, 1 << 17, 1 << 17), (1 << 17, (1 << 17) + 1, 1 << 18),
+    (1 << 17, 1 << 18, 1 << 18), (1 << 17, 1 << 19, 1 << 19),
+    (1 << 18, 1 << 17, 1 << 18), (1 << 18, 1 << 18, 1 << 18),
+    (1 << 18, 1 << 19, 1 << 19),
+    (100_000, 1 << 15, 1 << 16), (100_000, 100_000, 1 << 17),
+    (100_000, 1 << 19, 1 << 19),
+]
+
+
+@pytest.mark.parametrize("max_rows,n,want", _MACRO_CASES)
+def test_canonical_capacity_round_the_macro_batch(monkeypatch, max_rows, n,
+                                                  want):
+    assert conf.max_batch_rows == 1 << 21
+    assert conf.canonical_pow2_limit == 1 << 14
+    if max_rows is not None:
+        monkeypatch.setattr(conf, "max_batch_rows", max_rows)
+    assert cs.canonical_capacity(n) == want
+
+
+def _canon_pad_calls():
+    return sum(e["hits"] + e["misses"]
+               for e in cs.registry().entries.values()
+               if e["kind"] == "canon_pad")
+
+
+def test_full_macro_batch_reaches_the_collapse_unpadded(rng, monkeypatch):
+    """A partial agg over one batch at the full macro-batch capacity: the
+    collapse runs at the batch's own capacity, nothing is repadded, and
+    the groups equal pandas'."""
+    import pandas as pd
+
+    from blaze_tpu.columnar import FLOAT64
+    from blaze_tpu.ops.agg import AggCall, AggExec, AggMode
+
+    n = conf.canonical_pow2_limit * 2  # 2^15: on no power-of-four rung
+    monkeypatch.setattr(conf, "max_batch_rows", n)
+    monkeypatch.setattr(conf, "enable_stage_compiler", False)
+    schema = Schema([Field("k", INT64), Field("v", FLOAT64)])
+    k = rng.integers(0, 900, n).astype(np.int64)
+    v = rng.random(n) * 10 - 5
+    batch = ColumnBatch.from_numpy({"k": k, "v": v}, schema)
+    assert batch.capacity == n
+
+    capacities = []
+    collapse = AggExec._collapse
+
+    def spy(self, batches, raw_input):
+        out = collapse(self, batches, raw_input)
+        capacities.append((sum(b.capacity for b in batches), out.capacity))
+        return out
+
+    monkeypatch.setattr(AggExec, "_collapse", spy)
+    calls = [AggCall("sum", (col("v"),), FLOAT64, "s"),
+             AggCall("count", (col("v"),), INT64, "c"),
+             AggCall("avg", (col("v"),), FLOAT64, "a")]
+    agg = AggExec(MemorySourceExec([batch], schema), [col("k")], ["k"],
+                  calls, AggMode.PARTIAL)
+    waste0 = cs.TELEMETRY["canonicalization_waste_rows"]
+    pads0 = _canon_pad_calls()
+    out = collect(agg)
+    assert cs.TELEMETRY["canonicalization_waste_rows"] == waste0
+    assert _canon_pad_calls() == pads0
+    assert capacities == [(n, n)]  # one collapse, state at the input's size
+
+    rows = int(out.num_rows)
+    got = pd.DataFrame({
+        "k": np.asarray(out.columns[0].data)[:rows],
+        "s": np.asarray(out.columns[1].data)[:rows],
+        "c": np.asarray(out.columns[3].data)[:rows],
+    }).sort_values("k").reset_index(drop=True)
+    want = (pd.DataFrame({"k": k, "v": v}).groupby("k")["v"]
+            .agg(["sum", "count"]).reset_index())
+    np.testing.assert_array_equal(got["k"], want["k"])
+    np.testing.assert_array_equal(got["c"], want["count"])
+    np.testing.assert_allclose(got["s"], want["sum"], rtol=1e-9)
+
+
+def _yz(y, schema):
+    return ColumnBatch.from_numpy(
+        {"y": y, "z": np.zeros(len(y), np.float32)}, schema)
+
+
+def test_below_the_macro_batch_rungs_still_share_a_sort_program(
+        rng, monkeypatch):
+    """With the bound lowered to 2^17, 2^15- and 2^16-bucket inputs still
+    meet on the 2^16 rung (one sort program, waste charged), and a batch
+    at 2^17 sorts at 2^17, not 2^18."""
+    monkeypatch.setattr(conf, "max_batch_rows", 1 << 17)
+    # a layout no other case of this file sorts: its programs are new here
+    schema = Schema([Field("y", INT64), Field("z", FLOAT32)])
+    before = _sort_kernel_keys()
+    waste0 = cs.TELEMETRY["canonicalization_waste_rows"]
+    for n in ((1 << 14) + 5, 1 << 16):
+        data = rng.integers(0, 1 << 40, n).astype(np.int64)
+        sb = sorted_batch_jit(_yz(data, schema), [SortSpec(0)])
+        assert sb.capacity == 1 << 16
+        np.testing.assert_array_equal(
+            np.asarray(sb.columns[0].data)[:n], np.sort(data))
+    assert len(_sort_kernel_keys() - before) == 1
+    assert cs.TELEMETRY["canonicalization_waste_rows"] == waste0 + (1 << 15)
+
+    data = rng.integers(0, 1 << 40, 1 << 17).astype(np.int64)
+    sb = sorted_batch_jit(_yz(data, schema), [SortSpec(0)])
+    assert sb.capacity == 1 << 17
+    np.testing.assert_array_equal(np.asarray(sb.columns[0].data),
+                                  np.sort(data))
+    assert len(_sort_kernel_keys() - before) == 2
+    assert cs.TELEMETRY["canonicalization_waste_rows"] == waste0 + (1 << 15)
+
+
 def test_stage_batch_count_padding_matches_streaming(rng):
     """A 3-batch chain stage (padded to the 4 rung) returns exactly the
     streaming engine's rows."""
