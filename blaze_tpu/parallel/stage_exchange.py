@@ -12,10 +12,16 @@ sort-repartitioner's spill path, shuffle/sort_repartitioner.rs:199-213).
 The partition function is the same Spark-murmur3+pmod as the file path
 (exprs/hash.py), so a partition's row multiset is identical on either
 path and readers cannot tell them apart.
+
+On a host of several chips a partition lives on the chip that owns it
+(runtime/placement.py) from the all_to_all to the task that consumes it:
+cut out of that chip's shard of the output, handed over there, and, where
+the consuming stage exchanges again, sent on from there.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import List, Optional
 
 import jax
@@ -28,8 +34,19 @@ from blaze_tpu.columnar.types import Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.ops.base import ExecContext
 from blaze_tpu.plan import plan_pb2 as pb
-from blaze_tpu.runtime import resources, trace
+from blaze_tpu.runtime import placement, resources, trace
 from blaze_tpu.runtime.executor import execute_plan
+
+
+_collective_lock = threading.Lock()
+
+
+def live_nbytes(batch: ColumnBatch, nrows: int) -> int:
+    """Bytes of a batch's live rows: batch_nbytes counts the padded
+    capacity bucket."""
+    from blaze_tpu.runtime.memory import batch_nbytes
+
+    return batch_nbytes(batch) * nrows // max(batch.capacity, 1)
 
 
 def mesh_key_indices(writer: pb.ShuffleWriterNode,
@@ -57,7 +74,8 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                            ntasks: int, quota: Optional[int] = None,
                            work_dir: Optional[str] = None,
                            stats: Optional[dict] = None,
-                           namespace: str = "") -> bool:
+                           namespace: str = "", sup=None,
+                           task_devices: Optional[list] = None) -> bool:
     """Execute one shuffle_map stage's exchange over the device mesh.
 
     STREAMS: each map-output batch is exchanged as it is produced — the
@@ -69,26 +87,40 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     never re-execute; the reduce-side provider serves mesh-received
     batches and file segments transparently.
 
+    On several chips partition p's received rows stay on the chip that
+    owns p (runtime/placement.py): they are cut out of that chip's own
+    shard of the all_to_all's output, there, and the provider hands them
+    to a consumer placed on that chip as they are: no exchanged row
+    crosses the host. `task_devices` (one chip per map task, from the
+    runner) says this stage's own tasks read such partitions: they run
+    placed on `sup`'s pool, each on its chip, and what they produce
+    enters the next all_to_all from where it lies, one batch per chip in
+    lock step. Only the calling (driver) thread launches the collective
+    program; task threads launch single-device programs only.
+
     Returns False — with nothing registered, nothing executed — only when
     the stage can't ride the mesh at all (shape/keys/partition count).
     """
     import os
     import tempfile
 
+    from jax.sharding import NamedSharding
+
+    from blaze_tpu.config import conf
     from blaze_tpu.ops.basic import MemorySourceExec
+    from blaze_tpu.ops.common import slice_batch
     from blaze_tpu.ops.shuffle import ShuffleWriterExec, read_shuffle_partition
     from blaze_tpu.plan import decode_plan
     from blaze_tpu.plan.from_proto import _partitioning
-    from blaze_tpu.runtime import jit_cache
+    from blaze_tpu.runtime import faults, jit_cache
+    from blaze_tpu.runtime.executor import execute_stage_or_plan
+    from blaze_tpu.runtime.memory import batch_nbytes, get_manager
 
     writer = stage_plan.shuffle_writer
-    num_partitions = writer.partitioning.num_partitions
+    Pn = writer.partitioning.num_partitions
     devices = jax.devices()
-    if num_partitions < 2:
+    if Pn < 2:
         return False
-    from blaze_tpu.config import conf
-    from blaze_tpu.runtime import faults
-
     if conf.fault_injection_spec:
         faults.inject("exchange.stage")
     input_op = decode_plan(writer.input)
@@ -99,23 +131,31 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         return False  # variable element capacities can't stack on the mesh
 
     schema = input_op.schema
-    Pn = num_partitions
     # P > D (VERDICT r4 #7): device d OWNS the contiguous partition block
     # [d*k, (d+1)*k), k = ceil(P/D). With one device the "exchange" is
     # purely local grouping — partitions stay in HBM with no all_to_all
     # and no host round trip at all, where the file exchange would pull
     # every map output to the host and write it out.
-    use_d = min(len(devices), Pn)
-    kpd = -(-Pn // use_d)
-    use_d = -(-Pn // kpd)  # drop devices left with no partitions
-    mesh = (Mesh(np.array(devices[:use_d]), ("p",)) if use_d > 1 else None)
-    recv_parts: List[List[ColumnBatch]] = [[] for _ in range(Pn)]
+    use_d, kpd = placement.layout(Pn, len(devices))
+    mesh_devs = devices[:use_d]
+    mesh = Mesh(np.array(mesh_devs), ("p",)) if use_d > 1 else None
+    # (batch, live rows) per partition: the exchange hands the counts over
+    recv_parts: List[List[tuple]] = [[] for _ in range(Pn)]
     file_outputs: List[tuple] = []
+    # Exchanged partitions stay PINNED in HBM until the consuming stage
+    # finishes, so the mesh path honors the memory budget, chip by chip:
+    # once the bytes pinned on any chip pass half of a chip's budget, what
+    # is left takes the file path (the reduce side reads both alike).
+    budget = get_manager().total // 2
+    pinned = [0] * use_d
+
+    def keep(p: int, b: ColumnBatch, nrows: int) -> None:
+        recv_parts[p].append((b, nrows))
+        pinned[p // kpd] += batch_nbytes(b)
 
     def exchange_local(batch: ColumnBatch) -> bool:
         """Single-device exchange: group by partition id on device, slice
         per partition; one host pull (the bounds) per macro-batch."""
-        from blaze_tpu.ops.common import slice_batch
         from blaze_tpu.parallel.shuffle import partition_ids
 
         key = ("local_xchg", Pn, tuple(key_idx), batch.shape_key())
@@ -141,37 +181,72 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
             for p in range(Pn):
                 n = int(bounds[p + 1]) - int(bounds[p])
                 if n:
-                    recv_parts[p].append(
-                        slice_batch(sb, int(bounds[p]), n))
+                    keep(p, slice_batch(sb, int(bounds[p]), n), n)
             sp.set(rows=int(bounds[Pn]) - int(bounds[0]))
         return True
 
-    def exchange_batch(batch: ColumnBatch) -> bool:
-        """Exchange one batch over the mesh; False on quota overflow."""
-        if use_d == 1:
-            return exchange_local(batch)
-        with trace.span("exchange", transport="mesh", partitions=Pn,
-                        devices=use_d, capacity=batch.capacity) as sp:
-            return exchange_mesh(batch, sp)
-
-    def exchange_mesh(batch: ColumnBatch, sp) -> bool:
-        n = int(batch.num_rows)
-        sp.set(rows=n)
+    def deal_out(batch: ColumnBatch, n: int) -> list:
+        """A batch that lies on one chip as one shard per mesh device:
+        equal shares of its rows at their capacity bucket, cut where the
+        batch lies and sent chip to chip."""
         per = max(1, -(-n // use_d))
         cap = bucket_capacity(per)
+        shards = []
+        with placement.on_device(placement.device_of(batch.columns)):
+            for i, dev in enumerate(mesh_devs):
+                rows = min(max(n - i * per, 0), per)
+                cut = batch.take(
+                    jnp.arange(cap, dtype=jnp.int32) + i * per, rows)
+                shards.append((jax.device_put(cut, dev), rows))
+        return shards
+
+    def fit(b: ColumnBatch, cap: int) -> ColumnBatch:
+        """`b` at capacity `cap` (no less than its live rows), where it
+        lies: every shard of a round has the round's capacity."""
+        if b.capacity == cap:
+            return b
+        key = ("mesh_fit", cap, tuple(schema.fields), b.shape_key())
+
+        def make():
+            def run(x):
+                idx = jnp.minimum(jnp.arange(cap, dtype=jnp.int32),
+                                  x.capacity - 1)
+                return x.take(idx, x.num_rows)
+
+            return run
+
+        with placement.on_device(placement.device_of(b.columns)):
+            return jit_cache.get_or_compile(key, make)(b)
+
+    sharding = NamedSharding(mesh, P("p")) if mesh is not None else None
+
+    def exchange_round(shards: list) -> bool:
+        """One all_to_all. `shards[d]` is (batch on mesh device d, its
+        live rows), or None for a chip with nothing to send; the batches
+        have one layout. Each partition's received rows are cut out of
+        its owner's shard of the output, on that chip. False on quota
+        overflow, with nothing kept."""
+        rows = [0 if s is None else s[1] for s in shards]
+        cap = bucket_capacity(max(rows))
+        fitted = [None if s is None else fit(s[0], cap) for s in shards]
+        some = next(b for b in fitted if b is not None)
+        cols = []
+        for dev, b in zip(mesh_devs, fitted):
+            if b is None:  # an empty shard of the round's layout
+                with placement.on_device(dev):
+                    b = jax.device_put(jax.tree.map(jnp.zeros_like, some),
+                                       dev)
+            cols.append(b.columns)
         # quota: rows one device may send one OWNER device (k partitions)
         q = min(quota * kpd, cap) if quota else cap
-        slices = [
-            batch.take(jnp.arange(cap, dtype=jnp.int32) + i * per,
-                       min(max(n - i * per, 0), per))
-            for i in range(use_d)
-        ]
-        cols = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0),
-                            *[b.columns for b in slices])
-        num_rows = jnp.array([int(b.num_rows) for b in slices], jnp.int32)
+
+        def glue(*xs):
+            return jax.make_array_from_single_device_arrays(
+                (use_d * xs[0].shape[0],) + xs[0].shape[1:], sharding,
+                list(xs))
 
         key = ("mesh_xchg", Pn, use_d, cap, q, tuple(key_idx),
-               slices[0].shape_key())
+               some.shape_key())
 
         def make():
             def step(local_cols, local_num_rows):
@@ -189,36 +264,44 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                                  out_specs=(P("p"), P("p"), P("p")))
 
         run = jit_cache.get_or_compile(key, make)
-        out_cols, out_counts, overflow = run(cols, num_rows)
+        # one collective program is enqueued on all chips at a time: two
+        # queries' drivers entering theirs in different orders on
+        # different chips would deadlock
+        with _collective_lock:
+            out_cols, out_counts, overflow = run(
+                jax.tree.map(glue, *cols),
+                jax.device_put(np.asarray(rows, np.int32), sharding))
         if int(np.asarray(overflow).sum()) > 0:
             return False
+        leaves, treedef = jax.tree.flatten(out_cols)
         if stats is not None:
             # devices the shard_map's output actually sits on
-            stats["devices"] = len(
-                jax.tree_util.tree_leaves(out_cols)[0].devices())
+            stats["devices"] = len(leaves[0].devices())
         out_counts = np.asarray(out_counts)  # (use_d, kpd)
         recv_cap = use_d * q  # per-device received capacity
-        full = ColumnBatch(schema, out_cols, jnp.asarray(0, jnp.int32),
-                           use_d * recv_cap)
-        for d in range(use_d):
-            off = 0
-            for j in range(kpd):
-                p = d * kpd + j
-                nrows = int(out_counts[d, j])
-                if p >= Pn or nrows == 0:
+        local = [{sh.device: sh.data for sh in x.addressable_shards}
+                 for x in leaves]
+        for d, dev in enumerate(mesh_devs):
+            got = int(out_counts[d].sum())
+            if not got:
+                continue
+            with placement.on_device(dev):
+                mine = ColumnBatch(
+                    schema, treedef.unflatten([m[dev] for m in local]),
+                    jnp.asarray(got, jnp.int32), recv_cap)
+                off = 0
+                for j in range(kpd):
+                    p, nrows = d * kpd + j, int(out_counts[d, j])
+                    if p < Pn and nrows:
+                        # compact to the rows' own capacity bucket:
+                        # retaining the full staging capacity per slice
+                        # would pin O(batches * D^2 * q) padded rows in
+                        # HBM across the stage
+                        keep(p, slice_batch(mine, off, nrows), nrows)
                     off += nrows
-                    continue
-                # compact to the rows' own capacity bucket: retaining the
-                # full staging capacity per slice would pin
-                # O(batches * D^2 * q) padded rows in HBM across the stage
-                cap_p = bucket_capacity(nrows)
-                idx = jnp.arange(cap_p, dtype=jnp.int32) + \
-                    (d * recv_cap + off)
-                recv_parts[p].append(full.take(idx, nrows))
-                off += nrows
         return True
 
-    def spill_batch_to_file(batch: ColumnBatch) -> None:
+    def spill_batch_to_file(batch: ColumnBatch, n: int) -> None:
         nonlocal work_dir
         if work_dir is None:
             work_dir = tempfile.mkdtemp(prefix="blaze_tpu_mesh_ovf_")
@@ -228,45 +311,91 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         op = ShuffleWriterExec(MemorySourceExec([batch], schema),
                                _partitioning(writer.partitioning),
                                data, index)
-        list(execute_plan(op, ExecContext(partition=0, num_partitions=1)))
+        # on a mesh these are the exchanged rows that do cross the host
+        moved = live_nbytes(batch, n) if mesh is not None else 0
+        with trace.span("exchange", transport="file", partitions=Pn,
+                        capacity=batch.capacity, rows=n, host_bytes=moved):
+            list(execute_plan(op, ExecContext(partition=0,
+                                              num_partitions=1)))
+        if stats is not None:
+            stats["host_bytes"] = stats.get("host_bytes", 0) + moved
         file_outputs.append((data, index))
 
-    # map side: stream every task's batches straight into the exchange
-    # (whole-stage single-dispatch where the subtree matches). Exchanged
-    # partitions stay PINNED in HBM until the consuming stage finishes,
-    # so the mesh path honors the memory budget: once pinned bytes pass
-    # half the budget, the remaining batches take the file path (the
-    # reduce side reads both transparently).
-    from blaze_tpu.runtime.executor import execute_stage_or_plan
-    from blaze_tpu.runtime.memory import batch_nbytes, get_manager
+    def send(shards: list, whole: Optional[tuple] = None) -> None:
+        """A round through the all_to_all, or through files where a chip
+        is over its budget or the quota overflows (`whole`: the batch the
+        shards were dealt out of, which then goes as one file)."""
+        if max(pinned) <= budget:
+            with trace.span("exchange", transport="mesh", partitions=Pn,
+                            devices=use_d, host_bytes=0) as sp:
+                if conf.trace_enabled:
+                    live = [s for s in shards if s is not None]
+                    sp.set(rows=sum(n for _, n in live),
+                           bytes=sum(live_nbytes(b, n) for b, n in live),
+                           capacity=max(b.capacity for b, _ in live))
+                if exchange_round(shards):
+                    return
+        for s in shards if whole is None else [whole]:
+            if s is not None and s[1]:
+                spill_batch_to_file(*s)
 
-    budget = get_manager().total // 2
-    pinned = 0
-    for task in range(ntasks):
+    def map_task(ctx: ExecContext) -> list:
+        """One map task's non-empty output batches with their rows."""
         op = decode_plan(writer.input)  # fresh operator state per task
-        for batch in execute_stage_or_plan(
-                op, ExecContext(partition=task, num_partitions=ntasks)):
-            if int(batch.num_rows) == 0:
-                continue
-            if pinned > budget or not exchange_batch(batch):
-                spill_batch_to_file(batch)
-            else:
-                pinned += batch_nbytes(batch)
+        out = []
+        for batch in execute_stage_or_plan(op, ctx):
+            n = int(batch.num_rows)
+            if n:
+                out.append((batch, n))
+        return out
 
-    def _unshard(x):
-        # Batches sliced out of the shard_map output stay committed
-        # across the mesh devices. Downstream task programs are
-        # single-device: feeding them multi-device pytrees trips XLA
-        # buffer mismatches (and a fresh compile against them can wait on
-        # collectives that never run). Round-trip through host to an
-        # UNCOMMITTED default-device array — committed placement would
-        # break a later mesh stage's shard_map instead. Single-device
-        # leaves (the real-chip case) pass through untouched.
-        import numpy as np
+    if mesh is None or sup is None or task_devices is None or ntasks < 2:
+        # map side: the tasks one after another on this thread, every
+        # batch straight into the exchange as it is produced (whole-stage
+        # single-dispatch where the subtree matches); on a mesh a batch
+        # lies on one chip and is dealt out from there
+        for task in range(ntasks):
+            op = decode_plan(writer.input)
+            for batch in execute_stage_or_plan(
+                    op, ExecContext(partition=task, num_partitions=ntasks)):
+                n = int(batch.num_rows)
+                if n == 0:
+                    continue
+                if mesh is not None:
+                    send(deal_out(batch, n), (batch, n))
+                elif pinned[0] > budget or not exchange_local(batch):
+                    spill_batch_to_file(batch, n)
+    else:
+        # map side, placed: the tasks read partitions that lie on their
+        # chips, so each runs there, on the supervisor's pool; their
+        # batches then go through the all_to_all from where they lie, one
+        # per chip and round
+        from blaze_tpu.runtime.supervisor import TaskSpec
 
-        if hasattr(x, "devices") and len(x.devices()) > 1:
-            return jnp.asarray(np.asarray(x))
-        return x
+        outs = sup.run_tasks(("mesh_map", stage_id), [
+            TaskSpec(what=f"mesh_map[{stage_id}:{t}]", attempt_fn=map_task,
+                     partition=t, num_partitions=ntasks,
+                     device=task_devices[t]) for t in range(ntasks)])
+        queues: List[list] = [[] for _ in mesh_devs]
+        for out in outs:
+            for b, n in out:
+                dev = placement.device_of(b.columns)
+                if dev not in mesh_devs:  # a wider mesh fed this stage
+                    dev = mesh_devs[dev.id % use_d]
+                    b = jax.device_put(b, dev)
+                queues[mesh_devs.index(dev)].append((b, n))
+        for r in range(max(len(q) for q in queues)):
+            # batches of one layout (columns' storage, validity, string
+            # widths) share a round
+            rounds: dict = {}
+            for d, q in enumerate(queues):
+                if r < len(q):
+                    b = q[r][0]
+                    layout = (jax.tree.structure(b.columns),
+                              b.shape_key()[1:])
+                    rounds.setdefault(layout, [None] * use_d)[d] = q[r]
+            for shards in rounds.values():
+                send(shards)
 
     def provider(partition: int):
         # defaulted extra args would miscount as task-context params in
@@ -274,15 +403,20 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         from blaze_tpu.ops.host_sort import host_supported
         from blaze_tpu.ops.shuffle import read_shuffle_partition_host
 
-        for b in recv_parts[partition]:
-            if mesh is None:  # one device: _unshard passes through
-                yield jax.tree_util.tree_map(_unshard, b)
-                continue
-            # the span closes before the yield: a span must never stay
-            # open across a generator's suspension
-            with trace.span("exchange", transport="unshard",
-                            partitions=Pn, capacity=b.capacity):
-                b = jax.tree_util.tree_map(_unshard, b)
+        for b, _ in recv_parts[partition]:
+            if mesh is not None:
+                # The one place an exchanged batch is re-placed: a
+                # consumer placed on the partition's owner takes it as it
+                # lies, any other gets a copy, chip to chip; none goes
+                # through the host. The span closes before the yield: a
+                # span must never stay open across a generator's
+                # suspension.
+                here = placement.here()
+                with trace.span("exchange", transport="place",
+                                partitions=Pn, capacity=b.capacity,
+                                partition=partition, device=here.id,
+                                host_bytes=0):
+                    b = placement.put(b, here)
             yield b
         for data, index in file_outputs:
             if host_supported(schema):
@@ -293,19 +427,12 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                                                   schema)
 
     if stats is not None:
-        import os as _os
-
-        from blaze_tpu.runtime.memory import batch_nbytes
-
         # live-row-scaled logical bytes: batch_nbytes counts the padded
         # capacity bucket, which would bias the AQE threshold vs the file
         # path's on-disk measure
-        total = 0
-        for parts in recv_parts:
-            for b in parts:
-                cap = max(b.capacity, 1)
-                total += batch_nbytes(b) * int(b.num_rows) // cap
-        total += sum(_os.path.getsize(d) for d, _ in file_outputs)
+        total = sum(live_nbytes(b, n) for parts in recv_parts
+                    for b, n in parts)
+        total += sum(os.path.getsize(d) for d, _ in file_outputs)
         stats["bytes"] = int(total)
     resources.put(f"{namespace}shuffle:{stage_id}", provider)
     return True

@@ -18,7 +18,7 @@ from typing import Callable, Dict, Hashable, Optional
 import jax
 
 from blaze_tpu.config import conf
-from blaze_tpu.runtime import trace
+from blaze_tpu.runtime import placement, trace
 
 _lock = threading.Lock()
 _cache: Dict[Hashable, Callable] = {}
@@ -85,7 +85,18 @@ def get_or_compile(key: Hashable, make_fn: Callable[[], Callable],
     `jit=False` caches the bare callable instead: used for pipelines with
     host-evaluated expressions (digests/JSON/UDF), which run op-at-a-time
     on concrete arrays (hostfns.host_apply) rather than inside one
-    compiled program."""
+    compiled program.
+
+    On a task placed on a chip (runtime/placement.py) the key gains that
+    chip's id: the executable is the chip's own (jax's persistent-cache
+    key keeps the device assignment on TPU), so its first call there is a
+    compile and is counted as one. Unplaced callers, and every caller on
+    a one-chip host, keep their keys."""
+    kind = kind_of(key)
+    dev = placement.current()
+    if dev is not None:
+        tag = ("@dev", dev.id)
+        key = key + (tag,) if isinstance(key, tuple) else (key, tag)
     with _lock:
         fn = _cache.get(key)
         if fn is not None:
@@ -100,7 +111,6 @@ def get_or_compile(key: Hashable, make_fn: Callable[[], Callable],
 
     faults.inject("jit.compile")
     if jit:
-        kind = kind_of(key)
         name = (name or kind)[:_NAME_MAX]
 
         def build():

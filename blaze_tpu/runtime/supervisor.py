@@ -69,7 +69,7 @@ from typing import Any, Callable, Dict, FrozenSet, List, Optional, Tuple
 from blaze_tpu import config
 from blaze_tpu.config import conf
 from blaze_tpu.ops.base import ExecContext, TaskKilledError
-from blaze_tpu.runtime import faults, trace
+from blaze_tpu.runtime import faults, placement, trace
 
 # thread-local plumbing: the attempt running on THIS thread (read by
 # faults._stall to make injected stalls kill-interruptible) and the task
@@ -529,7 +529,9 @@ class TaskSpec:
     ExecContext carrying that attempt's kill flag and the task's commit
     gate. `fallback_fn()` is the rung-3 row-interpreter route (also used
     by breaker reroutes). `op_kinds` is the set of operator names in the
-    task's plan, for breaker matching."""
+    task's plan, for breaker matching. `device` is the chip that owns the
+    task (runtime/placement.py), None for the process's default: every
+    attempt runs with it as its thread's default device."""
 
     what: str
     attempt_fn: Callable[[ExecContext], Any]
@@ -538,6 +540,7 @@ class TaskSpec:
     fallback_fn: Optional[Callable[[], Any]] = None
     op_kinds: FrozenSet[str] = frozenset()
     speculatable: bool = True
+    device: Any = None
 
 
 class _Task:
@@ -842,7 +845,10 @@ class Supervisor:
                 with trace.span("task_attempt",
                                 attempt_id=att.attempt_id,
                                 partition=task.spec.partition,
-                                speculative=speculative) as sp:
+                                speculative=speculative) as sp, \
+                        placement.on_device(task.spec.device):
+                    if conf.trace_enabled:
+                        sp.set(device=placement.here().id)
                     ctx = ExecContext(
                         partition=task.spec.partition,
                         num_partitions=task.spec.num_partitions,
@@ -1040,7 +1046,8 @@ class Supervisor:
                     and self.breaker.should_reroute(spec.op_kinds)):
                 self._note("breaker_reroutes")
                 return spec.fallback_fn()
-            return spec.attempt_fn(ctx)
+            with placement.on_device(spec.device):
+                return spec.attempt_fn(ctx)
 
         # sequential path runs on the driver thread: only task_id needs
         # pushing, the query/stage ids are already on this thread's stack

@@ -314,8 +314,15 @@ SPAN_KINDS = (
                      # caller's last pull is marked final; collect_s
     "dispatch",      # jit_cache: one call of a cached program, host time
                      # of the (asynchronous) dispatch; first_call compiles
-    "exchange",      # stage_exchange: one batch through the in-HBM
-                     # exchange, or one unshard of its output; exchange_s
+    "exchange",      # stage_exchange: one batch (or, on a mesh, one
+                     # round of one batch per chip) through the in-HBM
+                     # exchange: transport local | mesh (rows, bytes,
+                     # devices, capacity); one exchanged batch handed to
+                     # its consumer: transport place (partition, device);
+                     # one batch through files in place: transport file.
+                     # On a mesh each carries host_bytes, the bytes that
+                     # crossed the host; exchange_s, mesh_exchange_s,
+                     # mesh_host_roundtrip_MB
     "h2d",           # parquet scan / host_sort.host_to_device: host time
                      # of staging + enqueue, not the transfer; upload_s,
                      # first_upload_ms
@@ -325,7 +332,8 @@ SPAN_KINDS = (
     "scan_decode",   # ops/parquet: one record batch pulled from the
                      # parquet reader (read + decode); scan_decode_s
     "stage",         # executor: shuffle-map/broadcast/result stage
-    "task_attempt",  # supervisor: one per (task, attempt)
+    "task_attempt",  # supervisor: one per (task, attempt); device = the
+                     # chip its programs were sent to (runtime/placement)
 )
 
 # run-record wire format (ledger lines + history records). Bump on

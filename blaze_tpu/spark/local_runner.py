@@ -31,7 +31,7 @@ from blaze_tpu.plan import decode_plan, fingerprint_plan
 from blaze_tpu.plan import plan_pb2 as pb
 from blaze_tpu.plan.fingerprint import fingerprint_query
 from blaze_tpu.runtime import artifacts, faults, history, journal, monitor
-from blaze_tpu.runtime import resources, trace
+from blaze_tpu.runtime import placement, resources, trace
 from blaze_tpu.runtime import supervisor as supervisor_mod
 from blaze_tpu.runtime.executor import execute_plan, run_task_with_resilience
 from blaze_tpu.runtime.supervisor import Supervisor, TaskSpec
@@ -64,10 +64,13 @@ def run_plan(root: SparkPlan, num_partitions: int = 4,
     no overflow possible).
 
     run_info: optional dict populated with execution-path counters
-    ("mesh_stages", "file_stages", "broadcast_stages", and "mesh_devices"
-    — the fewest devices any mesh exchange's output sat on) so callers — the
-    multichip dryrun, chip_smoke.py, tests — can assert WHICH transport
-    carried each exchange rather than trusting the result alone.
+    ("mesh_stages", "file_stages", "broadcast_stages", "mesh_devices" —
+    the fewest devices any mesh exchange's output sat on — and
+    "mesh_host_bytes" — bytes of mesh-exchanged partitions that crossed
+    the host on their way to the consuming task, 0 where each is consumed
+    on the chip that owns it) so callers — the multichip dryrun,
+    chip_smoke.py, tests — can assert WHICH transport carried each
+    exchange rather than trusting the result alone.
 
     When conf.trace_enabled, the whole run is a "query" span in the
     engine trace (runtime/trace.py) and every stage/task below inherits
@@ -309,6 +312,10 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
         # AQE statistics: completed shuffles' total bytes + partition counts
         shuffle_bytes: Dict[int, int] = {}
         shuffle_parts: Dict[int, int] = {}
+        mesh_stats: List[Dict[str, int]] = []
+        # shuffle stages whose partitions a mesh exchange left on their
+        # owner chips: the tasks that read them are placed there
+        resident: set = set()
 
         from blaze_tpu.spark.aqe import apply_dynamic_join_selection
 
@@ -428,17 +435,20 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                         )
 
                         stats: Dict[str, int] = {}
+                        mesh_stats.append(stats)
                         # a transient/resource failure on the mesh degrades
                         # to the file exchange (same row multisets by
                         # design); plan/fatal/killed relay — another
                         # transport won't fix a broken plan
                         try:
+                            ntasks = _input_tasks(stage, stages)
                             mesh_ok = run_mesh_shuffle_stage(
-                                stage.plan, stage.stage_id,
-                                _input_tasks(stage, stages),
+                                stage.plan, stage.stage_id, ntasks,
                                 quota=mesh_quota,
                                 work_dir=work_dir, stats=stats,
-                                namespace=ns)
+                                namespace=ns, sup=sup,
+                                task_devices=_task_devices(
+                                    stage, ntasks, resident))
                         except Exception as e:  # noqa: BLE001 — classified
                             cat = faults.classify(e)
                             if cat in ("killed", "fatal", "plan"):
@@ -456,6 +466,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                             ndev = stats.get("devices", 1)
                             run_info["mesh_devices"] = min(
                                 run_info.get("mesh_devices", ndev), ndev)
+                            if ndev > 1:
+                                resident.add(stage.stage_id)
                             sp.set(transport="mesh",
                                    bytes=stats.get("bytes", 0),
                                    **monitor.stage_span_attrs(
@@ -466,7 +478,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                             continue
                     logical = _run_shuffle_stage(stage, stages, shuffle_mgr,
                                                  sup, run_info, ns=ns,
-                                                 jnl=jnl, fp=fp)
+                                                 jnl=jnl, fp=fp,
+                                                 resident=resident)
                     # logical (uncompressed) bytes: the mesh path reports
                     # the same unit, so the AQE threshold is
                     # transport-independent
@@ -501,7 +514,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                         trace.span("stage", stage_id=stage.stage_id,
                                    stage_kind="result",
                                    fingerprint=fp, tasks=parts) as sp:
-                    out = _run_result_stage(stage, parts, sup, run_info)
+                    out = _run_result_stage(stage, parts, sup, run_info,
+                                            resident)
                     sp.set(**monitor.stage_span_attrs(
                         run_info["query_id"], stage.stage_id))
                 if progress is not None:
@@ -512,6 +526,11 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
         if _ov is not None:
             _ov.__exit__(None, None, None)
         sup.close()
+        if run_info["mesh_stages"]:
+            # what the mesh stages' overflow sent through files: the only
+            # exchanged bytes that cross the host
+            run_info["mesh_host_bytes"] = sum(
+                st.get("host_bytes", 0) for st in mesh_stats)
         faults.run_info_delta(telemetry_before, run_info)
         # pipelined-execution accounting for this query: streams/sinks
         # opened, and a leak indicator (must be 0 once every task stream
@@ -571,6 +590,15 @@ def _input_tasks(stage: Stage, stages: List[Stage],
     return max(upstream) if upstream else fallback
 
 
+def _task_devices(stage: Stage, ntasks: int, resident) -> Optional[list]:
+    """The chip of each of the stage's `ntasks` tasks (runtime/placement.py's
+    rule), or None where the stage reads no partitions that a mesh exchange
+    left on their owners: those tasks run on the default device, unplaced."""
+    if not resident or not any(d in resident for d in stage.depends_on):
+        return None
+    return [placement.owner(t, ntasks) for t in range(ntasks)]
+
+
 def _schema_of_reader(node: pb.PlanNode):
     from blaze_tpu.plan.from_proto import decode_schema
 
@@ -579,7 +607,8 @@ def _schema_of_reader(node: pb.PlanNode):
 
 def _run_shuffle_stage(stage: Stage, stages: List[Stage],
                        shuffle_mgr, sup: Supervisor, run_info=None,
-                       ns: str = "", jnl=None, fp=None) -> int:
+                       ns: str = "", jnl=None, fp=None,
+                       resident=None) -> int:
     """Runs the map tasks through the shuffle manager (register ->
     per-task writer slot -> commit MapStatus -> reduce-side reader
     resource); returns the stage's total LOGICAL output bytes
@@ -601,6 +630,7 @@ def _run_shuffle_stage(stage: Stage, stages: List[Stage],
     op_kinds = stage.op_kinds()
     specs: List[TaskSpec] = []
     slots = []
+    devs = _task_devices(stage, ntasks, resident) or [None] * ntasks
     for task in range(ntasks):
         node = pb.PlanNode()
         node.CopyFrom(stage.plan)
@@ -619,7 +649,7 @@ def _run_shuffle_stage(stage: Stage, stages: List[Stage],
         specs.append(TaskSpec(
             what=f"shuffle_map[{stage.stage_id}:{task}]",
             attempt_fn=attempt, partition=task, num_partitions=ntasks,
-            fallback_fn=fb, op_kinds=op_kinds))
+            fallback_fn=fb, op_kinds=op_kinds, device=devs[task]))
         slots.append(slot)
     ops = sup.run_tasks(("shuffle", stage.stage_id), specs)
     logical = 0
@@ -1048,7 +1078,7 @@ def _root_sort_split(op):
 
 
 def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
-                      run_info=None) -> ColumnBatch:
+                      run_info=None, resident=None) -> ColumnBatch:
     """`parts` is the upstream exchange's partition count (_input_tasks) —
     NOT the global default: an 8-way repartition read with 4 tasks would
     silently drop half the shuffle partitions."""
@@ -1070,6 +1100,7 @@ def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
 
     op_kinds = stage.op_kinds()
     specs: List[TaskSpec] = []
+    devs = _task_devices(stage, parts, resident) or [None] * parts
     for p in range(parts):
         def attempt(task_ctx):
             op_p = decode_plan(stage.plan)  # fresh operator state per task
@@ -1085,7 +1116,7 @@ def _run_result_stage(stage: Stage, parts: int, sup: Supervisor,
         specs.append(TaskSpec(
             what=f"result[{stage.stage_id}:{p}]", attempt_fn=attempt,
             partition=p, num_partitions=parts, fallback_fn=fb,
-            op_kinds=op_kinds))
+            op_kinds=op_kinds, device=devs[p]))
     batches: List[ColumnBatch] = []
     for lst in sup.run_tasks(("result", stage.stage_id), specs):
         batches.extend(lst)
@@ -1139,7 +1170,10 @@ def _collect_result(stage: Stage, op, split, batches: List[ColumnBatch],
 
     if not batches:
         return ColumnBatch.empty(op.schema)
-    out = concat_batches(batches, op.schema)
+    # placed tasks leave their results on their chips; the driver's merge
+    # programs take them on one
+    out = concat_batches([placement.put(b, placement.here())
+                          for b in batches], op.schema)
     # Ordered collect for the remaining shapes (device path): a root
     # TakeOrdered (SortExec with fetch) sorted each partition with a
     # bounded top-k; merging the sorted partitions gives the total order

@@ -277,8 +277,9 @@ def run_cells(paths, frames, cells, device_checks: bool = True,
             tel = {k: v - tel0.get(k, 0) for k, v in
                    compile_service.TELEMETRY.snapshot().items()}
             counters = {k: run_info.get(k, 0) for k in (
-                "mesh_stages", "mesh_devices", "file_stages",
-                "broadcast_stages", "spill_count", "compile_compile_count")}
+                "mesh_stages", "mesh_devices", "mesh_host_bytes",
+                "file_stages", "broadcast_stages", "spill_count",
+                "compile_compile_count")}
             counters.update(
                 stage_compiled=tel["stage_compiled"],
                 agg_pallas_traces=tel["agg_pallas_traces"],
@@ -311,11 +312,13 @@ def run_cells(paths, frames, cells, device_checks: bool = True,
                             f"not the Pallas kernel: {tel}")
             if exchange_width:
                 # every exchange in HBM: local grouping on one device, a
-                # shard_map all_to_all across min(devices, width) of them
+                # shard_map all_to_all across min(devices, width) of them,
+                # each partition consumed where it lies (no host crossing)
                 require(run_info["mesh_stages"] >= 1
                         and run_info["file_stages"] == 0
                         and run_info["mesh_devices"]
-                        == min(ndev, exchange_width),
+                        == min(ndev, exchange_width)
+                        and run_info["mesh_host_bytes"] == 0,
                         f"{name}/{mode} run {run}: an exchange left the "
                         f"device mesh (run_info={run_info})")
                 if ndev > 1:
