@@ -6,10 +6,18 @@ runtime SMJ fallback). TPU-first redesign — there is no cursor state machine
 and no hash table; a join is three dense phases:
 
   1. MATCH: concat the (encoded) keys of the sorted build side and a probe
-     batch, one variadic `lax.sort`, then segmented scans give every probe
-     row its [start, start+count) match range in the sorted build side —
-     this replaces both the hash-table probe and the merge cursors (probing
-     via binary search was measured ~10x worse on TPU, see memory).
+     batch, one variadic `lax.sort` with the side tag as its last key, then
+     scans over the sorted order give every probe row its
+     [start, start+count) match range in the sorted build side — this
+     replaces both the hash-table probe and the merge cursors (probing via
+     binary search was measured ~10x worse on TPU, see memory). The scans
+     rely on two things: inside a run of equal keys the build rows precede
+     the probe rows (the tag is a sort key), and prefix counts never fall,
+     so a running maximum carries a run start's count forward to the run's
+     rows and a reverse running minimum carries the next start's back. Two
+     more sorts put the results back in probe and build order. No gather
+     and no scatter: on the TPU one by computed index over a 2^21-row batch
+     costs 15-56 ms, a sort of it 5-14 ms, a scan under 1 ms (PERF.md).
   2. EXPAND: one host sync reads the total match count, then a jit-cached
      expansion program gathers (probe_idx, build_idx) pairs with
      `jnp.repeat(total_repeat_length=...)` into a bucketed output capacity.
@@ -150,7 +158,9 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
     per-build-row probe-match counts (for outer bookkeeping).
 
     Returns (start, count) aligned to probe's ORIGINAL row order and
-    (build_match_count) aligned to sorted-build row order.
+    (build_match_count) aligned to sorted-build row order. `start` of a
+    probe row with count 0 is unspecified (`expand_pairs` masks it), and so
+    is the build_match_count of a padding slot (callers mask by row_mask).
     """
     capB, capP = build.capacity, probe.capacity
     cap = capB + capP
@@ -172,7 +182,6 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
                                 swords)
         pkeys = _join_sort_keys(probe, probe_cols, null_safe, force_flags, 1,
                                 swords)
-        live = jnp.concatenate([build.row_mask(), probe.row_mask()])
         keys = []
         for b, p in zip(bkeys, pkeys):
             assert b.dtype == p.dtype, (b.dtype, p.dtype)
@@ -195,46 +204,42 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
         eq = jnp.ones((cap,), jnp.bool_)
         for k in skeys:
             eq = eq & (k == jnp.roll(k, 1))
-        starts = (~eq).at[0].set(True)
-        slive = live[spos]
-        # dead rows clump at the end; gid garbage there
-        starts = starts & slive
+        # liveness is the first sort key, so it comes out of the sort
+        slive = skeys[0] == 0
+        # dead rows clump at the end and start no run
+        starts = (~eq | (pos == 0)) & slive
 
     with jax.named_scope("match.run_cumsums"):
-        gid = jnp.cumsum(starts.astype(jnp.int32)) - 1
         is_build = (stag == 0) & slive
         is_probe = (stag == 1) & slive
+        nb = is_build.astype(jnp.int32)
+        npr = is_probe.astype(jnp.int32)
+        csum_b = jnp.cumsum(nb)
+        csum_p = jnp.cumsum(npr)
 
-        csum_b = jnp.cumsum(is_build.astype(jnp.int32))
-        csum_p = jnp.cumsum(is_probe.astype(jnp.int32))
-
-    with jax.named_scope("match.run_start_idx"):
-        run_start_idx = nonzero_i32(starts, cap, fill_value=cap - 1)
-
-    with jax.named_scope("match.run_totals"):
-        zb = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_b])
-        zp = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum_p])
-        # per-run: build rows before the run, and totals in run
-        run_b_before = zb[run_start_idx]
-        num_runs = jnp.sum(starts, dtype=jnp.int32)
-        run_end_idx = jnp.concatenate([run_start_idx[1:],
-                                       jnp.full((1,), cap, jnp.int32)])
-        slot = jnp.arange(cap, dtype=jnp.int32)
-        # runs are contiguous; run r spans
-        # [run_start_idx[r], run_start_idx[r+1])
-        # (the final run ends where dead rows begin = total live count)
-        total_live = jnp.sum(live, dtype=jnp.int32)
-        run_end_idx = jnp.where(slot == num_runs - 1, total_live,
-                                run_end_idx)
-        run_b_total = zb[jnp.clip(run_end_idx, 0, cap)] - run_b_before
-        run_p_total = zp[jnp.clip(run_end_idx, 0, cap)] - zp[run_start_idx]
-
-    with jax.named_scope("match.run_broadcast"):
-        # broadcast run data back to rows
-        gid_c = jnp.clip(gid, 0, cap - 1)
-        row_start = run_b_before[gid_c]
-        row_bcnt = run_b_total[gid_c]
-        row_pcnt = run_p_total[gid_c]
+    # From per-row prefix counts to per-run quantities by scans alone (no
+    # index by run id: see the module doc). Two facts carry it: the side
+    # tag is the last sort key, so inside a run every build row precedes
+    # every probe row; and the prefix counts never fall, so the count taken
+    # at the latest run start is the largest among all starts so far (and
+    # the count at the next run start the smallest among all to come).
+    with jax.named_scope("match.run_scans"):
+        # build rows before the row's run: a running maximum carries the
+        # run start's exclusive build count forward
+        row_start = jax.lax.cummax(
+            jnp.where(starts, csum_b - nb, 0), axis=0)
+        # read at probe rows only: the run's build rows all lie behind one
+        row_bcnt = csum_b - row_start
+        # read at build rows only: no probe row of the run lies behind one,
+        # so the run's probe rows are the probe prefix at the run's end
+        # (= the exclusive count at the next run start, or the total after
+        # the last run) less the row's own
+        total_p = csum_p[-1]
+        ahead = jax.lax.cummin(
+            jnp.where(starts, csum_p - npr, total_p), axis=0,
+            reverse=True)
+        run_end_p = jnp.concatenate([ahead[1:], total_p[None]])
+        row_pcnt = run_end_p - csum_p
 
     with jax.named_scope("match.to_probe_order"):
         # per-probe-row (original order): sort by (not-probe, original pos)
@@ -250,7 +255,7 @@ def match_ranges(build: ColumnBatch, probe: ColumnBatch,
     # sort restricted to build rows (same comparator, stable) -> compact.
     with jax.named_scope("match.to_build_order"):
         not_build = jnp.where(is_build, jnp.uint8(0), jnp.uint8(1))
-        backb = jax.lax.sort((not_build, slot, row_pcnt), num_keys=2,
+        backb = jax.lax.sort((not_build, pos, row_pcnt), num_keys=2,
                              is_stable=True)
         bmatch = backb[2][:capB]
 

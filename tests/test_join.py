@@ -4,6 +4,9 @@ Mirrors the reference's SMJ test battery (sort_merge_join_exec.rs:1024+,
 ~15 cases incl. inner/left/right/full/semi/anti with nulls and small batch
 chunking) plus BHJ build-side reversal (BlazeConverters.scala:420-434)."""
 
+import re
+
+import jax
 import numpy as np
 import pandas as pd
 import pytest
@@ -14,6 +17,7 @@ from blaze_tpu.exprs import ir
 from blaze_tpu.ops.basic import MemorySourceExec
 from blaze_tpu.ops.join import (
     BroadcastNestedLoopJoinExec, JoinKey, JoinType, SortMergeJoinExec,
+    _join_sort_keys, match_ranges, sort_batch_by_keys,
 )
 from blaze_tpu.runtime.executor import collect
 
@@ -352,3 +356,113 @@ def test_bhj_runtime_size_fallback(rng, jt, how):
         want = _oracle(pd.DataFrame({"lk": pk, "lv": pv}),
                        pd.DataFrame({"rk": bk, "rv": bv}), how)
         assert _rows(chunked) == _rows(want)
+
+
+# ---------------------------------------------------------------------------
+# match_ranges against a plain numpy reference
+# ---------------------------------------------------------------------------
+
+def _ints(lo, hi):
+    return lambda rng, n: rng.integers(lo, hi, n).astype(np.int64)
+
+
+def _strings(rng, n):
+    # widths 1..9: the two sides usually differ in their widest string
+    return ["k" * int(w) for w in rng.integers(1, 10, n)]
+
+
+# name -> (key dtypes, per-side (rows, capacity, null share), per-column
+# draws for build and probe, null_safe)
+MATCH_CASES = {
+    "empty_build": ([T.INT64], (0, 16, 0), (30, None, 0),
+                    [_ints(0, 5)], [_ints(0, 5)], [False]),
+    "every_key_duplicated": ([T.INT64], (40, None, 0), (60, None, 0),
+                             [_ints(0, 3)], [_ints(0, 3)], [False]),
+    "no_match_at_all": ([T.INT64], (20, None, 0), (40, None, 0),
+                        [_ints(0, 20)], [_ints(100, 140)], [False]),
+    "null_build_keys": ([T.INT64], (50, None, 0.4), (70, None, 0),
+                        [_ints(0, 8)], [_ints(0, 8)], [False]),
+    "null_probe_keys": ([T.INT64], (50, None, 0), (70, None, 0.4),
+                        [_ints(0, 8)], [_ints(0, 8)], [False]),
+    "null_safe_keys": ([T.INT64], (50, None, 0.3), (70, None, 0.3),
+                       [_ints(0, 8)], [_ints(0, 8)], [True]),
+    "two_column_keys": ([T.INT64, T.INT64], (60, None, 0.1), (90, None, 0.1),
+                        [_ints(0, 4), _ints(0, 3)],
+                        [_ints(0, 4), _ints(0, 3)], [False, True]),
+    "string_key": ([T.STRING], (40, None, 0.1), (60, None, 0.1),
+                   [_strings], [_strings], [False]),
+    # from_numpy pads with zeros, and 0 is a live key on both sides
+    "padding_rows_both_sides": ([T.INT64], (20, 64, 0), (50, 128, 0),
+                                [_ints(0, 4)], [_ints(0, 4)], [False]),
+    "build_capacity_larger": ([T.INT64], (200, 256, 0), (10, 16, 0),
+                              [_ints(0, 30)], [_ints(0, 30)], [False]),
+}
+
+
+def _match_side(rng, dtypes, size, draws):
+    n, cap, null_share = size
+    schema = T.Schema([T.Field(f"k{i}", dt) for i, dt in enumerate(dtypes)])
+    data = {f.name: draw(rng, n) for f, draw in zip(schema, draws)}
+    validity = ({f.name: rng.random(n) >= null_share for f in schema}
+                if null_share else None)
+    return ColumnBatch.from_numpy(data, schema, capacity=cap,
+                                  validity=validity)
+
+
+def _key_rows(batch):
+    d = batch.to_numpy()
+    return list(zip(*[list(d[f.name]) for f in batch.schema]))
+
+
+def _keys_equal(a, b, null_safe):
+    return all((x is None and y is None and ns) or
+               (x is not None and y is not None and x == y)
+               for x, y, ns in zip(a, b, null_safe))
+
+
+@pytest.mark.parametrize("case", sorted(MATCH_CASES))
+def test_match_ranges_against_numpy(rng, case):
+    dtypes, bsize, psize, bdraws, pdraws, null_safe = MATCH_CASES[case]
+    build = _match_side(rng, dtypes, bsize, bdraws)
+    probe = _match_side(rng, dtypes, psize, pdraws)
+    cols = list(range(len(dtypes)))
+    flags = [b.validity is not None or p.validity is not None
+             for b, p in zip(build.columns, probe.columns)]
+    build_sorted = sort_batch_by_keys(
+        build, _join_sort_keys(build, cols, null_safe, flags, 0))
+    start, cnt, bmatch = map(np.asarray, jax.jit(
+        lambda b, p: match_ranges(b, p, cols, cols, null_safe, flags))(
+            build_sorted, probe))
+    assert cnt.shape == start.shape == (probe.capacity,)
+    assert bmatch.shape == (build.capacity,)
+
+    bkeys, pkeys = _key_rows(build_sorted), _key_rows(probe)
+    assert sorted(bkeys, key=repr) == sorted(_key_rows(build), key=repr)
+    for i, pk in enumerate(pkeys):
+        want = [j for j, bk in enumerate(bkeys)
+                if _keys_equal(pk, bk, null_safe)]
+        assert cnt[i] == len(want), (i, pk)
+        # `start` of an unmatched probe row is unspecified
+        if want:
+            assert list(range(start[i], start[i] + cnt[i])) == want, (i, pk)
+    for j, bk in enumerate(bkeys):
+        assert bmatch[j] == sum(_keys_equal(pk, bk, null_safe)
+                                for pk in pkeys), (j, bk)
+    # padding probe rows match nothing
+    assert not cnt[len(pkeys):].any() and not start[len(pkeys):].any()
+
+
+@pytest.mark.parametrize("case", ["null_safe_keys", "string_key"])
+def test_match_ranges_lowers_to_sorts_and_scans_only(rng, case):
+    """On the TPU a gather or a scatter by computed index costs 7-26 ns an
+    element at full batch capacity, a sort of the batch ~9 ms and a scan
+    next to nothing (PERF.md, PR 29): the match finds its runs by scans, and
+    this fails the day someone indexes by a run id again."""
+    dtypes, bsize, psize, bdraws, pdraws, null_safe = MATCH_CASES[case]
+    build = _match_side(rng, dtypes, bsize, bdraws)
+    probe = _match_side(rng, dtypes, psize, pdraws)
+    text = jax.jit(lambda b, p: match_ranges(
+        b, p, [0], [0], null_safe, [True])).lower(build, probe).as_text()
+    ops = re.findall(r"stablehlo\.(\w+)", text)
+    assert ops.count("sort") == 3, ops
+    assert not [op for op in ops if "gather" in op or "scatter" in op], ops

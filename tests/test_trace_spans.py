@@ -311,8 +311,7 @@ def test_named_scopes_inside_match_ranges_and_collapse_sort():
 
     text = jax.jit(match).lower(b, b).as_text(debug_info=True)
     for scope in ("match.keys", "match.merge_sort", "match.run_starts",
-                  "match.run_cumsums", "match.run_start_idx",
-                  "match.run_totals", "match.run_broadcast",
+                  "match.run_cumsums", "match.run_scans",
                   "match.to_probe_order", "match.to_build_order"):
         assert scope in text, scope
     text = jax.jit(lambda x: sort_batch(x, [SortSpec(0)])).lower(b).as_text(
