@@ -9,7 +9,7 @@ PYENV = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu
 
 .PHONY: check check-fast check-faults check-supervisor check-trace \
 	check-durability check-dist-obs check-network check-elastic \
-	check-streaming check-autopilot check-profile check-zerocopy \
+	check-streaming check-profile check-zerocopy \
 	check-pipeline \
 	check-pipeline-soak \
 	check-perf \
@@ -20,7 +20,7 @@ PYENV = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu
 check: check-lint test validate check-perf check-history check-service \
 	check-doctor check-flight check-executors check-durability \
 	check-dist-obs check-network check-elastic check-streaming \
-	check-autopilot check-profile check-zerocopy
+	check-profile check-zerocopy
 	@echo "CHECK OK — safe to commit"
 
 # Static invariant gate (tools/blazelint): lock discipline, knob
@@ -240,10 +240,6 @@ check-elastic:
 check-streaming:
 	$(PYENV) python tools/chaos_soak.py --streaming \
 	  --json-out STREAMING_r21.json
-
-check-autopilot:
-	$(PYENV) python tools/chaos_soak.py --autopilot \
-	  --json-out AUTOPILOT_r22.json
 
 # Continuous-profiling acceptance (ISSUE 19): seeded-stall attribution
 # in the collapsed-stack export, pooled SIGKILL sidecar recovery of

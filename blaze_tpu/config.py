@@ -19,13 +19,9 @@ three agree.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import hashlib
-import json
 import os
-import threading
-from typing import Any, Callable, Dict, Iterator, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,24 +30,13 @@ class Knob:
 
     ``default_factory`` (mutable defaults: dicts) wins over ``default``;
     ``env`` names an environment variable consulted once at BlazeConf
-    construction (the value is cast through ``type``).
-
-    ``step``/``min``/``max`` are the autopilot actuation schedule: a knob
-    that declares all three may be moved one bounded step at a time by
-    runtime/autopilot.py (``geometric=True`` multiplies/divides by
-    ``step`` instead of adding/subtracting it). Knobs without the triple
-    are never actuated — blazelint's doctor-knob-sync rule enforces that
-    every knob in autopilot.ACTUATORS declares it."""
+    construction (the value is cast through ``type``)."""
 
     name: str
     default: Any = None
     doc: str = ""
     env: str = ""
     default_factory: Optional[Callable[[], Any]] = None
-    step: Optional[float] = None
-    min: Optional[float] = None
-    max: Optional[float] = None
-    geometric: bool = False
 
     @property
     def type(self) -> type:
@@ -70,27 +55,6 @@ class Knob:
         if self.default_factory is not None:
             return self.default_factory()
         return self.default
-
-    def propose_step(self, current: Any, direction: int) -> Optional[Any]:
-        """One bounded step from ``current`` in ``direction`` (+1/-1).
-
-        Returns the clamped next value, or None when the knob declares
-        no schedule or the clamp leaves the value unchanged (already
-        pinned at the min/max rail)."""
-        if self.step is None or self.min is None or self.max is None:
-            return None
-        if self.geometric:
-            nxt = current * self.step if direction > 0 else current / self.step
-        else:
-            nxt = current + self.step * direction
-        nxt = sorted((self.min, nxt, self.max))[1]
-        if self.type is bool:
-            # validate_overlay is strict on bool knobs — a proposed 0/1
-            # int would be rejected at apply time
-            nxt = bool(round(nxt))
-        elif self.type is int:
-            nxt = int(round(nxt))
-        return None if nxt == current else nxt
 
 
 _DECLARATIONS: Tuple[Knob, ...] = (
@@ -141,8 +105,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     Knob("dense_agg_range", 1 << 16,
          doc="Dense grouped-agg key range for the MXU one-hot path "
              "(<= 2^16: 256x256 byte decomposition); stages whose keys "
-             "exceed it fall back.",
-         step=2.0, min=1 << 12, max=1 << 22, geometric=True),
+             "exceed it fall back."),
     Knob("float_sum_digit_planes", 6,
          doc="Precision policy for FLOAT sums on the MXU digit-plane "
              "path: 6 planes digitize to 46 bits (the TPU's emulated-f64 "
@@ -157,8 +120,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     Knob("target_batch_bytes", 128 << 20,
          doc="Adaptive macro-batching target: batch sources size batches "
              "toward this many bytes, clamped by the memory budget "
-             "(ops/common.adaptive_batch_rows).",
-         step=2.0, min=16 << 10, max=1 << 30, geometric=True),
+             "(ops/common.adaptive_batch_rows)."),
     Knob("max_batch_rows", 1 << 21,
          doc="Hard row cap on adaptive macro-batches. Its capacity bucket "
              "is also where shape canonicalization's power-of-four rungs "
@@ -331,8 +293,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     Knob("prefetch_batches", 2,
          doc="Bounded queue depth per pipelined stream; in-flight bytes "
              "are reserved against the MemManager budget (backpressure, "
-             "not OOM).",
-         step=1, min=1, max=8),
+             "not OOM)."),
 
     # -- resource accounting & live metrics (runtime/monitor.py) --
     Knob("monitor_enabled", True,
@@ -426,8 +387,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
              "event records and monitor counter deltas are batched into "
              "a 'telemetry' frame on the control socket at this cadence "
              "(a flush also rides every task result). <= 0 disables "
-             "the timer; results still carry their flush.",
-         step=2.0, min=50, max=2000, geometric=True),
+             "the timer; results still carry their flush."),
     Knob("executor_trace_events", 4096,
          doc="Bounded ring capacity of each executor process's local "
              "TraceLog (worker-side spans buffer here between ships; "
@@ -452,8 +412,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
          doc="Base backoff before worker reconnect attempt i "
              "(~backoff * 2^i, jittered) after a control-socket error; "
              "the resume handshake re-delivers unacked TaskSpecs and "
-             "results, deduped by (task_id, attempt, epoch).",
-         step=2.0, min=10, max=1600, geometric=True),
+             "results, deduped by (task_id, attempt, epoch)."),
     Knob("executor_drain_grace_ms", 5000,
          doc="Graceful-decommission budget: a draining executor "
              "(ExecutorPool.decommission or SIGTERM) finishes in-flight "
@@ -502,22 +461,19 @@ _DECLARATIONS: Tuple[Knob, ...] = (
              "first touch; a mismatch falls back to the BCS2 socket "
              "fetch whose server-side read quarantines + lineage-"
              "repairs. Off = every pooled fetch streams over the "
-             "socket.",
-         step=1, min=0, max=1),
+             "socket."),
     Knob("dict_encode_strings", True,
          doc="Dictionary-encode string columns in serde frames: ship "
              "(dict, codes) once and keep filter/join/groupby on i32 "
              "codes, decoding only at the result-merge edge. Columns "
              "whose slice cardinality exceeds dict_max_cardinality (or "
              "where the dict form is not smaller) fall back to plain "
-             "length-prefixed encoding per column.",
-         step=1, min=0, max=1),
+             "length-prefixed encoding per column."),
     Knob("dict_max_cardinality", 64 << 10,
          doc="Distinct-value ceiling for dictionary-encoded string "
              "columns: a serde slice with more unique strings than this "
              "is written in plain form (the dict no longer pays for "
-             "itself and the code gather stops being cache-friendly).",
-         step=2.0, min=256, max=1 << 20, geometric=True),
+             "itself and the code gather stops being cache-friendly)."),
 
     # -- elastic fleet & driver HA (runtime/autoscaler.py,
     # -- runtime/standby.py) --
@@ -534,8 +490,7 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     Knob("autoscale_max", 4,
          doc="Autoscaler ceiling: scale-up stops here even while parked "
              "arrivals persist (doctor's fleet_underprovisioned finding "
-             "suggests raising it when the policy pins at the ceiling).",
-         step=1, min=1, max=8),
+             "suggests raising it when the policy pins at the ceiling)."),
     Knob("autoscale_cooldown_ms", 5000,
          doc="Hysteresis between autoscaler actuations: after a "
              "scale_up/scale_down decision the policy observes without "
@@ -574,31 +529,6 @@ _DECLARATIONS: Tuple[Knob, ...] = (
              "stream_stall flight dossier (once per stream) and a doctor "
              "stream_lag finding suggesting the knob to turn."),
 
-    # -- self-tuning autopilot (runtime/autopilot.py) --
-    Knob("autopilot_enabled", False, env="BLAZE_AUTOPILOT",
-         doc="Guarded per-fingerprint knob adaptation: each run's top "
-             "doctor finding proposes ONE bounded knob step (the knob's "
-             "declared step/min/max schedule), canary runs are verdicted "
-             "against the settled baseline by detect_regressions(), and "
-             "a regression rolls the overlay back immediately and "
-             "quarantines the value. Needs autopilot_dir."),
-    Knob("autopilot_dir", "", env="BLAZE_AUTOPILOT_DIR",
-         doc="Crash-atomic OverlayStore directory ('' disables): one "
-             "journal-style JSONL of propose/promote/rollback/quarantine "
-             "events, folded into per-fingerprint state on open — "
-             "settled overlays and quarantine lists survive driver "
-             "restart and standby failover."),
-    Knob("autopilot_canary_runs", 3,
-         doc="Consecutive canary runs that must beat the settled p50 "
-             "before a proposed overlay value is promoted to settled; a "
-             "canary that can't produce this streak within 3x the budget "
-             "is reverted as inconclusive (and quarantined, so the "
-             "explorer never oscillates on it)."),
-    Knob("autopilot_max_active_canaries", 4,
-         doc="Cap on concurrently-canarying fingerprints across the "
-             "store; proposals beyond it are deferred until a canary "
-             "promotes or rolls back."),
-
     # -- per-operator enable flags (tier b, spark.blaze.enable.<op>) --
     Knob("enable_ops", default_factory=dict,
          doc="Per-operator enable flags ({'filter': False} routes that "
@@ -608,18 +538,6 @@ _DECLARATIONS: Tuple[Knob, ...] = (
 
 KNOBS: Dict[str, Knob] = {k.name: k for k in _DECLARATIONS}
 
-# Overlay layers in precedence order (later wins). ``base`` is the
-# BlazeConf singleton itself; the other three are plain dicts validated
-# against KNOBS and composed per query by resolve_overlay().
-OVERLAY_LAYERS: Tuple[str, ...] = ("base", "tenant", "fingerprint", "pin")
-
-# Thread-scoped overlay application: a query thread enters
-# overlay_scope(...) and every conf.<knob> read on THAT thread sees the
-# overlaid value; concurrent queries on other threads keep reading base
-# (or their own overlay) — one query's canary can never leak into
-# another tenant's resolved conf.
-_overlay_tls = threading.local()
-
 
 class BlazeConf:
     """The process-wide knob singleton, built from ``KNOBS``.
@@ -627,20 +545,13 @@ class BlazeConf:
     Attribute surface is exactly the registry: reading/writing an
     undeclared name is an AttributeError/blazelint finding, and
     ``update()`` keeps the historical KeyError contract for the JVM
-    bridge's property plumbing. Reads are overlay-aware: inside an
-    overlay_scope() the calling thread sees the scoped values."""
+    bridge's property plumbing."""
 
     __slots__ = tuple(KNOBS)
 
     def __init__(self) -> None:
         for knob in KNOBS.values():
             setattr(self, knob.name, knob.resolve())
-
-    def __getattribute__(self, name: str) -> Any:
-        ov = _overlay_tls.__dict__.get("values")
-        if ov is not None and name in ov:
-            return ov[name]
-        return object.__getattribute__(self, name)
 
     def op_enabled(self, op: str) -> bool:
         return self.enable_ops.get(op, True)
@@ -651,138 +562,6 @@ class BlazeConf:
                 raise KeyError(f"unknown conf key: {k}")
             setattr(self, k, v)
         return self
-
-
-def validate_overlay(mapping: Dict[str, Any],
-                     layer: str = "overlay") -> Dict[str, Any]:
-    """Validate one overlay layer against the Knob registry.
-
-    Unknown knob names raise KeyError (the conf.update contract);
-    type-incompatible values raise TypeError. int/float coerce to the
-    declared type; bool is strict (it IS an int to isinstance)."""
-    out: Dict[str, Any] = {}
-    for name, value in dict(mapping).items():
-        knob = KNOBS.get(name)
-        if knob is None:
-            raise KeyError(f"unknown conf key in {layer} overlay: {name}")
-        t = knob.type
-        if t is bool:
-            if not isinstance(value, bool):
-                raise TypeError(
-                    f"{layer} overlay {name}: expected bool, "
-                    f"got {type(value).__name__}")
-        elif isinstance(value, bool):
-            raise TypeError(
-                f"{layer} overlay {name}: expected {t.__name__}, got bool")
-        elif t in (int, float) and isinstance(value, (int, float)):
-            value = t(value)
-        elif not isinstance(value, t):
-            raise TypeError(
-                f"{layer} overlay {name}: expected {t.__name__}, "
-                f"got {type(value).__name__}")
-        out[name] = value
-    return out
-
-
-_tenant_overlays: Dict[str, Dict[str, Any]] = {}
-
-
-def set_tenant_overlay(tenant: str,
-                       mapping: Optional[Dict[str, Any]]) -> None:
-    """Install (or clear, with a falsy mapping) a tenant's overlay."""
-    if not mapping:
-        _tenant_overlays.pop(tenant, None)
-    else:
-        _tenant_overlays[tenant] = validate_overlay(mapping, layer="tenant")
-
-
-def tenant_overlay(tenant: Optional[str]) -> Dict[str, Any]:
-    return dict(_tenant_overlays.get(tenant) or {}) if tenant else {}
-
-
-def overlay_hash(values: Dict[str, Any]) -> Optional[str]:
-    """Stable short hash of a resolved overlay (None when empty) —
-    stamped into history records so StatisticsFeed/detect_regressions
-    compare like-with-like across overlay generations."""
-    if not values:
-        return None
-    blob = json.dumps(values, sort_keys=True, default=repr)
-    return hashlib.sha1(blob.encode()).hexdigest()[:12]
-
-
-@dataclasses.dataclass
-class ResolvedOverlay:
-    """The composed non-base layers for one query: what differs from
-    base, which layer each value came from, and the stable hash."""
-
-    values: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    provenance: Dict[str, str] = dataclasses.field(default_factory=dict)
-    canary: bool = False
-    canary_knob: str = ""
-
-    @property
-    def hash(self) -> Optional[str]:
-        return overlay_hash(self.values)
-
-    def as_record(self) -> Dict[str, Any]:
-        """JSON-safe stamp for ledger lines / dossiers / run_info."""
-        return {"overlay": dict(self.values),
-                "provenance": dict(self.provenance),
-                "overlay_hash": self.hash,
-                "canary": self.canary,
-                "canary_knob": self.canary_knob}
-
-
-def resolve_overlay(tenant: Optional[str] = None,
-                    fingerprint_overlay: Optional[Dict[str, Any]] = None,
-                    pin: Optional[Dict[str, Any]] = None) -> ResolvedOverlay:
-    """Compose base -> tenant -> per-fingerprint -> per-query pin.
-
-    Each layer is validated against KNOBS; later layers win and the
-    winning layer is recorded per knob in ``provenance`` (knobs absent
-    from every layer stay 'base' and are not listed)."""
-    resolved = ResolvedOverlay()
-    for layer, mapping in (("tenant", tenant_overlay(tenant)),
-                           ("fingerprint", fingerprint_overlay),
-                           ("pin", pin)):
-        if not mapping:
-            continue
-        for name, value in validate_overlay(mapping, layer=layer).items():
-            resolved.values[name] = value
-            resolved.provenance[name] = layer
-    return resolved
-
-
-@contextlib.contextmanager
-def overlay_scope(values: Optional[Dict[str, Any]],
-                  provenance: Optional[Dict[str, str]] = None
-                  ) -> Iterator[None]:
-    """Apply an overlay to every conf read on the calling thread.
-
-    Nests: an inner scope merges over (and restores) the outer one.
-    supervisor/pipeline task threads inherit the submitting thread's
-    scope via current_overlay() capture."""
-    tls = _overlay_tls.__dict__
-    prev = (tls.get("values"), tls.get("provenance"))
-    merged = dict(prev[0] or {})
-    merged.update(values or {})
-    merged_prov = dict(prev[1] or {})
-    merged_prov.update(provenance or {})
-    tls["values"] = merged or None
-    tls["provenance"] = merged_prov or None
-    try:
-        yield
-    finally:
-        tls["values"], tls["provenance"] = prev
-
-
-def current_overlay() -> Dict[str, Any]:
-    """The calling thread's active overlay values ({} outside a scope)."""
-    return dict(_overlay_tls.__dict__.get("values") or {})
-
-
-def current_provenance() -> Dict[str, str]:
-    return dict(_overlay_tls.__dict__.get("provenance") or {})
 
 
 def knob_catalog_md() -> str:

@@ -343,8 +343,8 @@ def diagnose(record: dict,
             f"({100 * _share(cp, 'serde_encode', 'serde_decode'):.0f}% "
             f"of wall time)",
             # suggestion stays an inline literal expression so the
-            # doctor-knob-sync checker (and the autopilot's verb parser)
-            # can see every conf.<knob> mention statically
+            # doctor-knob-sync checker can see every conf.<knob>
+            # mention statically
             ("raise conf.shuffle_mmap_enabled (serve same-host shuffle "
              "fetches as zero-copy mmap views instead of socket "
              "streams) and raise conf.dict_encode_strings (ship string "

@@ -64,8 +64,7 @@ SCHEMA_VERSION = 1
 
 TRIGGERS = ("failure", "shed", "deadline", "hang", "slo_breach",
             "breaker_trip", "resource_leak", "executor_death",
-            "driver_restart", "driver_failover", "stream_stall",
-            "autopilot_rollback")
+            "driver_restart", "driver_failover", "stream_stall")
 
 _lock = threading.Lock()
 _captured: set = set()            # (query_id, trigger): exactly-once
@@ -285,12 +284,6 @@ def _capture_locked_out(trigger, query_id, tenant_id, error, run_info,
                   if error is not None else None),
         "detail": detail,
         "knobs": _knob_overlay(),
-        # conf-overlay provenance (runtime/autopilot.py): the resolved
-        # overlay + which layer (tenant/fingerprint/pin) set each value
-        # and the canary posture — "why did my query's conf change"
-        "autopilot": (dict(info["autopilot"])
-                      if isinstance(info.get("autopilot"), dict)
-                      else None),
         "trace_events": recs,
         "trace_dropped": trace.TRACE.dropped,
         "monitor_samples": samples,

@@ -338,15 +338,6 @@ def record_run(qid: str, run_info: Optional[dict] = None,
                      if isinstance(v, (int, float))
                      and not isinstance(v, bool)},
     }
-    ap = (run_info or {}).get("autopilot") or {}
-    if ap:
-        # like-with-like hygiene: StatisticsFeed baselines skip canary
-        # runs, detect_regressions priors must share the overlay
-        # generation, and the autopilot keys its settled baseline off
-        # the pre-AQE query fingerprint it actuates on
-        record["overlay_hash"] = ap.get("overlay_hash")
-        record["canary"] = bool(ap.get("canary"))
-        record["autopilot_fp"] = ap.get("fingerprint", "")
     if critical_path is not None:
         record["critical_path"] = critical_path
     if acc is not None and acc.overflow:
@@ -399,10 +390,6 @@ class StatisticsFeed:
         self._ops: Dict[str, List[Dict[str, Any]]] = {}
         self._groups: Dict[str, List[Dict[str, Any]]] = {}
         for rec in self._records:
-            if rec.get("canary"):
-                # autopilot canary runs never feed baselines — a knob
-                # under trial must not shift the costs it is judged by
-                continue
             op_rows = {o.get("fingerprint"): o.get("rows", 0)
                        for o in rec.get("ops") or []}
             for s in rec.get("stages") or []:
@@ -533,27 +520,12 @@ def detect_regressions(records: Optional[Iterable[dict]] = None,
     factor = 1.0 + float(pct) / 100.0
     for fp, samples in series.items():
         idx, last_ms, last_cp, meta = samples[-1]
-        latest_rec = records[idx]
-        # like-with-like: canary runs (autopilot explorations) never
-        # serve as priors, and priors must share the settled overlay
-        # generation the latest run is judged against. Records without
-        # the autopilot fields degrade to the legacy all-priors window
-        # (canary falsy, overlay_hash None on both sides).
-        if latest_rec.get("canary"):
-            settled = [s for s in samples[:-1]
-                       if not records[s[0]].get("canary")]
-            base_hash = (records[settled[-1][0]].get("overlay_hash")
-                         if settled else None)
-        else:
-            base_hash = latest_rec.get("overlay_hash")
-        priors = [s for s in samples[:-1]
-                  if not records[s[0]].get("canary")
-                  and records[s[0]].get("overlay_hash") == base_hash]
+        priors = samples[:-1]
         if len(priors) < min_prior_runs:
             continue
         prior_ms = sorted(s[1] for s in priors)
         prior_cp = sorted(s[2] for s in priors)
-        qid = latest_rec.get("query_id")
+        qid = records[idx].get("query_id")
         for metric, latest, prior, grace in (
                 ("wall_ms", last_ms, prior_ms, grace_ms),
                 ("copied_bytes", last_cp, prior_cp, float(grace_bytes))):

@@ -674,9 +674,6 @@ GAUGE_NAMES = (
     "blaze_recovered_queries_total",
     "blaze_autoscale_target_seats",
     "blaze_autoscale_decisions_total",
-    "blaze_autopilot_overlays_active",
-    "blaze_autopilot_promotions_total",
-    "blaze_autopilot_rollbacks_total",
     "blaze_driver_role",
     "blaze_stream_lag_ms",
     "blaze_stream_batches_total",
@@ -948,25 +945,6 @@ def prometheus_text() -> str:
     emit("blaze_driver_role", "gauge",
          "Driver role of this process (1 for the held role)",
          [({"role": standby.role()}, 1)])
-
-    # self-tuning autopilot (runtime/autopilot.py): the folded
-    # OverlayStore posture — fingerprints with a live overlay, lifetime
-    # promotions, and rollbacks by knob (restart-persistent: the fold is
-    # what a restarted driver resumes from, so the counters are too)
-    from blaze_tpu.runtime import autopilot
-
-    apm = autopilot.metrics()
-    emit("blaze_autopilot_overlays_active", "gauge",
-         "Plan fingerprints with a settled or canary overlay (absent "
-         "with the autopilot off)",
-         [({}, apm["overlays_active"])] if apm else [])
-    emit("blaze_autopilot_promotions_total", "counter",
-         "Canary overlays promoted to settled",
-         [({}, apm["promotions_total"])] if apm else [])
-    emit("blaze_autopilot_rollbacks_total", "counter",
-         "Canary overlays rolled back + quarantined, by knob",
-         [({"knob": k}, n) for k, n in
-          sorted((apm or {}).get("rollbacks_total", {}).items())])
 
     # durable streaming (runtime/streaming.py): one series per LIVE
     # stream — a stopped stream's series disappears from the exposition

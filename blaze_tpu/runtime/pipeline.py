@@ -65,7 +65,6 @@ import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Iterator, Optional
 
-from blaze_tpu import config
 from blaze_tpu.config import conf
 from blaze_tpu.runtime import placement, trace
 from blaze_tpu.runtime.metrics import MetricsSet
@@ -174,22 +173,16 @@ class _CtxSnapshot:
     thread: trace correlation ids, the supervisor's current
     attempt/task so current_kill_event() / current_commit_gate() —
     and through them faults._stall's kill-interruptible sleep — work
-    inside pump bodies exactly as they do at batch boundaries, and the
-    query's resolved conf overlay (config.overlay_scope) so producers
-    reading adaptive batch knobs see the same per-query conf as the
-    task thread that opened the stream; and the chip the task was placed
-    on (runtime/placement.py), so a producer's uploads land where the
-    consumer's programs run."""
+    inside pump bodies exactly as they do at batch boundaries; and the
+    chip the task was placed on (runtime/placement.py), so a producer's
+    uploads land where the consumer's programs run."""
 
-    __slots__ = ("trace_ctx", "sup_attempt", "sup_task",
-                 "conf_overlay", "conf_provenance", "device")
+    __slots__ = ("trace_ctx", "sup_attempt", "sup_task", "device")
 
     def __init__(self) -> None:
         self.trace_ctx = trace.current_context()
         self.sup_attempt = None
         self.sup_task = None
-        self.conf_overlay = config.current_overlay()
-        self.conf_provenance = config.current_provenance()
         self.device = placement.current()
         try:
             from blaze_tpu.runtime import supervisor
@@ -207,9 +200,6 @@ class _CtxSnapshot:
         stack = ExitStack()
         stack.enter_context(trace.context(**self.trace_ctx))
         stack.enter_context(placement.on_device(self.device))
-        if self.conf_overlay:
-            stack.enter_context(config.overlay_scope(
-                self.conf_overlay, self.conf_provenance))
         cur = supervisor._current
         prev = (getattr(cur, "attempt", None), getattr(cur, "task", None))
         cur.attempt, cur.task = self.sup_attempt, self.sup_task

@@ -438,14 +438,9 @@ class QueryService:
     def run(self, root, tenant_id: str = "", *,
             priority: Optional[float] = None,
             run_info: Optional[Dict[str, Any]] = None,
-            conf_pins: Optional[Dict[str, Any]] = None,
             **run_plan_kwargs):
         """Admit + execute on the CALLING thread; returns the result
-        batch. Raises faults.AdmissionRejected when shed.
-
-        conf_pins: per-query knob overrides — the highest-precedence
-        overlay layer (base -> tenant -> autopilot fingerprint -> pin),
-        validated against the Knob registry at resolution."""
+        batch. Raises faults.AdmissionRejected when shed."""
         from blaze_tpu.spark import local_runner
 
         session = self.admit(tenant_id, priority)
@@ -454,8 +449,6 @@ class QueryService:
         run_info["tenant_id"] = session.tenant_id
         run_info["admission_outcome"] = session.admission_outcome
         run_info["admission_wait_ms"] = round(session.admission_wait_ms, 1)
-        if conf_pins:
-            run_info["conf_pins"] = dict(conf_pins)
         try:
             return local_runner.run_plan(root, run_info=run_info,
                                          session=session,
@@ -466,12 +459,10 @@ class QueryService:
     def submit(self, root, tenant_id: str = "", *,
                priority: Optional[float] = None,
                run_info: Optional[Dict[str, Any]] = None,
-               conf_pins: Optional[Dict[str, Any]] = None,
                **run_plan_kwargs) -> Future:
         """Admit on the calling thread (so AdmissionRejected raises
         HERE, synchronously — shedding must push back on the submitter),
-        then execute on a per-query driver thread; returns a Future.
-        conf_pins: as in run() — the per-query overlay layer."""
+        then execute on a per-query driver thread; returns a Future."""
         from blaze_tpu.spark import local_runner
 
         session = self.admit(tenant_id, priority)
@@ -480,8 +471,6 @@ class QueryService:
         run_info["tenant_id"] = session.tenant_id
         run_info["admission_outcome"] = session.admission_outcome
         run_info["admission_wait_ms"] = round(session.admission_wait_ms, 1)
-        if conf_pins:
-            run_info["conf_pins"] = dict(conf_pins)
         fut: Future = Future()
 
         def drive() -> None:

@@ -66,7 +66,6 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, FrozenSet, List, Optional, Tuple
 
-from blaze_tpu import config
 from blaze_tpu.config import conf
 from blaze_tpu.ops.base import ExecContext, TaskKilledError
 from blaze_tpu.runtime import faults, placement, trace
@@ -568,13 +567,6 @@ class _Task:
         # cross-thread records stay correlated; task_id = spec.what
         self.trace_ctx: Dict[str, Any] = dict(trace_ctx or {})
         self.trace_ctx["task_id"] = spec.what
-        # the submitting thread's resolved conf overlay
-        # (config.overlay_scope): replayed around every attempt so pool
-        # workers and speculative twins read the same per-query conf as
-        # the driver thread — one query's overlay never leaks into a
-        # concurrent query's tasks
-        self.conf_overlay = config.current_overlay()
-        self.conf_provenance = config.current_provenance()
         self._attempt_seq = itertools.count(1)
 
     def next_attempt_id(self) -> int:
@@ -855,11 +847,6 @@ class Supervisor:
                         is_running=att.is_running,
                         commit_gate=task.gate)
                     try:
-                        if task.conf_overlay:
-                            with config.overlay_scope(
-                                    task.conf_overlay,
-                                    task.conf_provenance):
-                                return task.spec.attempt_fn(ctx)
                         return task.spec.attempt_fn(ctx)
                     finally:
                         if att.kill_reason:

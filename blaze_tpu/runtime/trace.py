@@ -215,12 +215,6 @@ EVENT_KINDS = (
     "artifact_commit",      # runtime/artifacts.py: first-commit-wins publish
     "artifact_corrupt",     # artifacts: read-path checksum mismatch
     "artifact_quarantined", # artifacts: corrupt file renamed .quarantine
-    "autopilot_apply",      # local_runner: stored overlay applied to a
-                            # fingerprinted query at admission
-    "autopilot_explore",    # autopilot: canary proposed / canary win
-    "autopilot_promote",    # autopilot: canary graduated to settled
-    "autopilot_rollback",   # autopilot: canary reverted + quarantined
-                            # (regression verdict or inconclusive)
     "batch",                # ops/base.count_stream batch boundary
     "breaker_trip",         # supervisor: per-operator circuit breaker
     "compile_compiled",     # compile_service: fresh XLA compilation
@@ -1037,11 +1031,6 @@ def build_run_record(query_id: str, run_info: Optional[dict] = None,
     # can rank offline, from the record alone
     if isinstance(info.get("stream"), dict):
         rec["stream"] = dict(info["stream"])
-    # conf-overlay provenance (runtime/autopilot.py): the resolved
-    # overlay, which layer set each value, and the canary posture — the
-    # 3am "why did my query's conf change" answer, in the ledger line
-    if isinstance(info.get("autopilot"), dict):
-        rec["autopilot"] = dict(info["autopilot"])
     # sampling-profiler evidence (runtime/profiler.py): top self-time
     # frames so doctor's host_cpu_bound rule ranks offline, from the
     # record alone (diagnose() stays a pure function of its inputs)

@@ -166,16 +166,8 @@ def run_plan(root: SparkPlan, num_partitions: int = 4,
             profiler.export_query(qid)
         # persist the run's fingerprinted statistics (after the monitor
         # roll-up so the record carries the byte/spill/compile counters)
-        rec = (history.record_run(qid, run_info)
-               if conf.history_dir else None)
-        if conf.autopilot_enabled and conf.autopilot_dir:
-            # autopilot post-run hook (runtime/autopilot.py): verdict a
-            # canary against the settled baseline, or propose the next
-            # one-knob exploration — off the record just persisted, so
-            # its baselines and ours are the same bytes
-            from blaze_tpu.runtime import autopilot
-
-            autopilot.observe(qid, run_info, rec)
+        if conf.history_dir:
+            history.record_run(qid, run_info)
         if jnl is not None:
             # terminal journal record (classified from the in-flight
             # exception, the flight-recorder posture below): a journal
@@ -251,55 +243,22 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
             def provider(partition, nparts, _p=subtree):
                 return fallback.export_iterator(_p, partition, nparts)
             resources.put(rid, provider)
-        # pre-AQE query fingerprint: pins the journal's plan record AND keys
-        # the autopilot's persisted overlay — stable across runs of the same
-        # plan and known before execution (post-AQE shapes are not)
-        query_fp = fingerprint_query([fingerprint_plan(s.plan)
-                                      for s in stages])
         jnl = (None if run_info.get("stream")
                else journal.journal_for(run_info.get("query_id", "")))
         if jnl is not None:
             # the plan record pins what this journal is a log OF: the
-            # pre-AQE query fingerprint plus the stage skeleton (per-stage
-            # fingerprints — the resume keys — are journaled with each
-            # stage_commit, computed after AQE re-optimization)
-            jnl.plan(fingerprint=query_fp,
+            # pre-AQE query fingerprint (stable across runs of the same
+            # plan, known before execution) plus the stage skeleton
+            # (per-stage fingerprints — the resume keys — are journaled
+            # with each stage_commit, computed after AQE re-optimization)
+            jnl.plan(fingerprint=fingerprint_query(
+                         [fingerprint_plan(s.plan) for s in stages]),
                      num_partitions=num_partitions,
                      stages=[{"stage_id": s.stage_id, "kind": s.kind,
                               "num_partitions": s.num_partitions,
                               "plan_proto": base64.b64encode(
                                   s.plan.SerializeToString()).decode()}
                              for s in stages])
-        # -- conf overlays + self-tuning autopilot -------------------------
-        # resolve base -> tenant -> per-fingerprint -> per-query pin
-        # (config.resolve_overlay validates each layer against KNOBS); the
-        # values ride a thread-local scope around the stage loop below —
-        # supervisor tasks replay it around every attempt — and the record
-        # with per-value provenance is stamped into run_info for the
-        # ledger / history / flight dossiers
-        from blaze_tpu import config
-
-        fp_overlay: Dict[str, object] = {}
-        canary_knob = ""
-        if conf.autopilot_enabled and conf.autopilot_dir:
-            from blaze_tpu.runtime import autopilot
-
-            fp_overlay, canary_knob = autopilot.overlay_for(query_fp)
-        resolved = config.resolve_overlay(
-            tenant=run_info.get("tenant_id") or None,
-            fingerprint_overlay=fp_overlay or None,
-            pin=run_info.get("conf_pins") or None)
-        if canary_knob:
-            resolved.canary = True
-            resolved.canary_knob = canary_knob
-        if resolved.values or (conf.autopilot_enabled and conf.autopilot_dir):
-            run_info["autopilot"] = dict(resolved.as_record(),
-                                         fingerprint=query_fp)
-        if fp_overlay:
-            trace.event("autopilot_apply", fingerprint=query_fp,
-                        overlay_hash=resolved.hash or "",
-                        canary=bool(canary_knob), canary_knob=canary_knob,
-                        knobs=",".join(sorted(fp_overlay)))
         work_dir = work_dir or tempfile.mkdtemp(prefix="blaze_tpu_stages_")
         os.makedirs(work_dir, exist_ok=True)
 
@@ -342,16 +301,7 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
             progress = None
         qid = run_info.get("query_id", "")
         psp.set(stages=len(stages))
-    _ov = None
     try:
-        if resolved.values:
-            # overlay scope entered INSIDE the try so the finally is
-            # its only exit path — conf reads on this thread (and, via
-            # the supervisor's per-task replay, on worker threads) see
-            # the resolved values for exactly the stage loop's duration
-            _ov = config.overlay_scope(resolved.values,
-                                       resolved.provenance)
-            _ov.__enter__()
         for stage in stages:
             # re-optimize THIS stage with the statistics of completed
             # shuffles before running it (ref: AQE per-stage re-entry)
@@ -523,8 +473,6 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                 return _merge_fallback_root_sort(root, out, parts)
         raise AssertionError("no result stage produced")
     finally:
-        if _ov is not None:
-            _ov.__exit__(None, None, None)
         sup.close()
         if run_info["mesh_stages"]:
             # what the mesh stages' overflow sent through files: the only

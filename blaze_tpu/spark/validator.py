@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import traceback
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 import pandas as pd
@@ -566,6 +566,69 @@ def _to_pandas(batch) -> pd.DataFrame:
                          for k, v in batch.to_numpy().items()})
 
 
+def _suite(suite: str):
+    """(generate_tables, catalogue, joinless) of a suite: "core" = the
+    BASELINE config shapes in this module; "tpcds" = the
+    hand-constructed TPC-DS q01-q10 catalogue (spark/tpcds.py, the
+    north-star queries)."""
+    if suite == "tpcds":
+        from blaze_tpu.spark import tpcds
+
+        return tpcds.generate_tables, tpcds.QUERIES, tpcds.JOINLESS
+    return generate_tables, QUERIES, _JOINLESS
+
+
+def matrix_cells(suite: str = "core",
+                 queries: Optional[List[str]] = None
+                 ) -> List[Tuple[str, str]]:
+    """The (query, join mode) cells of a suite, in catalogue order; a
+    joinless query has one (the axis is inert)."""
+    _, catalogue, joinless = _suite(suite)
+    return [(name, mode) for name in catalogue
+            if not queries or name in queries
+            for mode in (["bhj"] if name in joinless else ["bhj", "smj"])]
+
+
+def run_cell(paths, frames, name: str, mode: str,
+             spill_budget: Optional[int] = None,
+             suite: str = "core") -> Result:
+    """One cell of the matrix over tables already generated: fresh plan
+    through run_plan, diffed against its pandas oracle. `ok` refuses an
+    oracle-equal answer that a fallback served, unless the cell asked
+    for faults or forced spill (it is SUPPOSED to climb the ladder
+    then)."""
+    from blaze_tpu.config import conf
+    from blaze_tpu.runtime import memory as M
+
+    _, catalogue, _ = _suite(suite)
+    strict = not spill_budget and not conf.fault_injection_spec
+    t0 = time.time()
+    mgr = M.init(spill_budget) if spill_budget else M.get_manager()
+    # deltas, not totals: without spill_budget the SHARED global
+    # manager carries counts from earlier cells/process activity
+    sc0, sb0 = mgr.spill_count, mgr.spilled_bytes
+    run_info: Dict = {}
+    diff = error = None
+    try:
+        plan, oracle = catalogue[name](paths, frames, mode)
+        out = run_plan(plan, num_partitions=4, run_info=run_info)
+        # order-insensitive where the plan has no global sort tail
+        diff = _compare(_to_pandas(out).reset_index(drop=True),
+                        oracle().reset_index(drop=True))
+        evidence = fallback_evidence(run_info) if strict else {}
+        if diff is None and evidence:
+            diff = f"oracle-equal, but served by a fallback: {evidence}"
+    except Exception:
+        error = traceback.format_exc(limit=8)
+    return Result(
+        name, mode, diff is None and error is None,
+        time.time() - t0, error=error, diff=diff,
+        spill_count=mgr.spill_count - sc0,
+        spilled_bytes=mgr.spilled_bytes - sb0,
+        run_info={k: v for k, v in run_info.items()
+                  if isinstance(v, (int, float, str))})
+
+
 def run_matrix(tmpdir: str, rows: int = 20_000,
                queries: Optional[List[str]] = None,
                spill_budget: Optional[int] = None,
@@ -574,63 +637,19 @@ def run_matrix(tmpdir: str, rows: int = 20_000,
     bytes before every cell so sort/agg/shuffle spill fires IN QUERY
     CONTEXT (the reference fuzz-gates a 1.23M-row external sort under
     MemManager::init(10000), sort_exec.rs:954) — each Result then records
-    the spill counters the run produced.
-
-    suite: "core" = the BASELINE config shapes in this module;
-    "tpcds" = the hand-constructed TPC-DS q01-q10 catalogue
-    (spark/tpcds.py, the north-star queries)."""
-    from blaze_tpu.config import conf
-    from blaze_tpu.runtime import memory as M
-
-    if suite == "tpcds":
-        from blaze_tpu.spark import tpcds
-
-        paths, frames = tpcds.generate_tables(tmpdir, rows=rows)
-        catalogue, joinless = tpcds.QUERIES, tpcds.JOINLESS
-    else:
-        paths, frames = generate_tables(tmpdir, rows=rows)
-        catalogue, joinless = QUERIES, _JOINLESS
-    # a cell that asked for faults or forced spill is SUPPOSED to climb
-    # the ladder; any other cell must not pass because of it
-    strict = not spill_budget and not conf.fault_injection_spec
+    the spill counters the run produced."""
+    generate, _, _ = _suite(suite)
+    paths, frames = generate(tmpdir, rows=rows)
     results: List[Result] = []
-    for name, build in catalogue.items():
-        if queries and name not in queries:
-            continue
-        modes = ["bhj"] if name in joinless else ["bhj", "smj"]
-        for mode in modes:
-            t0 = time.time()
-            mgr = M.init(spill_budget) if spill_budget else M.get_manager()
-            # deltas, not totals: without spill_budget the SHARED global
-            # manager carries counts from earlier cells/process activity
-            sc0, sb0 = mgr.spill_count, mgr.spilled_bytes
-            run_info: Dict = {}
-            diff = error = None
-            try:
-                plan, oracle = build(paths, frames, mode)
-                out = run_plan(plan, num_partitions=4, run_info=run_info)
-                # order-insensitive where the plan has no global sort tail
-                diff = _compare(_to_pandas(out).reset_index(drop=True),
-                                oracle().reset_index(drop=True))
-                evidence = fallback_evidence(run_info) if strict else {}
-                if diff is None and evidence:
-                    diff = f"oracle-equal, but served by a fallback: {evidence}"
-            except Exception:
-                error = traceback.format_exc(limit=8)
-            results.append(Result(
-                name, mode, diff is None and error is None,
-                time.time() - t0, error=error, diff=diff,
-                spill_count=mgr.spill_count - sc0,
-                spilled_bytes=mgr.spilled_bytes - sb0,
-                run_info={k: v for k, v in run_info.items()
-                          if isinstance(v, (int, float, str))}))
-            r = results[-1]
-            # incremental progress: long matrices run under timeouts in
-            # background shells — per-cell lines must not be lost to a
-            # buffered final report
-            print(f"[cell] {r.query} {r.mode} "
-                  f"{'PASS' if r.ok else 'FAIL'} {r.seconds:.1f}s "
-                  f"spills={r.spill_count}", flush=True)
+    for name, mode in matrix_cells(suite, queries):
+        r = run_cell(paths, frames, name, mode, spill_budget, suite)
+        results.append(r)
+        # incremental progress: long matrices run under timeouts in
+        # background shells — per-cell lines must not be lost to a
+        # buffered final report
+        print(f"[cell] {r.query} {r.mode} "
+              f"{'PASS' if r.ok else 'FAIL'} {r.seconds:.1f}s "
+              f"spills={r.spill_count}", flush=True)
     return results
 
 

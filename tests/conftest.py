@@ -23,3 +23,16 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+# xdist's loadfile hands files to its workers in collection order, so a long
+# file late in the alphabet (test_tpcds_smj.py: minutes) ends alone on one
+# worker after the others have drained; the longest files go out first.
+_LONGEST_FIRST = ("test_tpcds_smj.py", "test_tpcds.py", "test_join.py",
+                  "test_mesh_resident.py", "test_validator.py",
+                  "test_compile_service.py", "test_spark_planner.py")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))

@@ -287,6 +287,21 @@ def test_trace_context_replayed_on_pool_thread():
     assert stats[0]["stage_id"] == 7
 
 
+def test_conf_is_one_value_on_the_pump_thread():
+    """A value set with conf.update on the constructing thread is the
+    value the producer reads on the I/O pool's thread."""
+    conf.update(enable_pipeline=True, prefetch_batches=3)
+    seen = []
+
+    def gen():
+        seen.append((threading.get_ident(), conf.prefetch_batches))
+        yield 1
+
+    assert list(pipeline.prefetch(gen(), 2)) == [1]
+    (ident, value), = seen
+    assert value == 3 and ident != threading.get_ident()
+
+
 def test_occupancy_stats_and_histograms():
     conf.trace_enabled = True
     trace.reset()

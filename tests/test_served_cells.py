@@ -1,0 +1,111 @@
+"""The benchmark's one-chip cells on the CPU, at rehearsal rows: each cell's
+own plan (benchmarks/queries) over its own generator's tables through
+run_plan equals its plain reference with nothing refused
+(benchmarks/harness/evidence.py), and once warm a query compiles nothing:
+the `compiles_in_window` contract of BENCHMARK.json. The cells, their
+configurations and their traffic are read from the manifest."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import pytest
+
+from blaze_tpu.runtime import compile_service
+from blaze_tpu.spark.local_runner import run_plan
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+ROWS = 50_000
+SEED = 11
+
+
+def _load(rel: str):
+    spec = importlib.util.spec_from_file_location(
+        "sc_" + rel.replace("/", "_").replace(".", "_"),
+        os.path.join(BENCH, rel))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _json(*rel: str):
+    with open(os.path.join(*rel)) as fh:
+        return json.load(fh)
+
+
+ONE_CHIP = {w["name"]: w for w in _json(REPO, "BENCHMARK.json")["workloads"]
+            if w["chips"] == 1}
+
+
+def test_the_manifest_has_the_one_chip_cells_this_file_names():
+    assert set(ONE_CHIP) == {"sf10_q03_bhj", "sf1_q06core_agg",
+                             "sf1_q03_nobhj"}
+
+
+@pytest.fixture(scope="module")
+def cells(tmp_path_factory):
+    """name -> one query of the cell: fresh plan through run_plan, the
+    differences from the reference (None = equal) and the refusals."""
+    compare, evidence = _load("harness/compare.py"), _load("harness/evidence.py")
+    tables = {}
+
+    def cell(name: str):
+        config = _json(BENCH, "configs", ONE_CHIP[name]["config"] + ".json")
+        (entry,) = _json(BENCH, "traffic",
+                         ONE_CHIP[name]["traffic"] + ".json")["mix"]
+        query = _load(f"queries/{entry['query']}.py")
+        if config["name"] not in tables:
+            tables[config["name"]] = _load(
+                f"datagen/{config['generator']}.py").generate(
+                    config, SEED,
+                    str(tmp_path_factory.mktemp(config["name"])), ROWS)
+        paths, frames = tables[config["name"]]
+        settings, params = config["settings"], entry["params"]
+
+        def run():
+            info: dict = {}
+            got = compare.to_frame(run_plan(
+                query.plan(paths, config, params),
+                num_partitions=settings["exchange_width"],
+                mesh_exchange=settings["mesh_exchange"], run_info=info))
+            want = query.reference(frames, config, params)
+            assert len(want) > 0
+            wrong = compare.diff(got, want,
+                                 config["guarantees"]["float_rtol"],
+                                 query.ORDER_KEYS)
+            return wrong, evidence.refusals(info, 1,
+                                            settings["exchange_width"])
+
+        return run
+
+    return cell
+
+
+@pytest.fixture
+def one_chip(monkeypatch):
+    """Show the program one of the eight virtual devices, as the cell's
+    machine does."""
+    real = jax.devices
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: real(*a, **k)[:1])
+
+
+@pytest.mark.parametrize("name", ["sf10_q03_bhj", "sf1_q06core_agg"])
+def test_cell_equals_its_reference_and_nothing_is_refused(
+        cells, one_chip, name):
+    wrong, refused = cells(name)()
+    assert wrong is None
+    assert refused == []
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP))
+def test_a_warm_query_compiles_nothing(cells, one_chip, name):
+    run = cells(name)
+    for _ in range(2):      # the benchmark's two warm-up queries
+        assert run() == (None, [])
+    before = compile_service.TELEMETRY.snapshot().get("compile_count", 0)
+    assert before > 0       # the counter counts: the warm-ups compiled
+    assert run() == (None, [])
+    assert compile_service.TELEMETRY.snapshot().get(
+        "compile_count", 0) == before

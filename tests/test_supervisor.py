@@ -262,6 +262,29 @@ def test_run_tasks_ordered_results_and_concurrency():
     assert peak[0] > 1, "tasks must actually overlap on the pool"
 
 
+def test_conf_is_one_value_on_every_task_thread():
+    """conf.<knob> is a plain attribute: what the driver thread set with
+    conf.update is what a supervisor task reads on its pool thread, with
+    nothing captured or replayed."""
+    assert type(conf).__getattribute__ is object.__getattribute__
+    conf.update(max_concurrent_tasks=4, retry_backoff_ms=7)
+    sup = Supervisor()
+    driver = threading.get_ident()
+
+    def attempt(ctx):
+        time.sleep(0.02)    # so the pool spreads the four over threads
+        return threading.get_ident(), conf.retry_backoff_ms
+
+    try:
+        specs = [TaskSpec(what=f"t{i}", attempt_fn=attempt, partition=i,
+                          num_partitions=4) for i in range(4)]
+        seen = sup.run_tasks("s", specs)
+    finally:
+        sup.close()
+    assert [v for _, v in seen] == [7] * 4
+    assert any(ident != driver for ident, _ in seen)
+
+
 def test_first_task_error_kills_siblings():
     conf.max_concurrent_tasks = 4
     sup = Supervisor()

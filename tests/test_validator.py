@@ -4,18 +4,24 @@ runs the same matrix standalone with bigger data."""
 
 import pytest
 
-from blaze_tpu.spark.validator import QUERIES, _JOINLESS, run_matrix
+from blaze_tpu.spark.validator import (QUERIES, _JOINLESS, generate_tables,
+                                       matrix_cells, run_cell, run_matrix)
 
 
-def test_validator_matrix(tmp_path):
-    results = run_matrix(str(tmp_path), rows=4000)
+@pytest.fixture(scope="module")
+def tables(tmp_path_factory):
+    return generate_tables(str(tmp_path_factory.mktemp("core")), rows=4000)
+
+
+def test_validator_matrix_cell_count():
     expected_cells = sum(1 if q in _JOINLESS else 2 for q in QUERIES)
-    assert len(results) == expected_cells
-    failures = [r for r in results if not r.ok]
-    msg = "\n".join(
-        f"{r.query}[{r.mode}]: {r.diff or ''} {r.error or ''}"
-        for r in failures)
-    assert not failures, msg
+    assert len(matrix_cells()) == expected_cells == 15
+
+
+@pytest.mark.parametrize("name,mode", matrix_cells())
+def test_validator_matrix(tables, name, mode):
+    r = run_cell(*tables, name, mode)
+    assert r.ok, f"{r.query}[{r.mode}]: {r.diff or ''} {r.error or ''}"
 
 
 def test_cell_served_by_a_fallback_fails(tmp_path, monkeypatch):

@@ -115,20 +115,6 @@ def render(metrics: dict, source: str) -> str:
             f"target_seats={int(g('blaze_autoscale_target_seats'))} "
             f"scale_ups={ups} scale_downs={downs}"
             + ("  ** STANDBY **" if role == "standby" else ""))
-    rollback_rows = [(k, v) for k, v in metrics.items()
-                     if k.startswith("blaze_autopilot_rollbacks_total{")]
-    if ("blaze_autopilot_overlays_active" in metrics or rollback_rows):
-        rollbacks = int(sum(v for _, v in rollback_rows))
-        by_knob = " ".join(
-            k.split('knob="', 1)[-1].rstrip('"}') + f"={int(v)}"
-            for k, v in sorted(rollback_rows) if v)
-        lines.append(
-            f"autopilot overlays="
-            f"{int(g('blaze_autopilot_overlays_active'))} "
-            f"promotions={int(g('blaze_autopilot_promotions_total'))} "
-            f"rollbacks={rollbacks}"
-            + (f" [{by_knob}]" if by_knob else "")
-            + ("  ** ROLLED BACK **" if rollbacks else ""))
     if "blaze_profile_samples_total" in metrics:
         p_dropped = int(g("blaze_profile_dropped_total"))
         lines.append(

@@ -1,25 +1,17 @@
 """Doctor↔knob sync checker.
 
-The self-tuning autopilot (``runtime/autopilot.py``) parses the top
-doctor finding's ``suggestion`` string for a ``conf.<knob>`` mention and
-steps that knob — so the suggestion text is machine-actuated, not
-advisory prose. Two invariants keep that loop closed:
+A doctor finding's ``suggestion`` string is what the operator reading a
+dossier or a ledger line acts on, so it must name something that
+exists:
 
   * **unactionable-suggestion** (error): every ``Finding(...)``
     constructed in ``runtime/doctor.py`` must name at least one declared
     Knob as ``conf.<name>`` in its suggestion, and every ``conf.<name>``
     it mentions must resolve in the ``KNOBS`` registry. A typo'd or
-    free-form suggestion silently disables the autopilot for that
-    finding class (and misleads the operator reading the dossier).
-  * **actuator-schedule** (error): every knob in autopilot's
-    ``ACTUATORS`` registry must be declared in ``KNOBS`` with a full
-    step schedule (``step``/``min``/``max`` all set) — the explorer
-    refuses to move a knob without declared rails, so a schedule-less
-    actuator is dead weight that LOOKS autotunable.
+    free-form suggestion misleads the operator reading the dossier.
 
 The knob registry is loaded by executing ``config.py`` standalone (the
-knob-registry checker's posture — never ``import blaze_tpu``);
-``ACTUATORS`` is extracted from the autopilot module's AST.
+knob-registry checker's posture — never ``import blaze_tpu``).
 """
 
 from __future__ import annotations
@@ -30,10 +22,9 @@ from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from tools.blazelint.core import (Checker, Finding, ModuleInfo, call_name,
-                                  load_config_module, module_registry)
+                                  load_config_module)
 
 DOCTOR_REL = "blaze_tpu/runtime/doctor.py"
-AUTOPILOT_REL = "blaze_tpu/runtime/autopilot.py"
 
 _KNOB_RE = re.compile(r"conf\.([a-z0-9_]+)")
 
@@ -62,15 +53,10 @@ class DoctorKnobSync(Checker):
             knobs = dict(load_config_module(root / config_rel).KNOBS)
         self.knobs = knobs
         self._suggestions: List[Tuple[ModuleInfo, ast.Call, str]] = []
-        self._actuators: Optional[List[str]] = None
-        self._autopilot_seen = False
 
     # -- per module --------------------------------------------------------
 
     def check_module(self, mod: ModuleInfo) -> Iterable[Finding]:
-        if mod.rel == AUTOPILOT_REL:
-            self._autopilot_seen = True
-            self._actuators = module_registry(mod.tree, "ACTUATORS")
         if mod.rel != DOCTOR_REL:
             return ()
         for node in ast.walk(mod.tree):
@@ -108,41 +94,7 @@ class DoctorKnobSync(Checker):
                     checker=self.name, rule="unactionable-suggestion",
                     path=mod.rel, line=node.lineno, severity="error",
                     message=("Finding suggestion names no declared "
-                             "conf.<knob> — the autopilot (and the 3am "
-                             "operator) cannot act on it"),
+                             "conf.<knob> — the 3am operator cannot "
+                             "act on it"),
                     symbol="suggestion"))
-        if self._autopilot_seen:
-            if self._actuators is None:
-                findings.append(Finding(
-                    checker=self.name, rule="missing-registry",
-                    path=AUTOPILOT_REL, line=1, severity="error",
-                    message=("module-level registry ACTUATORS not found "
-                             "in runtime/autopilot.py"),
-                    symbol="ACTUATORS"))
-            else:
-                findings.extend(self._check_actuators())
-        return findings
-
-    def _check_actuators(self) -> List[Finding]:
-        findings: List[Finding] = []
-        for name in self._actuators or []:
-            knob = self.knobs.get(name)
-            if knob is None:
-                findings.append(Finding(
-                    checker=self.name, rule="actuator-schedule",
-                    path=AUTOPILOT_REL, line=1, severity="error",
-                    message=(f"ACTUATORS entry {name!r} is not a "
-                             f"declared knob in config.KNOBS"),
-                    symbol=name))
-                continue
-            missing = [f for f in ("step", "min", "max")
-                       if getattr(knob, f, None) is None]
-            if missing:
-                findings.append(Finding(
-                    checker=self.name, rule="actuator-schedule",
-                    path=AUTOPILOT_REL, line=1, severity="error",
-                    message=(f"actuatable knob {name!r} declares no "
-                             f"{'/'.join(missing)} — the explorer "
-                             f"cannot step a knob without rails"),
-                    symbol=name))
         return findings

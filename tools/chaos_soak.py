@@ -89,22 +89,6 @@ aggregation state oracle-equal to a pandas replay of EVERY published
 file (0 dropped, 0 double-counted rows), checkpoint epochs strictly
 monotone across both drivers, exactly ONE driver_failover dossier.
 
-`--autopilot` (ISSUE 18): the self-tuning-autopilot acceptance run,
-emitting `AUTOPILOT_r22.json`. (1) converge: a 400ms stall armed on
-EVERY serde.encode call makes frame count the dominant cost, so the
-doctor's serde_bound suggestion (raise conf.target_batch_bytes) is
-genuinely right; the explorer must canary its way up the knob's
-declared schedule — stepping OVER the neutral 512KB plateau via an
-inconclusive-canary quarantine — until a promoted settled overlay
-beats the base configuration's p50, with every run pandas-oracle-equal
-and no (knob, value) proposed twice. (2) poison: a seeded proposal
-that SHRINKS target_batch_bytes (strictly more frames under the same
-stall) must draw a regression verdict on its first canary run, roll
-back, quarantine the value, capture exactly one autopilot_rollback
-flight dossier, keep the quarantine across a driver restart (store
-refold), and never re-propose the value. (3) an autopilot on/off A/B
-with the explorer idled must be within noise.
-
 `--profile` (ISSUE 19): the continuous-profiling acceptance run,
 emitting `PROFILE_r23.json`. (1) attrib: four deterministic 250ms
 stalls armed on serde.encode with the sampling profiler on — the
@@ -2082,237 +2066,6 @@ def _streaming_round(args):
     return rec
 
 
-def _autopilot_run(tables, run_info=None):
-    """One oracle-checked q3 driver run under whatever overlay the
-    autopilot currently holds for its fingerprint."""
-    from blaze_tpu.spark import validator
-    from blaze_tpu.spark.local_runner import run_plan
-
-    paths, frames = tables
-    plan, oracle = validator.QUERIES["q3_join_agg_sort"](paths, frames,
-                                                         "smj")
-    info = dict(run_info or {})
-    work_dir = tempfile.mkdtemp(prefix="chaos_ap_cell_")
-    t0 = time.time()
-    try:
-        out = run_plan(plan, num_partitions=4, work_dir=work_dir,
-                       mesh_exchange="off", run_info=info)
-        diff = validator._compare(
-            validator._to_pandas(out).reset_index(drop=True),
-            oracle().reset_index(drop=True))
-    finally:
-        shutil.rmtree(work_dir, ignore_errors=True)
-    ap = info.get("autopilot") or {}
-    return {"seconds": round(time.time() - t0, 3),
-            "canary": bool(ap.get("canary")),
-            "overlay": ap.get("overlay") or {},
-            "fingerprint": ap.get("fingerprint"),
-            "diff": diff}
-
-
-def _p50(xs):
-    return sorted(xs)[len(xs) // 2] if xs else 0.0
-
-
-def _autopilot_converge_round(tables, args):
-    """Convergence: a 400ms stall on every serde.encode call makes frame
-    count the dominant cost, so the doctor's serde_bound finding (raise
-    conf.target_batch_bytes) is RIGHT. The explorer must walk the knob up
-    — through the neutral 512KB plateau (inconclusive canary ->
-    quarantine -> step over) — and promote a settled overlay whose p50
-    beats the base configuration's. Every run stays oracle-equal and no
-    (knob, value) is ever proposed twice (no oscillation)."""
-    from blaze_tpu.config import conf
-    from blaze_tpu.runtime import autopilot, faults, history
-
-    conf.trace_enabled = True  # stage records feed doctor + verdicts
-    conf.autopilot_canary_runs = 2
-    conf.target_batch_bytes = 1 << 18
-    rnd = {"round": "autopilot_converge", "runs": []}
-    faults.install({"seed": args.seed, "concurrent": True,
-                    "points": {"serde.encode": {"kind": "stall",
-                                                "ms": 400}}})
-    try:
-        # warm jit caches with the plane OFF so the warm run never
-        # reaches the history baseline the explorer gates on
-        conf.autopilot_enabled = False
-        conf.history_dir = ""
-        _autopilot_run(tables)
-        conf.autopilot_enabled = True
-        conf.autopilot_dir = tempfile.mkdtemp(prefix="chaos_ap_store_")
-        conf.history_dir = tempfile.mkdtemp(prefix="chaos_ap_hist_")
-        autopilot.reset()
-        history.reset()
-        wrong = 0
-        fp = None
-        for _ in range(34):
-            cell = _autopilot_run(tables)
-            rnd["runs"].append({k: cell[k] for k in
-                                ("seconds", "canary", "overlay")})
-            if cell["diff"] is not None:
-                wrong += 1
-                rnd.setdefault("diffs", []).append(cell["diff"])
-            fp = cell["fingerprint"] or fp
-            st = autopilot.active().state_for(fp)
-            settled = [r for r in rnd["runs"]
-                       if not r["canary"] and r["overlay"] == st.settled]
-            base = [r["seconds"] for r in rnd["runs"][:3]]
-            if (st.promotions >= 1 and len(settled) >= 3
-                    and _p50([r["seconds"] for r in settled[-3:]])
-                    < _p50(base) * 0.95):
-                break
-    finally:
-        faults.install(None)
-    st = autopilot.active().state_for(fp)
-    proposes = [(r["knob"], r["value"])
-                for r in autopilot.active().store.load_records()
-                if r["kind"] == "propose"]
-    settled = [r["seconds"] for r in rnd["runs"]
-               if not r["canary"] and r["overlay"] == st.settled]
-    rnd.update({
-        "wrong_answers": wrong,
-        "promotions": st.promotions,
-        "rollbacks": st.rollbacks,
-        "settled_overlay": dict(st.settled),
-        "quarantine": {k: list(v) for k, v in st.quarantine.items()},
-        "proposes": [f"{k}={v}" for k, v in proposes],
-        "oscillated": len(proposes) != len(set(proposes)),
-        "base_p50_s": round(_p50([r["seconds"]
-                                  for r in rnd["runs"][:3]]), 3),
-        "settled_p50_s": round(_p50(settled[-3:]), 3),
-    })
-    rnd["converged"] = bool(
-        not wrong and not rnd["oscillated"] and st.promotions >= 1
-        and st.settled.get("target_batch_bytes", 0) > (1 << 18)
-        and rnd["settled_p50_s"] < rnd["base_p50_s"])
-    return rnd
-
-
-def _autopilot_poison_round(tables, args):
-    """Rollback: seed the store with a POISONED proposal (shrink
-    target_batch_bytes to 256KB under the same stall — strictly more
-    frames, strictly slower). The first canary run must come back as a
-    regression verdict, roll back, quarantine the value, and capture an
-    autopilot_rollback flight dossier; the quarantine must survive a
-    driver restart (module cache dropped, store refolded) and the value
-    must never be re-proposed."""
-    from blaze_tpu.config import conf
-    from blaze_tpu.runtime import autopilot, faults, history
-
-    conf.autopilot_enabled = True
-    conf.autopilot_dir = tempfile.mkdtemp(prefix="chaos_ap_store_")
-    conf.history_dir = tempfile.mkdtemp(prefix="chaos_ap_hist_")
-    conf.flight_dir = tempfile.mkdtemp(prefix="chaos_ap_flight_")
-    conf.flight_triggers = "all"
-    conf.trace_enabled = True
-    conf.autopilot_canary_runs = 2
-    conf.history_regression_pct = 15.0
-    conf.target_batch_bytes = 4 << 20
-    autopilot.reset()
-    history.reset()
-    poisoned = 1 << 18
-    rnd = {"round": "autopilot_poison", "runs": []}
-    faults.install({"seed": args.seed, "concurrent": True,
-                    "points": {"serde.encode": {"kind": "stall",
-                                                "ms": 400}}})
-    try:
-        wrong = 0
-        fp = None
-        for _ in range(3):  # settle a baseline at the healthy 4MB
-            cell = _autopilot_run(tables)
-            rnd["runs"].append({k: cell[k] for k in
-                                ("seconds", "canary", "overlay")})
-            wrong += int(cell["diff"] is not None)
-            fp = cell["fingerprint"] or fp
-        autopilot.active().store.append(
-            "propose", fp, knob="target_batch_bytes", value=poisoned,
-            direction=-1, finding="poisoned", current=4 << 20)
-        autopilot.reset()  # refold: the canary arms on the next run
-        budget = int(conf.autopilot_canary_runs)
-        canaries = 0
-        for _ in range(budget):
-            cell = _autopilot_run(tables)
-            rnd["runs"].append({k: cell[k] for k in
-                                ("seconds", "canary", "overlay")})
-            wrong += int(cell["diff"] is not None)
-            canaries += int(cell["canary"])
-            if autopilot.active().state_for(fp).rollbacks >= 1:
-                break
-        st = autopilot.active().state_for(fp)
-        quarantined = st.quarantined("target_batch_bytes", poisoned)
-        rolled_back = [r for r in autopilot.active().store.load_records()
-                      if r["kind"] == "rollback"]
-        autopilot.reset()  # driver restart: quarantine must survive
-        survived = autopilot.active().state_for(fp).quarantined(
-            "target_batch_bytes", poisoned)
-        for _ in range(2):  # the value must never come back as a canary
-            cell = _autopilot_run(tables)
-            rnd["runs"].append({k: cell[k] for k in
-                                ("seconds", "canary", "overlay")})
-            wrong += int(cell["diff"] is not None)
-        reproposed = any(
-            r["kind"] == "propose" and r.get("value") == poisoned
-            and r.get("finding") != "poisoned"
-            for r in autopilot.active().store.load_records())
-    finally:
-        faults.install(None)
-    import glob as _glob
-    dossiers = _glob.glob(os.path.join(conf.flight_dir,
-                                       "dossier_*autopilot_rollback*"))
-    rnd.update({
-        "wrong_answers": wrong,
-        "canary_runs_before_rollback": canaries,
-        "rolled_back": bool(rolled_back),
-        "rollback_reason": (rolled_back[0].get("reason")
-                            if rolled_back else None),
-        "quarantined": quarantined,
-        "quarantine_survived_restart": survived,
-        "reproposed_after_quarantine": reproposed,
-        "rollback_dossiers": len(dossiers),
-    })
-    rnd["contained"] = bool(
-        not wrong and rolled_back and quarantined and survived
-        and not reproposed and canaries <= budget
-        and len(dossiers) == 1)
-    return rnd
-
-
-def _autopilot_overhead(tables, args):
-    """Idle-autopilot A/B: with no faults armed and a too-thin history
-    baseline (reset each rep, so the explorer never proposes), the
-    resolve/observe path must be noise-level — autopilot-on p50 within
-    15% of autopilot-off."""
-    from blaze_tpu.config import conf
-    from blaze_tpu.runtime import autopilot, history
-    from blaze_tpu.spark import validator
-    from blaze_tpu.spark.local_runner import run_plan
-
-    paths, frames = tables
-
-    def rep():
-        history.reset()
-        conf.history_dir = tempfile.mkdtemp(prefix="chaos_ap_ab_")
-        plan, _ = validator.QUERIES["q1_scan_filter_project"](
-            paths, frames, "bhj")
-        t0 = time.time()
-        run_plan(plan, num_partitions=4, mesh_exchange="off")
-        return time.time() - t0
-
-    rep()  # warm jit caches
-    conf.autopilot_enabled = False
-    off = [rep() for _ in range(5)]
-    conf.autopilot_enabled = True
-    conf.autopilot_dir = tempfile.mkdtemp(prefix="chaos_ap_store_")
-    autopilot.reset()
-    on = [rep() for _ in range(5)]
-    rnd = {"round": "autopilot_overhead",
-           "off_p50_s": round(_p50(off), 4),
-           "on_p50_s": round(_p50(on), 4)}
-    rnd["within_noise"] = (rnd["on_p50_s"]
-                           <= rnd["off_p50_s"] * 1.15 + 0.05)
-    return rnd
-
-
 def _overhead(tables):
     """Disabled-path cost: the microbench backs the <=1%-claim at the
     per-call level; the catalogue A/B shows end-to-end parity with an
@@ -2946,18 +2699,6 @@ def main() -> int:
                          "from its journal, resumed from the last "
                          "committed checkpoint, final state pandas-oracle "
                          "equal with strictly monotone checkpoint epochs")
-    ap.add_argument("--autopilot", action="store_true",
-                    help="self-tuning autopilot acceptance: under a "
-                         "seeded 400ms serde.encode stall the explorer "
-                         "must converge target_batch_bytes upward "
-                         "(canary -> consecutive wins -> promoted "
-                         "settled overlay beating the base p50, zero "
-                         "wrong answers, zero oscillation); a poisoned "
-                         "proposal must roll back on its first "
-                         "regression verdict, quarantine the value "
-                         "across a driver restart, and never be "
-                         "re-proposed; an autopilot on/off A/B must be "
-                         "within noise")
     ap.add_argument("--concurrent-queries", type=int, default=8,
                     help="client sessions per --service round")
     ap.add_argument("--tenants", type=int, default=3,
@@ -2971,7 +2712,6 @@ def main() -> int:
     args = ap.parse_args()
     if args.json_out is None:
         args.json_out = ("PROFILE_r23.json" if args.profile
-                         else "AUTOPILOT_r22.json" if args.autopilot
                          else "STREAMING_r21.json" if args.streaming
                          else "ELASTIC_r20.json" if args.elastic
                          else "NETWORK_r19.json" if args.network
@@ -2994,15 +2734,7 @@ def main() -> int:
         "max_concurrent_tasks", "hang_detect_ms", "speculation_multiplier",
         "trace_enabled", "trace_export_dir", "enable_pipeline",
         "max_concurrent_queries", "admission_queue_depth",
-        "tenant_priority_spec", "tenant_quota_spec",
-        "autopilot_enabled", "autopilot_dir", "autopilot_canary_runs",
-        "history_dir", "history_regression_pct", "flight_dir",
-        "flight_triggers", "target_batch_bytes")}
-    if args.autopilot and args.rows == ap.get_default("rows"):
-        # the gate's knob physics need enough shuffle volume that
-        # target_batch_bytes visibly changes the serde.encode frame
-        # count (at 24k rows: 256KB->32 calls, 1MB->28, 2MB->24)
-        args.rows = 24000
+        "tenant_priority_spec", "tenant_quota_spec")}
     if args.pipeline:
         conf.enable_pipeline = True
     if args.supervisor:
@@ -3044,50 +2776,6 @@ def main() -> int:
 
     tmpdir = tempfile.mkdtemp(prefix="chaos_tables_")
     tables = validator.generate_tables(tmpdir, rows=args.rows)
-
-    if args.autopilot:
-        from blaze_tpu.runtime import autopilot, history
-        try:
-            rounds = [_autopilot_converge_round(tables, args),
-                      _autopilot_poison_round(tables, args),
-                      _autopilot_overhead(tables, args)]
-        finally:
-            shutil.rmtree(tmpdir, ignore_errors=True)
-            for k, v in saved_conf.items():
-                setattr(conf, k, v)
-            autopilot.reset()
-            history.reset()
-        bad = []
-        converge, poison, ab = rounds
-        if not converge.get("converged"):
-            bad.append({"round": converge["round"], "converged": False,
-                        "wrong_answers": converge.get("wrong_answers"),
-                        "oscillated": converge.get("oscillated"),
-                        "promotions": converge.get("promotions"),
-                        "settled_overlay": converge.get("settled_overlay"),
-                        "base_p50_s": converge.get("base_p50_s"),
-                        "settled_p50_s": converge.get("settled_p50_s")})
-        if not poison.get("contained"):
-            bad.append({k: poison.get(k) for k in (
-                "round", "wrong_answers", "rolled_back",
-                "rollback_reason", "quarantined",
-                "quarantine_survived_restart",
-                "reproposed_after_quarantine", "rollback_dossiers")})
-        if not ab.get("within_noise"):
-            bad.append({"round": ab["round"],
-                        "off_p50_s": ab.get("off_p50_s"),
-                        "on_p50_s": ab.get("on_p50_s")})
-        report = {
-            "rows": args.rows, "seed": args.seed,
-            "ok": not bad, "bad": bad, "rounds": rounds,
-        }
-        with open(args.json_out, "w") as f:
-            json.dump(report, f, indent=1)
-        print(f"\nautopilot soak {'OK' if report['ok'] else 'FAILED'} "
-              f"-> {args.json_out}")
-        if bad:
-            print(f"bad: {bad}")
-        return 0 if report["ok"] else 1
 
     if args.elastic:
         try:
