@@ -44,6 +44,10 @@ from blaze_tpu.ops.sort import truncate
 from blaze_tpu.ops.sort_keys import SortSpec, sort_batch
 from blaze_tpu.runtime import compile_service, jit_cache
 
+# agg_collapse cache key -> (scan, scatter): how many per-group reductions
+# the program was traced with in each form (ops/segment.count_forms)
+_SEG_FORMS: dict = {}
+
 AGG_BUF_PREFIX = "#9223372036854775807"  # ref agg/mod.rs:38
 
 
@@ -427,18 +431,23 @@ class AggExec(Operator):
                     gcols = [sb.columns[i].take(
                         jnp.clip(layout.start_idx, 0, sb.capacity - 1))
                         for i in range(ngroups)]
-                if raw_input:
-                    with jax.named_scope("collapse.accumulate_raw"):
-                        scols = self._accumulate_raw(sb, layout, ngroups)
-                else:
-                    with jax.named_scope("collapse.merge_state"):
-                        scols = self._merge_state(sb, layout, ngroups)
+                with seg.count_forms() as forms:
+                    if raw_input:
+                        with jax.named_scope("collapse.accumulate_raw"):
+                            scols = self._accumulate_raw(sb, layout, ngroups)
+                    else:
+                        with jax.named_scope("collapse.merge_state"):
+                            scols = self._merge_state(sb, layout, ngroups)
+                # traced once a program; _collapse adds it at every dispatch
+                _SEG_FORMS[key] = (forms["scan"], forms["scatter"])
                 return ColumnBatch(self._state_schema, gcols + scols,
                                    layout.num_groups, sb.capacity)
 
             return run
 
-        return jit_cache.get_or_compile(key, make)(big)
+        out = jit_cache.get_or_compile(key, make)(big)
+        compile_service.note_seg_reductions(*_SEG_FORMS.get(key, (0, 0)))
+        return out
 
     def _is_state_input(self) -> bool:
         return self.mode in (AggMode.PARTIAL_MERGE, AggMode.FINAL)
@@ -448,24 +457,31 @@ class AggExec(Operator):
         """Partial: raw input columns -> state columns via segmented ops."""
         out: List[Column] = []
         ci = ngroups
+        counted: dict = {}
         for call in self.aggs:
             ins = sb.columns[ci:ci + len(call.inputs)]
             ci += len(call.inputs)
             with jax.named_scope(call.fn):
-                out.extend(self._acc_one(call, ins, layout))
+                out.extend(self._acc_one(call, ins, layout, counted))
         return out
 
-    def _acc_one(self, call: AggCall, ins: List[Column], layout
-                 ) -> List[Column]:
+    def _acc_one(self, call: AggCall, ins: List[Column], layout,
+                 counted: dict) -> List[Column]:
+        def count(valid):
+            # sum's non-empty flag, count(x) and avg's count over one input
+            # expression count one mask: once per _accumulate_raw
+            over = tuple(e.key() for e in call.inputs)
+            if over not in counted:
+                counted[over] = seg.seg_count(valid, layout)
+            return counted[over]
+
         fn = call.fn
         if fn == "count":
             valid = None
             for c in ins:
                 v = c.valid_mask()
                 valid = v if valid is None else (valid & v)
-            cnt = seg.seg_sum(valid.astype(jnp.int64), layout,
-                              jnp.ones_like(valid))
-            return [Column(T.INT64, cnt, None)]
+            return [Column(T.INT64, count(valid), None)]
         (x,) = ins
         valid = x.valid_mask()
         if fn == "sum":
@@ -485,20 +501,17 @@ class AggExec(Operator):
                                                  sd.scale - x.dtype.scale)
                 sh, sl, ok = W.seg_sum_wide(h, l, live, layout, seg)
                 ok = ok & ~_seg_any(live & ~rok, layout)
-                nonempty = seg.seg_sum(valid.astype(jnp.int64), layout,
-                                       jnp.ones_like(valid)) > 0
+                nonempty = count(valid) > 0
                 return [W.build(sd, sh, sl, ok),
                         Column(T.BOOLEAN, nonempty, None)]
             data = x.data.astype(sd.jnp_dtype())
             s = seg.seg_sum(jnp.where(valid, data, 0), layout, valid)
-            nonempty = seg.seg_sum(valid.astype(jnp.int64), layout,
-                                   jnp.ones_like(valid)) > 0
+            nonempty = count(valid) > 0
             return [Column(sd, s, None), Column(T.BOOLEAN, nonempty, None)]
         if fn == "avg":
             sd = (call.dtype if call.dtype.kind == TypeKind.DECIMAL
                   else T.FLOAT64)
-            cnt = seg.seg_sum(valid.astype(jnp.int64), layout,
-                              jnp.ones_like(valid))
+            cnt = count(valid)
             if sd.wide_decimal:
                 from blaze_tpu.columnar import int128 as i128
                 from blaze_tpu.exprs import wide_decimal as W
@@ -570,9 +583,7 @@ class AggExec(Operator):
         if dedup:
             gid_key = jnp.where(valid, layout.gid, jnp.int32(2 ** 30))
             keep = keep & _first_occurrence(x, gid_key)
-        lens = seg.seg_sum(keep.astype(jnp.int32), layout,
-                           jnp.ones_like(keep))
-        lens = jnp.where(layout.group_mask, lens, 0)
+        lens = seg.seg_sum(keep, layout, keep)
         goff = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                 jnp.cumsum(lens, dtype=jnp.int32)])
         # kept rows to the front, original (group-sorted) order preserved
@@ -608,7 +619,6 @@ class AggExec(Operator):
                 keep.astype(jnp.int32), mode="drop")
         else:
             glens = seg.seg_sum(lens_r, layout, jnp.ones((cap,), jnp.bool_))
-            glens = jnp.where(layout.group_mask, glens, 0)
         goff = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                 jnp.cumsum(glens, dtype=jnp.int32)])
         return [Column(dt, ListData(goff, elems), None)]

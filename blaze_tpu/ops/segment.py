@@ -3,8 +3,10 @@
 The TPU-native replacement for the reference's open-addressing agg hash
 tables (agg_tables.rs): rows are first sorted by their grouping key, after
 which every grouped computation is a *segmented scan* — boundary detection by
-neighbor equality, group ids by cumsum, reductions by prefix-scan + boundary
-gather. No scatters, no data-dependent shapes.
+neighbor equality, group ids by cumsum, sums and counts by a scan that restarts
+at each run's first row + one gather at its last (`seg_sum`). No data-dependent
+shapes; the scatters that remain are `nonzero_i32` in `group_layout` and the
+min / max / first reductions (`jax.ops.segment_min` / `segment_max`).
 
 Used by agg (group-by), window (partition boundaries) and SMJ (run-length
 matching).
@@ -12,7 +14,9 @@ matching).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from typing import Callable, Sequence, Tuple
 
 import jax
@@ -138,39 +142,151 @@ def element_rows(offsets: Array, cap: int, ecap: int):
 
 # ---- per-group reductions (results compacted to slots [0, num_groups)) ----
 #
-# All reductions are SCATTER-based (jax.ops.segment_*), not prefix-scan
-# based: on TPU, XLA compiles f64/i64 cumsum and associative_scan through
-# the extended-precision emulation path and compile time explodes (observed
-# before this round: ~200s per f64 scan at 2^21 rows vs ~3s for the scatter
-# form). Scatter segment ops compile in seconds and run comparably.
+# Sums and counts take a layout from `group_layout` over a key-sorted batch,
+# so a group is a run of rows: its total is a running sum that restarts at
+# `layout.starts` (`_restarting_sum`), read at the run's last row and brought
+# to slot g by a gather at `layout.end_idx` (`_at_run_ends`). One form for
+# every dtype, a mask counted as int32 (a batch has at most 2^21 slots), a
+# group's own terms only: never a difference of global prefixes, which
+# subtracts prefixes of ~10^10 to get sums of ~10^6 and lets one inf or NaN
+# reach every later group. Integers wrap as a scatter-add's do.
+#
+# Chip readings (v5e, 2^21 slots, 18,000 groups; PERF.md section 6, PR 31):
+# `jax.ops.segment_sum` 144-154 ms whatever the dtype; the blocked scan
+# 1.3 ms and ~1 s of compile; `associative_scan` on (flag, f64) 61 ms and
+# 256 s of compile; int32 `cumsum` + `cummax` as fast as the blocked scan
+# and 35-44 s of compile; a gather of every slot 16 ms (int32) to 34 ms
+# (f64), of the first sixteenth 3-4 ms. min, max and first stay
+# scatter-based (`jax.ops.segment_min` / `segment_max`): no cell runs them.
+
+_BLOCK = 256   # rows one lane of `_restarting_sum` walks (at 128: 22 ms, not 1.3)
+_FEW = 16      # `_at_run_ends`: groups in the first 1/_FEW of the slots
+_PLAIN = 1 << 16   # `_at_run_ends`: no conditional up to this many slots
+
+_FORMS = threading.local()
+
+
+@contextlib.contextmanager
+def count_forms():
+    """Tally the per-group reductions traced inside the scope by the form
+    they were built in: yields {"scan": n, "scatter": n}. A trace-time
+    count of this thread; `ops/agg` keeps it per program and adds it to
+    `compile_service.TELEMETRY` at every dispatch."""
+    outer = getattr(_FORMS, "tally", None)
+    tally = _FORMS.tally = {"scan": 0, "scatter": 0}
+    try:
+        yield tally
+    finally:
+        _FORMS.tally = outer
+
+
+def _note_form(form: str) -> None:
+    tally = getattr(_FORMS, "tally", None)
+    if tally is not None:
+        tally[form] += 1
 
 
 def _seg_ids(layout: GroupLayout, extra_mask: Array = None) -> Array:
     """Per-row segment id for scatter ops: gid for contributing rows, an
-    out-of-range id (dropped by num_segments) for padding/masked rows."""
+    out-of-range id (dropped by num_segments) for padding/masked rows.
+    Every scatter-based reduction takes its ids here once, so this is
+    where `count_forms` hears of one."""
+    _note_form("scatter")
     mask = layout.row_mask if extra_mask is None else (
         layout.row_mask & extra_mask)
     cap = layout.gid.shape[0]
     return jnp.where(mask, layout.gid, jnp.int32(cap))
 
 
+def _restarting_sum(v: Array, starts: Array) -> Array:
+    """Inclusive running sum of `v` that restarts where `starts` is True.
+
+    Two-level and blocked: the rows are cut into lanes of `_BLOCK`
+    consecutive rows and one `lax.scan` of `_BLOCK` steps walks all lanes at
+    once, carrying each lane's running sum and whether it has met a start
+    (elementwise work only, so f64 and int64 compile in seconds where an
+    `associative_scan` of 2^21 emulated 64-bit rows does not); the same scan
+    over the lane totals gives what enters each lane; rows before a lane's
+    first start take that in. Only a group's own terms are ever added."""
+    n = v.shape[0]
+    zero = jnp.zeros((), v.dtype)
+
+    def step(carry, x):
+        acc, seen = carry
+        vj, fj = x
+        acc = jnp.where(fj, vj, acc + vj)
+        seen = seen | fj
+        return (acc, seen), (acc, seen)
+
+    if n <= _BLOCK:
+        _, (out, _) = lax.scan(step, (zero, jnp.zeros((), jnp.bool_)),
+                               (v, starts))
+        return out
+    pad = -n % _BLOCK
+    if pad:
+        v = jnp.concatenate([v, jnp.zeros((pad,), v.dtype)])
+        starts = jnp.concatenate([starts, jnp.zeros((pad,), jnp.bool_)])
+    lanes = (n + pad) // _BLOCK
+    (totals, any_start), (local, seen) = lax.scan(
+        step, (jnp.zeros((lanes,), v.dtype), jnp.zeros((lanes,), jnp.bool_)),
+        (v.reshape(lanes, _BLOCK).T, starts.reshape(lanes, _BLOCK).T))
+    entering = jnp.concatenate(
+        [jnp.zeros((1,), v.dtype), _restarting_sum(totals, any_start)[:-1]])
+    out = local + jnp.where(seen, zero, entering[None, :])
+    return out.T.reshape(n + pad)[:n]
+
+
+def _at_run_ends(x: Array, layout: GroupLayout) -> Array:
+    """`x` at each run's last row in slot g, exactly 0 past `num_groups`.
+
+    A gather by computed index costs by the slots it fills, live or not
+    (8-16 ns each on a v5e), so where the groups fit the first 1/`_FEW` of
+    the slots, which is seen at run time, only those are gathered. The
+    other branch fills every slot and pays ~60 % over a plain gather for
+    sitting in a conditional (PERF.md section 6, PR 31). Up to `_PLAIN`
+    slots every one is gathered outright: that is under a millisecond,
+    less than the conditional is worth (it doubles the reduction's compile
+    on the CPU backend, where the tests' watchdogs count compile as
+    silence)."""
+    cap = x.shape[0]
+    few = cap // _FEW
+    zero = jnp.zeros((), x.dtype)
+
+    def every_slot(_):
+        return jnp.where(layout.group_mask, x[layout.end_idx], zero)
+
+    def first_slots(_):
+        head = jnp.where(layout.group_mask[:few], x[layout.end_idx[:few]],
+                         zero)
+        return jnp.concatenate([head, jnp.zeros((cap - few,), x.dtype)])
+
+    if cap <= _PLAIN:
+        return every_slot(None)
+    return lax.cond(layout.num_groups <= few, first_slots, every_slot, None)
+
+
 def seg_sum(values: Array, layout: GroupLayout, valid: Array) -> Array:
-    cap = values.shape[0]
-    v = jnp.where(valid & layout.row_mask, values,
-                  jnp.zeros((), values.dtype))
-    return jax.ops.segment_sum(v, _seg_ids(layout, valid), num_segments=cap)
+    """Per-group sum of `values` over rows that are live and `valid`, in
+    slot g for group g and exactly 0 in every slot past `num_groups`. bool
+    values are counted (int32); other dtypes sum in their own dtype,
+    integers wrapping."""
+    _note_form("scan")
+    live = valid & layout.row_mask
+    if values.dtype == jnp.bool_:
+        v = (values & live).astype(jnp.int32)
+    else:
+        v = jnp.where(live, values, jnp.zeros((), values.dtype))
+    return _at_run_ends(_restarting_sum(v, layout.starts), layout)
 
 
 def seg_count(valid: Array, layout: GroupLayout) -> Array:
-    return seg_sum(valid.astype(jnp.int64), layout,
-                   jnp.ones_like(valid))
+    """Per-group count of the live rows where `valid` holds (int64)."""
+    return seg_sum(valid, layout, valid).astype(jnp.int64)
 
 
 def seg_any(flags: Array, layout: GroupLayout) -> Array:
     """Per-group OR (compacted to group slots)."""
-    n = seg_sum((flags & layout.row_mask).astype(jnp.int32), layout,
-                jnp.ones_like(flags, jnp.bool_))
-    return n > 0
+    return seg_sum(flags, layout, flags) > 0
 
 
 def seg_min(values, layout, valid):

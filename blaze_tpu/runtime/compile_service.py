@@ -35,10 +35,10 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
 * **Telemetry** — a process-global `MetricsSet` with
   compile_count / compile_ns / cache_hits / cache_misses /
   canonicalization_waste_rows / stage_attempts / stage_compiled /
-  agg_pallas_traces / agg_xla_traces and the derived
-  whole_stage_coverage_pct, exported as an extra `MetricNode`
-  child by `executor.metric_tree` and as a summary line by
-  `tracing.metric_report`.
+  agg_pallas_traces / agg_xla_traces / seg_scan_reductions /
+  seg_scatter_reductions and the derived whole_stage_coverage_pct,
+  exported as an extra `MetricNode` child by `executor.metric_tree` and
+  as a summary line by `tracing.metric_report`.
 """
 
 from __future__ import annotations
@@ -67,6 +67,7 @@ _COUNTERS = (
     "compile_count", "compile_ns", "cache_hits", "cache_misses",
     "canonicalization_waste_rows", "stage_attempts", "stage_compiled",
     "agg_pallas_traces", "agg_xla_traces",
+    "seg_scan_reductions", "seg_scatter_reductions",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -107,6 +108,14 @@ def note_agg_trace(pallas: bool) -> None:
     """ops/mxu_agg traced one grouped-accumulate program: the Pallas
     kernel, or the portable XLA formulation."""
     TELEMETRY.add("agg_pallas_traces" if pallas else "agg_xla_traces", 1)
+
+
+def note_seg_reductions(scan: int, scatter: int) -> None:
+    """ops/agg dispatched one agg_collapse program whose per-group
+    reductions (ops/segment) were built as `scan` scans and `scatter`
+    scatters."""
+    TELEMETRY.add("seg_scan_reductions", scan)
+    TELEMETRY.add("seg_scatter_reductions", scatter)
 
 
 def telemetry_summary() -> str:

@@ -243,3 +243,90 @@ def test_final_agg_single_external_state_batch_merges():
             5: float(sums[items == 5].sum())}
     assert n == 3
     assert got == want
+
+
+def test_q06core_partial_collapse_reads_scans_not_scatters(rng, monkeypatch):
+    """q06-core's partial aggregate (sum f64, count, avg) over a 2^14-slot
+    batch: no scatter under collapse.accumulate_raw in the lowered program,
+    and TELEMETRY's seg_* counters move by the program's tally at every
+    dispatch (the second dispatch is a cache hit and traces nothing)."""
+    import re
+
+    import jax
+
+    from blaze_tpu.config import conf
+    from blaze_tpu.ops import agg as agg_mod
+    from blaze_tpu.runtime import compile_service, jit_cache
+
+    schema = T.Schema([T.Field("ss_item_sk", T.INT64),
+                       T.Field("ss_sales_price", T.FLOAT64),
+                       T.Field("ss_ext_sales_price", T.FLOAT64)])
+    n = 1 << 14
+    data = {"ss_item_sk": rng.integers(1, 300, n).astype(np.int64),
+            "ss_sales_price": np.round(rng.uniform(0, 200, n), 2),
+            "ss_ext_sales_price": np.round(rng.uniform(0, 2000, n), 2)}
+    validity = {c: rng.random(n) > 0.045
+                for c in ("ss_sales_price", "ss_ext_sales_price")}
+    calls = [AggCall("sum", (ir.col("ss_ext_sales_price"),), T.FLOAT64,
+                     "total"),
+             AggCall("count", (ir.col("ss_ext_sales_price"),), T.INT64,
+                     "cnt"),
+             AggCall("avg", (ir.col("ss_sales_price"),), T.FLOAT64,
+                     "avg_price")]
+
+    seen = {}
+    real = jit_cache.get_or_compile
+
+    def spy(key, make_fn, **kw):
+        fn = real(key, make_fn, **kw)
+        if key[0] != "agg_collapse":
+            return fn
+
+        def call(batch):
+            seen.setdefault("programs", []).append((make_fn, batch))
+            return fn(batch)
+        return call
+
+    monkeypatch.setattr(agg_mod.jit_cache, "get_or_compile", spy)
+
+    def run_once():
+        b = ColumnBatch.from_numpy(data, schema, validity=validity)
+        assert b.capacity == n
+        p = AggExec(MemorySourceExec([b], schema), [ir.col("ss_item_sk")],
+                    ["item"], calls, AggMode.PARTIAL)
+        before = compile_service.TELEMETRY.snapshot()
+        out = collect(p)
+        after = compile_service.TELEMETRY.snapshot()
+        return out, {k: after[k] - before[k] for k in
+                     ("seg_scan_reductions", "seg_scatter_reductions",
+                      "compile_count")}
+
+    conf.enable_stage_compiler = False
+    try:
+        out, first = run_once()
+        _, second = run_once()
+    finally:
+        conf.enable_stage_compiler = True
+
+    # sum + avg's sum, and two counts: sum's non-empty flag and count(x)
+    # are over one column and are counted once
+    want = {"seg_scan_reductions": 4, "seg_scatter_reductions": 0}
+    assert {k: first[k] for k in want} == want
+    assert second == {**want, "compile_count": 0}
+
+    (make_fn, batch), = seen["programs"][:1]
+    text = jax.jit(make_fn()).lower(batch).as_text(debug_info=True)
+    ops = re.findall(r'loc\("(jit\([^"]*)"', text)
+    acc = [op for op in ops if "/collapse.accumulate_raw/" in op]
+    assert acc and not [op for op in acc if "scatter" in op]
+    # the text does name a scatter where one is: group_layout's nonzero_i32
+    assert [op for op in ops
+            if "/collapse.group_layout/" in op and "scatter" in op]
+
+    d = out.to_numpy()
+    ext = np.where(validity["ss_ext_sales_price"],
+                   data["ss_ext_sales_price"], 0.0)
+    want_total = pd.Series(ext).groupby(data["ss_item_sk"]).sum()
+    got = dict(zip(np.asarray(d["item"]).tolist(), list(d.values())[1]))
+    for k, w in want_total.items():
+        np.testing.assert_allclose(got[int(k)], w, rtol=1e-12)

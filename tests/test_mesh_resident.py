@@ -344,9 +344,11 @@ def test_manifest_lists_the_cell_its_config_and_its_metrics():
     assert config["name"] == on_file["name"] == "tpcds_sf1_nobhj_x4"
     assert config["source"] == on_file["source"]
     assert sorted(config["reduced"]) == sorted(on_file["reduced"])
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == [
-        "mesh_exchange_s", "mesh_host_roundtrip_MB", "chip_busy_balance"]
-    for m in manifest["per_layer"][-3:]:
+    # the cell's own three metrics, by name: later PRs append to the list
+    own = [m for m in manifest["per_layer"] if m["name"] in (
+        "mesh_exchange_s", "mesh_host_roundtrip_MB", "chip_busy_balance")]
+    assert len(own) == 3
+    for m in own:
         assert m["workloads"] == ["sf1_q03_nobhj_x4"]
         assert m["moves"] == "query_s.p50"
         assert os.path.exists(

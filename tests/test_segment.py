@@ -3,9 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from blaze_tpu.columnar import types as T
-from blaze_tpu.columnar.batch import ColumnBatch
+from blaze_tpu.columnar.batch import Column, ColumnBatch
 from blaze_tpu.ops import segment as seg
 from blaze_tpu.ops.sort_keys import SortSpec, sort_batch
 
@@ -126,3 +127,104 @@ def test_seg_minmax_nan_inf_semantics(rng):
     # group 3: {-inf, 2.0} -> -inf / 2.0
     assert mins[3] == -np.inf and maxs[3] == 2.0
     assert all(np.asarray(mok)[:4]) and all(np.asarray(xok)[:4])
+
+
+# ---- seg_sum / seg_count / seg_any: the scan forms against numpy ----
+
+# holds 18,000 keys and is past seg._PLAIN, so `_at_run_ends` takes its
+# conditional: narrow for one group and 18 keys, every slot for the rest
+CAP = 1 << 17
+
+
+def _keys(kind, rng):
+    if kind == "one":
+        return np.zeros(CAP, np.int64)
+    if kind == "each":
+        return np.arange(CAP, dtype=np.int64)
+    return np.sort(rng.integers(0, int(kind), CAP)).astype(np.int64)
+
+
+def _values(dtype, rng):
+    if dtype == "f64":
+        return np.round(rng.uniform(0.01, 200.0, CAP), 2)
+    if dtype == "i64":
+        return rng.integers(-10**12, 10**12, CAP).astype(np.int64)
+    return rng.random(CAP) < 0.5
+
+
+@jax.jit
+def _reduce_all(keys, values, valid, num_rows):
+    b = ColumnBatch(T.Schema([T.Field("k", T.INT64)]),
+                    [Column(T.INT64, keys, None)], num_rows, keys.shape[0])
+    layout = seg.group_layout(b, [0])
+    return (layout.num_groups, seg.seg_sum(values, layout, valid),
+            seg.seg_count(valid, layout), seg.seg_any(valid, layout))
+
+
+def _reference(keys, values, valid, n):
+    """Per-group totals of the first n rows, in slot order of the sorted
+    keys: (num_groups, sums as longdouble or int64, counts, anys)."""
+    live = valid[:n]
+    _, gid = np.unique(keys[:n], return_inverse=True)
+    groups = int(gid.max()) + 1 if n else 0
+    if values.dtype == np.bool_:
+        terms, sums = (values[:n] & live).astype(np.int32), np.zeros(
+            CAP, np.int32)
+    elif values.dtype == np.int64:
+        terms, sums = np.where(live, values[:n], 0), np.zeros(CAP, np.int64)
+    else:
+        terms, sums = (np.where(live, values[:n], 0).astype(np.longdouble),
+                       np.zeros(CAP, np.longdouble))
+    counts = np.zeros(CAP, np.int64)
+    np.add.at(sums, gid, terms)
+    np.add.at(counts, gid, live.astype(np.int64))
+    return groups, sums, counts, counts > 0
+
+
+@pytest.mark.parametrize("dtype", ["f64", "i64", "bool"])
+@pytest.mark.parametrize("num_rows", [0, 1, CAP, CAP - 3])
+@pytest.mark.parametrize("keys", ["one", "each", "18", "18000"])
+@pytest.mark.parametrize("null_share", [0.0, 0.045, 1.0])
+def test_seg_scan_forms_vs_numpy(rng, null_share, keys, num_rows, dtype):
+    k, v = _keys(keys, rng), _values(dtype, rng)
+    valid = rng.random(CAP) >= null_share
+    groups, sums, counts, anys = _reduce_all(k, v, valid,
+                                             np.int32(num_rows))
+    g, rsums, rcounts, ranys = _reference(k, v, valid, num_rows)
+    sums, counts, anys = map(np.asarray, (sums, counts, anys))
+    assert int(groups) == g
+    assert counts.dtype == np.int64 and anys.dtype == np.bool_
+    np.testing.assert_array_equal(counts, rcounts)  # zeros past g too
+    np.testing.assert_array_equal(anys, ranys)
+    if dtype == "f64":
+        np.testing.assert_allclose(sums, rsums.astype(np.float64),
+                                   rtol=1e-12, atol=0)
+    else:
+        assert sums.dtype == rsums.dtype
+        np.testing.assert_array_equal(sums, rsums)
+    assert not sums[g:].any()  # exact zeros in every slot past num_groups
+
+
+def test_seg_sum_inf_and_nan_stay_in_their_group():
+    keys = np.repeat(np.arange(8, dtype=np.int64), CAP // 8)
+    v = np.ones(CAP)
+    at = CAP // 8 * 2  # group 2's first row
+    v[at + 5], v[at + 700] = np.inf, np.nan
+    v[CAP // 8 * 5 + 1] = np.inf
+    _, sums, _, _ = _reduce_all(keys, v, np.ones(CAP, bool), np.int32(CAP))
+    sums = np.asarray(sums)
+    assert np.isnan(sums[2]) and sums[5] == np.inf
+    np.testing.assert_array_equal(sums[[0, 1, 3, 4, 6, 7]], CAP // 8)
+    assert not sums[8:].any()
+
+
+def test_seg_sum_int64_wraps_like_a_scatter_add():
+    keys = np.repeat(np.arange(4, dtype=np.int64), CAP // 4)
+    v = np.ones(CAP, np.int64)
+    v[CAP // 4: CAP // 4 + 3] = np.iinfo(np.int64).max  # group 1 wraps
+    _, sums, _, _ = _reduce_all(keys, v, np.ones(CAP, bool), np.int32(CAP))
+    want = np.zeros(CAP, np.int64)
+    with np.errstate(over="ignore"):
+        np.add.at(want, keys, v)
+    assert want[1] < 0  # the reference wrapped
+    np.testing.assert_array_equal(np.asarray(sums), want)

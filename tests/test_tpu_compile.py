@@ -1,0 +1,56 @@
+"""The per-group reductions of ops/segment.py compiled at real capacities
+for a described TPU v5e (the chip's compiler, no chip attached): what the
+chip would run holds no scatter, and the conditional of `_at_run_ends` is
+there only past `seg._PLAIN` slots. A compile that passes is not a chip
+run: times are in PERF.md, from the chip.
+
+The topology is described inside a fixture, never at import: one process at
+a time may load the TPU's library, and under xdist only the worker that is
+given this file should."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from blaze_tpu.ops import segment as seg
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to hold
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _q06core_reductions(starts, gid, num_groups, start_idx, end_idx,
+                        row_mask, group_mask, ext, ext_valid, price,
+                        price_valid):
+    layout = seg.GroupLayout(starts, gid, num_groups, start_idx, end_idx,
+                             row_mask, group_mask)
+    return (seg.seg_sum(ext, layout, ext_valid),
+            seg.seg_count(ext_valid, layout),
+            seg.seg_sum(price, layout, price_valid),
+            seg.seg_count(price_valid, layout))
+
+
+@pytest.mark.parametrize("cap", [seg._PLAIN, 1 << 21])
+def test_seg_reductions_compile_for_v5e_without_scatter(one_chip, cap):
+    def arg(dtype, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, i32, f64 = jnp.bool_, jnp.int32, jnp.float64
+    text = jax.jit(_q06core_reductions).lower(
+        arg(b), arg(i32), arg(i32, ()), arg(i32), arg(i32), arg(b), arg(b),
+        arg(f64), arg(b), arg(f64), arg(b)).compile().as_text()
+    assert " scatter(" not in text
+    assert " while(" in text  # the blocked scans
+    assert text.count(" conditional(") == (4 if cap > seg._PLAIN else 0)
