@@ -61,6 +61,40 @@ def nonzero_i32(mask: Array, size: int, fill_value: int = 0) -> Array:
         jnp.arange(mask.shape[0], dtype=jnp.int32), mode="drop")
 
 
+def rows_to_ranks(x: Array, dest: Array) -> Array:
+    """1-D plane `x` with row i in slot `dest[i]`: rows aimed past the end
+    drop, slots no row reaches are 0. What a filter's compaction does to a
+    plane once `dest` holds each kept row's rank.
+
+    Written as a scatter of 32-bit words: on a v5e a scatter of one 32-bit
+    operand at rising positions costs 5 ns a row whatever the buffers'
+    addresses, where the gather of the same plane by computed index costs
+    22 ns a row a word and, for the word that lands in fast memory, 20-27
+    depending on where the allocator put the source (43-57 ms at 2^21: a
+    level a process keeps; PERF.md section 6, PR 32). A 64-bit scatter is
+    one program of two operands at 70 ns a row, so an int64 goes as its
+    two halves (mask and shift, `bits64.i64_halves`: no 64-bit bitcast)."""
+    if x.dtype.itemsize == 8:
+        from blaze_tpu.columnar.bits64 import i64_halves
+
+        hi, lo = i64_halves(x.astype(jnp.int64))
+        hi, lo = rows_to_ranks(hi, dest), rows_to_ranks(lo, dest)
+        whole = (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+        return whole.astype(x.dtype)
+    return jnp.zeros(x.shape, x.dtype).at[dest].set(x, mode="drop")
+
+
+def _ranks_well(x) -> bool:
+    """Planes `rows_to_ranks` takes: 1-D flags and 32- or 64-bit integers
+    (a date, a timestamp, a compact decimal's unscaled value among them).
+    A double has no 64-bit bitcast on the chip (bits64.py), narrower
+    integers and floats have not been measured: those are gathered."""
+    if isinstance(x, (StringData, DictData, ListData, StructData)):
+        return False
+    return x.ndim == 1 and (x.dtype == jnp.bool_ or (
+        jnp.issubdtype(x.dtype, jnp.integer) and x.dtype.itemsize >= 4))
+
+
 def bucket_width(w: int) -> int:
     """Round string byte-width up to a power-of-two bucket (min 4).
 
@@ -384,12 +418,31 @@ class ColumnBatch:
     def compact(self, keep: Array) -> "ColumnBatch":
         """Filter: keep rows where `keep & row_mask`, compacted to the front.
 
-        Static-shape: uses size-bounded nonzero + gather; output capacity equals
-        input capacity (a later coalesce can re-bucket downward).
+        Static-shape: output capacity equals input capacity (a later
+        coalesce can re-bucket downward). Flag and integer planes are
+        scattered to their rows' ranks (`rows_to_ranks`), every other kind
+        of column is gathered at the kept rows' positions; slots past the
+        kept rows hold zeros and nulls in the first case, row 0 in the
+        second, and are nobody's to read.
         """
         mask = keep & self.row_mask()
         n = jnp.sum(mask, dtype=jnp.int32)
-        return self.take(nonzero_i32(mask, self.capacity), n)
+        # a kept row's rank among the kept; a dropped row aims past the end
+        dest = jnp.where(mask, jnp.cumsum(mask, dtype=jnp.int32) - 1,
+                         self.capacity)
+        idx, cols = None, []
+        for c in self.columns:
+            if _ranks_well(c.data):
+                v = c.validity
+                cols.append(Column(c.dtype, rows_to_ranks(c.data, dest),
+                                   None if v is None
+                                   else rows_to_ranks(v, dest)))
+                continue
+            if idx is None:   # nonzero_i32(mask, capacity), off `dest`
+                idx = rows_to_ranks(
+                    jnp.arange(self.capacity, dtype=jnp.int32), dest)
+            cols.append(c.take(idx))
+        return ColumnBatch(self.schema, cols, n, self.capacity)
 
     def normalized(self) -> "ColumnBatch":
         return self.with_columns(self.schema, [c.normalized() for c in self.columns])
@@ -398,7 +451,11 @@ class ColumnBatch:
     def to_numpy(self) -> Dict[str, object]:
         """Pull live rows to host. Strings -> list[bytes|None]; lists ->
         list[list|None]; numerics -> numpy masked to live rows with None
-        for nulls (object arrays)."""
+        for nulls (object arrays). A decimal comes back as its UNSCALED
+        integer (a compact one, p <= 18, as int64; a wide one as Python
+        ints): the scale is the schema's, not the array's, so 12.34 typed
+        decimal(7,2) reads 1234 and a caller that drops the schema cannot
+        tell cents from millionths."""
         # the ordered-collect path (local_runner) materializes on host
         # and caches the pylike dict so the driver does not pull the
         # same rows through the (slow) device->host link twice

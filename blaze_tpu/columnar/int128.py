@@ -131,14 +131,13 @@ def mul_small(h: Array, l: Array, m: int) -> Tuple[Array, Array]:
     return jnp.where(sign, nh, hi), jnp.where(sign, nl, ml)
 
 
-def divmod_small(h: Array, l: Array, d) -> Tuple[Array, Array, Array]:
-    """magnitude divmod by a small positive divisor (< 2^31):
+def divmod_small(h: Array, l: Array, d: int) -> Tuple[Array, Array, Array]:
+    """magnitude divmod by a small positive python int (< 2^31):
     (qh, ql, rem) on the MAGNITUDE; caller handles sign/rounding.
-    Long division over four 32-bit limbs. `d` may be a python int or an
-    int64 Array of per-row divisors — the < 2^31 bound is the CALLER's
-    contract for arrays (values beyond it overflow the per-limb step)."""
-    if not isinstance(d, jax.Array):
-        assert 0 < d < (1 << 31)
+    Long division over four 32-bit limbs. A per-row divisor goes to
+    divmod_full: on the TPU's compiler each emulated 64-bit division by
+    an array costs ~17 s of compile, and this would be eight of them."""
+    assert 0 < d < (1 << 31)
     dd = jnp.asarray(d, jnp.int64)
     ah, al = abs_(h, l)
     limbs = [(ah >> 32) & _MASK32, ah & _MASK32,

@@ -12,11 +12,15 @@ We keep the same logical types but record how each lands on device:
   date32                int32 (cap,)   days since epoch
   timestamp[us]         int64 (cap,)   micros since epoch
   decimal(p<=18, s)     int64 (cap,)   unscaled value (Spark compact repr)
+  decimal(p>18, s)      2 x int64 (cap,) hi/lo limb planes of the unscaled value
   string / binary       uint8 (cap, W) fixed-width bytes + int32 lengths
   null                  int8 zeros (all-invalid validity)
 
-Decimals with p>18 (Spark uses int128) are not yet device-native; the planner
-must fall back for those (tracked as TypeKind.DECIMAL with wide=True).
+Decimals with p>18 (Spark uses int128) are device-native too: two int64 limb
+planes, struct<hi, lo> (wide_decimal_storage below, columnar/int128.py), with
+the expression and aggregation kernels of exprs/wide_decimal.py; the convert
+strategy falls back only for the wide usages those kernels do not cover
+(spark/converters._wide_usage_ok). `DataType.wide_decimal` tells the two apart.
 """
 
 from __future__ import annotations

@@ -145,7 +145,19 @@ def _compile_expr(expr: ir.Expr, schema) -> CompiledExpr:
     if isinstance(expr, ir.MakeDecimal):
         c = compile_expr(expr.child, schema)
         dt = DataType(TypeKind.DECIMAL, precision=expr.precision, scale=expr.scale)
-        return lambda b: Column(dt, c(b).data.astype(jnp.int64), c(b).validity)
+        if expr.precision > 18:  # every long fits
+            return lambda b: Column(dt, c(b).data.astype(jnp.int64), c(b).validity)
+        bound = np.int64(10 ** expr.precision)
+
+        def run_make(b):
+            # Spark, ANSI off: a long past the precision makes a null
+            col = c(b)
+            v = col.data.astype(jnp.int64)
+            fits = (v > -bound) & (v < bound)
+            return Column(dt, jnp.where(fits, v, 0),
+                          _and_valid(col.validity, fits))
+
+        return run_make
     if isinstance(expr, ir.UnscaledValue):
         c = compile_expr(expr.child, schema)
         return lambda b: Column(INT64, c(b).data.astype(jnp.int64), c(b).validity)
@@ -393,6 +405,8 @@ def _compare(lc: Column, rc: Column, op: ir.BinOp) -> Column:
     elif lc.is_string or rc.is_string:
         lt, eq = S.compare(lc.data, rc.data)
         gt = ~lt & ~eq
+    elif lc.dtype.is_decimal or rc.dtype.is_decimal:
+        lt, eq, gt = _compare_decimal(lc, rc)
     else:
         ld, rd = _promote(lc, rc)
         lt, eq, gt = ld < rd, ld == rd, ld > rd
@@ -406,6 +420,50 @@ def _compare(lc: Column, rc: Column, op: ir.BinOp) -> Column:
         both_null = ~lv & ~rv
         return Column(BOOLEAN, both_null | (lv & rv & res), None)
     return Column(BOOLEAN, res, _strict(lc, rc))
+
+
+# decimal digits an integral type can hold (Spark's DecimalType.forType)
+_INT_DIGITS = {TypeKind.BOOLEAN: 1, TypeKind.INT8: 3, TypeKind.INT16: 5,
+               TypeKind.INT32: 10, TypeKind.INT64: 20}
+
+
+def _compare_decimal(lc: Column, rc: Column):
+    """(lt, eq, gt) of a compact decimal against a decimal of another scale,
+    an integer (scale 0) or a float. An unscaled value means nothing without
+    its scale: cents against units is wrong by 10^2. Both sides go to the
+    larger scale, in int64 while the aligned values provably fit 18 digits,
+    else on 128-bit limb planes; against a float both go to double, as
+    Spark casts them."""
+    lt_, rt_ = lc.dtype, rc.dtype
+    if lt_.is_floating or rt_.is_floating:
+        def as_double(c):
+            if c.dtype.is_decimal:
+                return c.data.astype(jnp.float64) / (10.0 ** c.dtype.scale)
+            return c.data.astype(jnp.float64)
+
+        ld, rd = as_double(lc), as_double(rc)
+        return ld < rd, ld == rd, ld > rd
+    scale = max(lt_.scale, rt_.scale)
+
+    def digits(t):
+        whole = (t.precision - t.scale if t.is_decimal
+                 else _INT_DIGITS.get(t.kind))
+        if whole is None:
+            raise TypeError(f"cannot compare a decimal with {t}")
+        return whole + scale
+
+    if max(digits(lt_), digits(rt_)) > 18:
+        from blaze_tpu.exprs import wide_decimal as W
+
+        return W.compare(lc, rc)
+
+    def aligned(c):
+        v = c.data.astype(jnp.int64)
+        up = scale - c.dtype.scale
+        return v * np.int64(10 ** up) if up else v
+
+    ld, rd = aligned(lc), aligned(rc)
+    return ld < rd, ld == rd, ld > rd
 
 
 def _strict(*cols: Column):

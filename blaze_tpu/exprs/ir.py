@@ -371,8 +371,31 @@ def contains_host_fn(expr: Expr) -> bool:
 # -- convenience builders --
 
 def lit(value: Any, dtype: Optional[DataType] = None) -> Literal:
+    """A literal; its type is inferred where none is given. A
+    `decimal.Decimal` becomes decimal(p, s) as Spark types a decimal
+    literal (the digits it is written with: Decimal("100.00") is
+    decimal(5,2), precision never under the scale), held as its unscaled
+    integer; a Decimal given WITH a decimal dtype is rescaled to it, and
+    must fit it exactly."""
+    import decimal
+
     from blaze_tpu.columnar import types as T
 
+    if isinstance(value, decimal.Decimal):
+        if not value.is_finite():
+            raise TypeError(f"cannot type the literal {value!r}")
+        _, digits, exponent = value.as_tuple()
+        if dtype is None:
+            scale = max(-exponent, 0)
+            dtype = T.decimal(max(len(digits) + max(exponent, 0), scale, 1),
+                              scale)
+        elif not dtype.is_decimal:
+            raise TypeError(f"a Decimal literal typed {dtype}")
+        unscaled = value.scaleb(dtype.scale)
+        if (unscaled != unscaled.to_integral_value()
+                or abs(int(unscaled)) >= 10 ** dtype.precision):
+            raise TypeError(f"{value!r} is no {dtype}")
+        return Literal(dtype, int(unscaled))
     if dtype is None:
         if isinstance(value, bool):
             dtype = T.BOOLEAN

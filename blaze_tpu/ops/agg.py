@@ -28,6 +28,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.batch import (
@@ -44,8 +45,10 @@ from blaze_tpu.ops.sort import truncate
 from blaze_tpu.ops.sort_keys import SortSpec, sort_batch
 from blaze_tpu.runtime import compile_service, jit_cache
 
-# agg_collapse cache key -> (scan, scatter): how many per-group reductions
-# the program was traced with in each form (ops/segment.count_forms)
+# agg_collapse cache key -> ops/segment.count_forms' tally of the program's
+# trace: how many per-group reductions it has in each form (scan, scatter),
+# how many of them add numbers (sums) and how many of those integers
+# (int_sums)
 _SEG_FORMS: dict = {}
 
 AGG_BUF_PREFIX = "#9223372036854775807"  # ref agg/mod.rs:38
@@ -79,6 +82,51 @@ def _sum_state_dtype(d: DataType) -> DataType:
     return T.INT64
 
 
+# Spark's result types over a decimal(p, s) input, precisions bounded at 38
+# (Sum.resultType, Average.resultType / sumDataType):
+#   sum -> decimal(p + 10, s)
+#   avg -> decimal(p + 4, s + 4) over a sum buffer decimal(p + 10, s)
+_SUM_DIGITS, _AVG_DIGITS, _AVG_SCALE = 10, 4, 4
+
+
+def _bounded(precision: int, scale: int) -> DataType:
+    return T.decimal(min(precision, 38), min(scale, 38))
+
+
+def avg_sum_dtype(result: DataType) -> DataType:
+    """The type of an avg's sum state, from the avg's result type alone (a
+    FINAL operator sees state columns, not the input): double unless the
+    result is decimal(p + 4, s + 4), whose buffer is decimal(p + 10, s)."""
+    if not result.is_decimal:
+        return T.FLOAT64
+    if result.scale < _AVG_SCALE:
+        raise TypeError(
+            f"avg planned as {result}: Spark types avg(decimal(p,s)) as "
+            f"decimal(p+{_AVG_DIGITS},s+{_AVG_SCALE}), so its scale is at "
+            f"least {_AVG_SCALE}")
+    return _bounded(result.precision - _AVG_DIGITS + _SUM_DIGITS,
+                    result.scale - _AVG_SCALE)
+
+
+def check_decimal_call(call: "AggCall", in_dtype: DataType) -> None:
+    """Plan time: a sum or avg that touches a decimal carries the result
+    type Spark plans for its input's type. Anything else is a plan Spark
+    never produces, and guessing a rescale for it would hide a mislabel
+    (a scale-2 sum under a scale-6 label is wrong by 10^4, silently)."""
+    if call.fn not in ("sum", "avg") or not (
+            in_dtype.is_decimal or call.dtype.is_decimal):
+        return
+    if not in_dtype.is_decimal:
+        raise TypeError(f"{call.fn}({in_dtype}) planned as {call.dtype}: a "
+                        "decimal result needs a decimal input")
+    p, sc = in_dtype.precision, in_dtype.scale
+    want = (_bounded(p + _SUM_DIGITS, sc) if call.fn == "sum"
+            else _bounded(p + _AVG_DIGITS, sc + _AVG_SCALE))
+    if call.dtype != want:
+        raise TypeError(f"{call.fn}({in_dtype}) planned as {call.dtype}; "
+                        f"Spark plans it as {want}")
+
+
 def collect_state_dtype(call: AggCall) -> DataType:
     """List dtype of a collect_list/collect_set state/result column."""
     return (call.dtype if call.dtype.kind == TypeKind.LIST
@@ -92,8 +140,8 @@ def state_fields(call: AggCall, i: int) -> List[Field]:
         sd = _sum_state_dtype(call.dtype)
         return [Field(f"{p}.sum", sd), Field(f"{p}.nonempty", T.BOOLEAN)]
     if call.fn == "avg":
-        sd = call.dtype if call.dtype.kind == TypeKind.DECIMAL else T.FLOAT64
-        return [Field(f"{p}.sum", sd), Field(f"{p}.count", T.INT64)]
+        return [Field(f"{p}.sum", avg_sum_dtype(call.dtype)),
+                Field(f"{p}.count", T.INT64)]
     if call.fn == "count":
         return [Field(f"{p}.count", T.INT64)]
     if call.fn in ("min", "max"):
@@ -175,6 +223,48 @@ def _first_occurrence(x: Column, gid_key: jax.Array) -> jax.Array:
         neq = neq | (v != jnp.roll(v, 1))
     first = (neq.at[0].set(True)) & (sgid < 2 ** 30)
     return jnp.zeros((cap,), jnp.bool_).at[perm].set(first)
+
+
+def finalize_sum(call: AggCall, s: Column, nonempty: jax.Array) -> Column:
+    """A sum's state -> its result column: null where no value was summed
+    and, for a decimal, where the sum left the result precision (Spark,
+    ANSI off: CheckOverflow nulls |sum| >= 10^p). The streaming finalize
+    and the whole-stage program (runtime/stage_compiler) both end here."""
+    dt = s.dtype
+    if not dt.is_decimal:
+        return Column(dt, s.data, nonempty)
+    if dt.wide_decimal:
+        from blaze_tpu.columnar import int128 as i128
+        from blaze_tpu.exprs import wide_decimal as W
+
+        # the seg shadow only catches magnitudes past 1.5e38
+        h, l = W.planes(s)
+        ok = s.valid_mask() & i128.in_precision(h, l, call.dtype.precision)
+        return Column(call.dtype, s.data, nonempty & ok)
+    ok = jnp.abs(s.data) < np.int64(10 ** call.dtype.precision)
+    return Column(call.dtype, s.data, nonempty & ok)
+
+
+def finalize_avg(call: AggCall, s: Column, cnt: jax.Array) -> Column:
+    """An avg's (sum, count) state -> its result column. A decimal avg is
+    Spark's: the sum, held at the input's scale, is brought to the result's
+    scale, divided by the count and rounded HALF_UP (ties away from zero),
+    all in 128 bits (sum x 10^4 passes int64 from 9.2e14 unscaled), and is
+    null where the count is 0 or the quotient leaves the result precision.
+    The streaming finalize and the whole-stage program both end here."""
+    ok = cnt > 0
+    if not call.dtype.is_decimal:
+        v = s.data.astype(jnp.float64) / jnp.maximum(cnt, 1).astype(
+            jnp.float64)
+        return Column(T.FLOAT64, jnp.where(ok, v, 0.0), ok)
+    from blaze_tpu.exprs import wide_decimal as W
+
+    with jax.named_scope("finalize.decimal_avg"):
+        h, l = W.planes(s)
+        qh, ql, ok_div = W.div_by_count(
+            h, l, cnt, call.dtype, call.dtype.scale - s.dtype.scale)
+        return W.shape(call.dtype, qh, ql,
+                       ok & ok_div & s.valid_mask()).normalized()
 
 
 class _AggState:
@@ -315,6 +405,10 @@ class AggExec(Operator):
                 [x for call in self.aggs for x in call.inputs])
             probe = ColumnBatch.empty(child_schema, bucket_capacity(0))
             gcols = [jax.eval_shape(fn, probe) for fn in self._group_fns]
+            for call, fns in zip(self.aggs, self._input_fns):
+                if call.fn in ("sum", "avg"):
+                    check_decimal_call(
+                        call, jax.eval_shape(fns[0], probe).dtype)
             group_fields = [Field(n, c.dtype)
                             for n, c in zip(self.group_names, gcols)]
         else:
@@ -324,6 +418,16 @@ class AggExec(Operator):
         state: List[Field] = []
         for i, call in enumerate(self.aggs):
             state.extend(state_fields(call, i))
+        if self.mode != AggMode.PARTIAL:
+            # the state arrives typed, by position: a decimal sum under
+            # another type than this plan derives (cents under a scale-6
+            # label) is refused here, not divided wrong later
+            for want, got in zip(state, child_schema.fields[ngroups:]):
+                if want.dtype != got.dtype and (want.dtype.is_decimal
+                                                or got.dtype.is_decimal):
+                    raise TypeError(
+                        f"agg state {got.name} arrives as {got.dtype}; "
+                        f"this plan's state is {want.dtype}")
         self._group_fields = group_fields
         self._state_fields = state
         if self.mode == AggMode.FINAL:
@@ -428,9 +532,8 @@ class AggExec(Operator):
                 with jax.named_scope("collapse.group_layout"):
                     layout = seg.group_layout(sb, list(range(ngroups)))
                 with jax.named_scope("collapse.group_keys"):
-                    gcols = [sb.columns[i].take(
-                        jnp.clip(layout.start_idx, 0, sb.capacity - 1))
-                        for i in range(ngroups)]
+                    gcols = [seg.group_first_rows(sb.columns[i], layout)
+                             for i in range(ngroups)]
                 with seg.count_forms() as forms:
                     if raw_input:
                         with jax.named_scope("collapse.accumulate_raw"):
@@ -439,14 +542,14 @@ class AggExec(Operator):
                         with jax.named_scope("collapse.merge_state"):
                             scols = self._merge_state(sb, layout, ngroups)
                 # traced once a program; _collapse adds it at every dispatch
-                _SEG_FORMS[key] = (forms["scan"], forms["scatter"])
+                _SEG_FORMS[key] = dict(forms)
                 return ColumnBatch(self._state_schema, gcols + scols,
                                    layout.num_groups, sb.capacity)
 
             return run
 
         out = jit_cache.get_or_compile(key, make)(big)
-        compile_service.note_seg_reductions(*_SEG_FORMS.get(key, (0, 0)))
+        compile_service.note_seg_reductions(**_SEG_FORMS.get(key, {}))
         return out
 
     def _is_state_input(self) -> bool:
@@ -487,20 +590,12 @@ class AggExec(Operator):
         if fn == "sum":
             sd = _sum_state_dtype(call.dtype)
             if sd.wide_decimal:
-                from blaze_tpu.columnar import int128 as i128
                 from blaze_tpu.exprs import wide_decimal as W
 
                 live = valid & layout.row_mask
+                # the sum keeps its input's scale (check_decimal_call)
                 h, l = W.planes(x)
-                # Spark sums keep the input scale; rescale defensively if
-                # the planned result scale differs (delta 0 is a no-op).
-                # A row that WRAPS during the upscale poisons its group
-                # (Spark: overflow -> null) — wrapped residues would
-                # otherwise defeat the sum's overflow shadow.
-                h, l, rok = i128.rescale_checked(h, l,
-                                                 sd.scale - x.dtype.scale)
                 sh, sl, ok = W.seg_sum_wide(h, l, live, layout, seg)
-                ok = ok & ~_seg_any(live & ~rok, layout)
                 nonempty = count(valid) > 0
                 return [W.build(sd, sh, sl, ok),
                         Column(T.BOOLEAN, nonempty, None)]
@@ -509,21 +604,16 @@ class AggExec(Operator):
             nonempty = count(valid) > 0
             return [Column(sd, s, None), Column(T.BOOLEAN, nonempty, None)]
         if fn == "avg":
-            sd = (call.dtype if call.dtype.kind == TypeKind.DECIMAL
-                  else T.FLOAT64)
+            # a decimal avg sums at its input's scale under the SUM's type
+            # (Spark's buffer, decimal(p+10, s)); finalize_avg rescales
+            sd = avg_sum_dtype(call.dtype)
             cnt = count(valid)
             if sd.wide_decimal:
-                from blaze_tpu.columnar import int128 as i128
                 from blaze_tpu.exprs import wide_decimal as W
 
                 live = valid & layout.row_mask
-                # state at the RESULT scale so finalize only divides;
-                # rows wrapping during the upscale poison their group
                 h, l = W.planes(x)
-                h, l, rok = i128.rescale_checked(h, l,
-                                                 sd.scale - x.dtype.scale)
                 sh, sl, ok = W.seg_sum_wide(h, l, live, layout, seg)
-                ok = ok & ~_seg_any(live & ~rok, layout)
                 return [W.build(sd, sh, sl, ok),
                         Column(T.INT64, cnt, None)]
             data = x.data.astype(sd.jnp_dtype())
@@ -769,33 +859,9 @@ class AggExec(Operator):
         if fn == "count":
             return scols[0]
         if fn == "sum":
-            if scols[0].dtype.wide_decimal:
-                from blaze_tpu.columnar import int128 as i128
-                from blaze_tpu.exprs import wide_decimal as W
-
-                # Spark nulls sums exceeding the result precision; the
-                # seg shadow only catches magnitudes past 1.5e38
-                h, l = W.planes(scols[0])
-                inp = i128.in_precision(h, l, call.dtype.precision)
-                v = scols[1].data & scols[0].valid_mask() & inp
-                return Column(call.dtype, scols[0].data, v)
-            return Column(scols[0].dtype, scols[0].data, scols[1].data)
+            return finalize_sum(call, scols[0], scols[1].data)
         if fn == "avg":
-            if call.dtype.wide_decimal:
-                from blaze_tpu.exprs import wide_decimal as W
-
-                h, l = W.planes(scols[0])
-                cnt = scols[1].data
-                qh, ql, ok_div = W.div_by_count(h, l, cnt, call.dtype, 0)
-                ok = (cnt > 0) & ok_div & scols[0].valid_mask()
-                return W.build(call.dtype, qh, ql, ok)
-            s, cnt = scols[0].data, scols[1].data
-            ok = cnt > 0
-            if call.dtype.kind == TypeKind.DECIMAL:
-                q = jnp.where(ok, s // jnp.maximum(cnt, 1), 0)
-                return Column(call.dtype, q, ok)
-            v = s.astype(jnp.float64) / jnp.maximum(cnt, 1).astype(jnp.float64)
-            return Column(T.FLOAT64, jnp.where(ok, v, 0.0), ok)
+            return finalize_avg(call, scols[0], scols[1].data)
         if fn in ("min", "max", "first_ignores_null"):
             return Column(call.dtype, scols[0].data, scols[1].data)
         if fn == "first":

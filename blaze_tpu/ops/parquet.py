@@ -17,6 +17,7 @@ hadoop_fs.rs) — local paths are opened directly when absent.
 from __future__ import annotations
 
 import logging
+import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -34,6 +35,17 @@ from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
 from blaze_tpu.runtime import resources, trace
 
 logger = logging.getLogger(__name__)
+
+# A scan batch's column buffers are 16-32 MB each. Arrow's default pool on
+# Linux (mimalloc) gives such a buffer back to the OS when it is freed and
+# maps a fresh one for every second scan: 80 ms of page faults before a
+# query's first upload, every other query, so q06-core's queries took 0.69
+# and 0.80 s in turn and a window's median fell anywhere between (v5e host,
+# transparent huge pages off; PERF.md section 6, PR 32). glibc's allocator
+# keeps the pages: 0.73-0.77 s, every query. A deployment that names its
+# pool (ARROW_DEFAULT_MEMORY_POOL) keeps it.
+if "ARROW_DEFAULT_MEMORY_POOL" not in os.environ:
+    pa.set_memory_pool(pa.system_memory_pool())
 
 
 def _stat_prune(expr: ir.Expr, stats: Dict[str, Tuple]) -> bool:
@@ -62,6 +74,12 @@ def _stat_prune(expr: ir.Expr, stats: Dict[str, Tuple]) -> bool:
             return False
         mn, mx = st
         v = r.value
+        if r.dtype.is_decimal:
+            # the literal holds its unscaled integer, the statistics a
+            # decimal.Decimal: 50000 under decimal(7,2) is 500.00
+            import decimal
+
+            v = decimal.Decimal(int(v)).scaleb(-r.dtype.scale)
         try:
             if expr.op == ir.BinOp.EQ:
                 return v < mn or v > mx

@@ -145,7 +145,7 @@ def element_rows(offsets: Array, cap: int, ecap: int):
 # Sums and counts take a layout from `group_layout` over a key-sorted batch,
 # so a group is a run of rows: its total is a running sum that restarts at
 # `layout.starts` (`_restarting_sum`), read at the run's last row and brought
-# to slot g by a gather at `layout.end_idx` (`_at_run_ends`). One form for
+# to slot g by a gather at `layout.end_idx` (`_at_group_rows`). One form for
 # every dtype, a mask counted as int32 (a batch has at most 2^21 slots), a
 # group's own terms only: never a difference of global prefixes, which
 # subtracts prefixes of ~10^10 to get sums of ~10^6 and lets one inf or NaN
@@ -160,8 +160,8 @@ def element_rows(offsets: Array, cap: int, ecap: int):
 # scatter-based (`jax.ops.segment_min` / `segment_max`): no cell runs them.
 
 _BLOCK = 256   # rows one lane of `_restarting_sum` walks (at 128: 22 ms, not 1.3)
-_FEW = 16      # `_at_run_ends`: groups in the first 1/_FEW of the slots
-_PLAIN = 1 << 16   # `_at_run_ends`: no conditional up to this many slots
+_FEW = 16      # `_at_group_rows`: groups in the first 1/_FEW of the slots
+_PLAIN = 1 << 16   # `_at_group_rows`: no conditional up to this many slots
 
 _FORMS = threading.local()
 
@@ -169,11 +169,15 @@ _FORMS = threading.local()
 @contextlib.contextmanager
 def count_forms():
     """Tally the per-group reductions traced inside the scope by the form
-    they were built in: yields {"scan": n, "scatter": n}. A trace-time
-    count of this thread; `ops/agg` keeps it per program and adds it to
+    they were built in, and the sums among them by what `seg_sum` was handed
+    to add: yields {"scan": n, "scatter": n, "sums": n, "int_sums": n}
+    (`sums`: every seg_sum over numbers, flags counted aside; `int_sums`:
+    those over an integer array). A trace-time count of this thread;
+    `ops/agg` keeps it per program and adds it to
     `compile_service.TELEMETRY` at every dispatch."""
     outer = getattr(_FORMS, "tally", None)
-    tally = _FORMS.tally = {"scan": 0, "scatter": 0}
+    tally = _FORMS.tally = {"scan": 0, "scatter": 0, "sums": 0,
+                            "int_sums": 0}
     try:
         yield tally
     finally:
@@ -236,8 +240,10 @@ def _restarting_sum(v: Array, starts: Array) -> Array:
     return out.T.reshape(n + pad)[:n]
 
 
-def _at_run_ends(x: Array, layout: GroupLayout) -> Array:
-    """`x` at each run's last row in slot g, exactly 0 past `num_groups`.
+def _at_group_rows(x: Array, idx: Array, layout: GroupLayout) -> Array:
+    """`x` at row `idx[g]` in slot g, exactly 0 past `num_groups`: with
+    `layout.end_idx` a run's last row (`seg_sum`), with `layout.start_idx`
+    its first (the group's key).
 
     A gather by computed index costs by the slots it fills, live or not
     (8-16 ns each on a v5e), so where the groups fit the first 1/`_FEW` of
@@ -253,16 +259,30 @@ def _at_run_ends(x: Array, layout: GroupLayout) -> Array:
     zero = jnp.zeros((), x.dtype)
 
     def every_slot(_):
-        return jnp.where(layout.group_mask, x[layout.end_idx], zero)
+        return jnp.where(layout.group_mask, x[idx], zero)
 
     def first_slots(_):
-        head = jnp.where(layout.group_mask[:few], x[layout.end_idx[:few]],
-                         zero)
+        head = jnp.where(layout.group_mask[:few], x[idx[:few]], zero)
         return jnp.concatenate([head, jnp.zeros((cap - few,), x.dtype)])
 
     if cap <= _PLAIN:
         return every_slot(None)
     return lax.cond(layout.num_groups <= few, first_slots, every_slot, None)
+
+
+def group_first_rows(col: Column, layout: GroupLayout) -> Column:
+    """Each run's first row of `col` in slot g: a group's key, off the
+    key-sorted batch. A flat column goes plane by plane through
+    `_at_group_rows`, so 18,000 groups in 2^21 slots gather 2^17 of them and
+    not every one (where all the slots past the groups read row 0 the gather
+    took 37 to 106 ms by where its buffers lay: PERF.md section 6, PR 32);
+    strings, lists and structs are taken whole."""
+    idx = jnp.clip(layout.start_idx, 0, col.capacity - 1)
+    if col.is_string or col.is_list or col.is_struct:
+        return col.take(idx)
+    v = col.validity
+    return Column(col.dtype, _at_group_rows(col.data, idx, layout),
+                  None if v is None else _at_group_rows(v, idx, layout))
 
 
 def seg_sum(values: Array, layout: GroupLayout, valid: Array) -> Array:
@@ -275,8 +295,14 @@ def seg_sum(values: Array, layout: GroupLayout, valid: Array) -> Array:
     if values.dtype == jnp.bool_:
         v = (values & live).astype(jnp.int32)
     else:
+        # the dtype the numbers are added in, read off the array itself: a
+        # decimal's unscaled integers cast to double upstream show here
+        _note_form("sums")
+        if jnp.issubdtype(values.dtype, jnp.integer):
+            _note_form("int_sums")
         v = jnp.where(live, values, jnp.zeros((), values.dtype))
-    return _at_run_ends(_restarting_sum(v, layout.starts), layout)
+    return _at_group_rows(_restarting_sum(v, layout.starts), layout.end_idx,
+                          layout)
 
 
 def seg_count(valid: Array, layout: GroupLayout) -> Array:

@@ -68,6 +68,7 @@ _COUNTERS = (
     "canonicalization_waste_rows", "stage_attempts", "stage_compiled",
     "agg_pallas_traces", "agg_xla_traces",
     "seg_scan_reductions", "seg_scatter_reductions",
+    "seg_sums", "seg_int_sums",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -110,12 +111,18 @@ def note_agg_trace(pallas: bool) -> None:
     TELEMETRY.add("agg_pallas_traces" if pallas else "agg_xla_traces", 1)
 
 
-def note_seg_reductions(scan: int, scatter: int) -> None:
+def note_seg_reductions(scan: int = 0, scatter: int = 0, sums: int = 0,
+                        int_sums: int = 0) -> None:
     """ops/agg dispatched one agg_collapse program whose per-group
     reductions (ops/segment) were built as `scan` scans and `scatter`
-    scatters."""
+    scatters; `sums` of them add numbers (flags counted aside), `int_sums`
+    of those over an integer array, as seg_sum saw its argument: the type
+    money was summed in, which an exact comparison of the answers cannot
+    tell (a double adds SF1's cents exactly too)."""
     TELEMETRY.add("seg_scan_reductions", scan)
     TELEMETRY.add("seg_scatter_reductions", scatter)
+    TELEMETRY.add("seg_sums", sums)
+    TELEMETRY.add("seg_int_sums", int_sums)
 
 
 def telemetry_summary() -> str:

@@ -36,7 +36,7 @@ from blaze_tpu.columnar.types import TypeKind
 from blaze_tpu.config import conf
 from blaze_tpu.ops import mxu_agg
 from blaze_tpu.ops.agg import (
-    AggExec, AggMode, result_field, state_fields,
+    AggExec, AggMode, finalize_avg, finalize_sum, state_fields,
 )
 from blaze_tpu.ops.base import ExecContext, MapLikeOp, Operator
 from blaze_tpu.runtime import compile_service, jit_cache, trace
@@ -157,7 +157,9 @@ def _match(root: Operator):
     for call in partial.aggs:
         if call.fn not in _AGG_FNS or len(call.inputs) != 1:
             return None
-        if call.dtype.wide_decimal:
+        if call.dtype.wide_decimal or (
+                call.fn == "avg" and
+                state_fields(call, 0)[0].dtype.wide_decimal):
             return None  # int128 limb planes keep the streaming path
         if call.fn in _MM_FNS + _FIRST_FNS:
             if call.dtype.kind not in _MM_VALUE_KINDS:
@@ -659,28 +661,17 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
                                                None))
                     continue
                 if out_mode_final:
-                    if call.fn == "avg":
-                        ok = cnt > 0
-                        if call.dtype.kind == TypeKind.DECIMAL:
-                            # decimal avg: unscaled floor-div at the
-                            # planned result scale (ops/agg.py finalize)
-                            q = jnp.where(ok,
-                                          outs[si] // jnp.maximum(cnt, 1),
-                                          0)
-                            cols.append(Column(call.dtype, _pad(q, cap),
-                                               _pad(ok, cap)))
-                            continue
-                        v = outs[si].astype(jnp.float64) / \
-                            jnp.maximum(cnt, 1).astype(jnp.float64)
-                        cols.append(Column(T.FLOAT64,
-                                           _pad(jnp.where(ok, v, 0.0),
-                                                cap),
-                                           _pad(ok, cap)))
-                    else:  # sum
-                        ok = cnt > 0
-                        cols.append(Column(
-                            result_field(call).dtype,
-                            _pad(outs[si], cap), _pad(ok, cap)))
+                    # the streaming finalize's own functions: one
+                    # semantics (decimal avg HALF_UP at the result scale,
+                    # a sum past its precision null) on both paths
+                    sd = state_fields(call, i)[0].dtype
+                    state = Column(sd, outs[si].astype(sd.jnp_dtype()),
+                                   None)
+                    done = (finalize_avg(call, state, cnt)
+                            if call.fn == "avg"
+                            else finalize_sum(call, state, cnt > 0))
+                    cols.append(Column(done.dtype, _pad(done.data, cap),
+                                       _pad(done.validity, cap)))
                     continue
                 # partial (shuffle map side): typed STATE columns in the
                 # agg-buf layout the FINAL merge consumes by position

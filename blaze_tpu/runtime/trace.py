@@ -306,6 +306,10 @@ SPAN_KINDS = (
     "d2h",           # serde.to_host / ColumnBatch.to_numpy: a device->host
                      # pull (blocks until the device has the value); the
                      # caller's last pull is marked final; collect_s
+    "decimal_decode",  # arrow_io.column_from_arrow: one decimal column's
+                     # decimal128 words -> int64 planes of unscaled
+                     # values, on the host (rows, wide); inside the scan's
+                     # h2d; decimal_decode_s
     "dispatch",      # jit_cache: one call of a cached program, host time
                      # of the (asynchronous) dispatch; first_call compiles
     "exchange",      # stage_exchange: one batch (or, on a mesh, one

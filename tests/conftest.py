@@ -36,3 +36,17 @@ _LONGEST_FIRST = ("test_tpcds_smj.py", "test_tpcds.py", "test_join.py",
 def pytest_collection_modifyitems(items):
     rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
     items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """A file's tests stay on one worker whatever `--dist` says. The order
+    above is made for that: dealt out test by test (`--dist load`) it puts
+    the TPC-DS files and test_join.py into one process, which then
+    segfaults inside jaxlib's CPU compile at one of test_join.py's joins
+    (five whole runs of five, the parent commit's too), and every
+    module-scoped fixture is built once a worker instead of once."""
+    from xdist.scheduler import LoadFileScheduling
+
+    return LoadFileScheduling(config, log)
+

@@ -105,3 +105,80 @@ def test_take_with_index_valid():
     out = batch.take(idx, 2, index_valid=iv)
     r = out.to_numpy()
     assert list(r["a"]) == [5, None]
+
+
+# ---- compact: flag and integer planes go to their ranks by a scatter of
+# 32-bit words, everything else by a gather at the kept rows' positions ----
+
+_COMPACT_PLANES = {
+    "int64": lambda rng, n: rng.integers(-2**62, 2**62, n),
+    "int64_edges": lambda rng, n: rng.choice(np.array(
+        [-2**63, 2**63 - 1, -1, 0, 1, 2**32, -2**32, 2**31, 0xFFFFFFFF],
+        dtype=np.int64), n),
+    "uint64": lambda rng, n: rng.integers(0, 2**64, n, dtype=np.uint64),
+    "int32": lambda rng, n: rng.integers(-2**31, 2**31, n).astype(np.int32),
+    "bool": lambda rng, n: rng.random(n) < 0.5,
+    "float64": lambda rng, n: rng.normal(0, 1e9, n),
+    "int16": lambda rng, n: rng.integers(-2**15, 2**15, n).astype(np.int16),
+}
+
+
+@pytest.mark.parametrize("num_rows", [0, 1, 1000, 1024])
+@pytest.mark.parametrize("plane", sorted(_COMPACT_PLANES))
+def test_compact_planes_vs_numpy(plane, num_rows):
+    import jax
+    import jax.numpy as jnp
+
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column, _ranks_well
+
+    cap = 1024
+    rng = np.random.default_rng(len(plane) * 1000 + num_rows)
+    data = _COMPACT_PLANES[plane](rng, cap)
+    valid, keep = rng.random(cap) < 0.9, rng.random(cap) < 0.5
+    dtype = {"bool": T.BOOLEAN, "int32": T.INT32, "float64": T.FLOAT64,
+             "int16": T.INT16}.get(plane, T.INT64)
+    batch = ColumnBatch(Schema([Field("x", dtype)]),
+                        [Column(dtype, jnp.asarray(data), jnp.asarray(valid))],
+                        jnp.asarray(num_rows, jnp.int32), cap)
+    assert _ranks_well(batch.columns[0].data) == (
+        plane not in ("float64", "int16"))
+    out = jax.jit(lambda b, k: b.compact(k))(batch, jnp.asarray(keep))
+    kept = np.flatnonzero(keep[:num_rows])
+    assert int(out.num_rows) == len(kept) and out.capacity == cap
+    got = np.asarray(out.columns[0].data)
+    assert got.dtype == data.dtype
+    np.testing.assert_array_equal(got[:len(kept)], data[kept])
+    np.testing.assert_array_equal(
+        np.asarray(out.columns[0].validity)[:len(kept)], valid[kept])
+
+
+def test_compact_integer_batch_lowers_without_a_gather():
+    """A batch of flags and 32/64-bit integers is compacted by scatters
+    alone; a double beside them brings the positions and one gather."""
+    import jax
+    import jax.numpy as jnp
+
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column
+
+    cap = 1 << 12
+    money = decimal(7, 2)
+
+    def batch(money_dtype, plane_dtype):
+        def s(dt):
+            return jax.ShapeDtypeStruct((cap,), dt)
+        fields = [Field("k", INT64), Field("p", money_dtype)]
+        cols = [Column(INT64, s(jnp.int64), None),
+                Column(money_dtype, s(plane_dtype), s(jnp.bool_))]
+        return ColumnBatch(Schema(fields), cols,
+                           jax.ShapeDtypeStruct((), jnp.int32), cap)
+
+    def keep_positive(b):
+        return b.compact(b.columns[1].data > 0)
+
+    text = jax.jit(keep_positive).lower(batch(money, jnp.int64)).as_text()
+    assert "gather" not in text and "scatter" in text
+    text = jax.jit(keep_positive).lower(
+        batch(T.FLOAT64, jnp.float64)).as_text()
+    assert text.count("gather") >= 1 and "scatter" in text

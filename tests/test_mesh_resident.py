@@ -335,10 +335,13 @@ def test_readers_on_recorded_runs(name, run, want):
 def test_manifest_lists_the_cell_its_config_and_its_metrics():
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
-    cell = manifest["workloads"][-1]
-    assert cell == {**cell, "name": "sf1_q03_nobhj_x4", "chips": 4,
-                    "config": "tpcds_sf1_nobhj_x4", "traffic": "q03_loop1"}
-    config = manifest["configs"][-1]
+    # by name: later PRs append cells and configurations to the lists
+    (cell,) = [c for c in manifest["workloads"]
+               if c["name"] == "sf1_q03_nobhj_x4"]
+    assert cell == {**cell, "chips": 4, "config": "tpcds_sf1_nobhj_x4",
+                    "traffic": "q03_loop1"}
+    (config,) = [c for c in manifest["configs"]
+                 if c["name"] == cell["config"]]
     with open(os.path.join(REPO, config["file"])) as fh:
         on_file = json.load(fh)
     assert config["name"] == on_file["name"] == "tpcds_sf1_nobhj_x4"

@@ -102,3 +102,22 @@ def test_sink_roundtrip(tmp_path, rng):
                                   np.asarray(b.to_numpy()["a"]))
     assert back.column("s").to_pylist() == [
         s.decode() for s in b.to_numpy()["s"]]
+
+
+def test_scans_read_through_the_system_pool():
+    """Importing the scan sets Arrow's pool to the system allocator, unless
+    the deployment named one: a child with the variable set keeps its own."""
+    import os
+    import subprocess
+    import sys
+
+    import blaze_tpu.ops.parquet  # noqa: F401
+
+    assert pa.default_memory_pool().backend_name == "system"
+    code = ("import pyarrow as pa, blaze_tpu.ops.parquet; "
+            "print(pa.default_memory_pool().backend_name)")
+    env = {**os.environ, "ARROW_DEFAULT_MEMORY_POOL": "mimalloc",
+           "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, check=True)
+    assert out.stdout.split()[-1] == "mimalloc"

@@ -131,7 +131,7 @@ def test_seg_minmax_nan_inf_semantics(rng):
 
 # ---- seg_sum / seg_count / seg_any: the scan forms against numpy ----
 
-# holds 18,000 keys and is past seg._PLAIN, so `_at_run_ends` takes its
+# holds 18,000 keys and is past seg._PLAIN, so `_at_group_rows` takes its
 # conditional: narrow for one group and 18 keys, every slot for the rest
 CAP = 1 << 17
 
@@ -228,3 +228,44 @@ def test_seg_sum_int64_wraps_like_a_scatter_add():
         np.add.at(want, keys, v)
     assert want[1] < 0  # the reference wrapped
     np.testing.assert_array_equal(np.asarray(sums), want)
+
+
+# ---- group_first_rows: a group's key off its run's first row ----
+
+@jax.jit
+def _first_rows(keys, valid, num_rows):
+    b = ColumnBatch(T.Schema([T.Field("k", T.INT64)]),
+                    [Column(T.INT64, keys, valid)], num_rows, keys.shape[0])
+    layout = seg.group_layout(b, [0])
+    col = seg.group_first_rows(b.columns[0], layout)
+    return layout.num_groups, col.data, col.validity
+
+
+@pytest.mark.parametrize("num_rows", [0, 1, CAP, CAP - 3])
+@pytest.mark.parametrize("keys", ["one", "each", "18", "18000"])
+def test_group_first_rows_vs_numpy(rng, keys, num_rows):
+    """Both branches of the conditional (18,000 keys fit the first
+    sixteenth of 2^17 slots, `each` does not), a null key as a group of its
+    own, and nothing read past the groups."""
+    k = _keys(keys, rng)
+    valid = np.ones(CAP, np.bool_)
+    if keys != "one":
+        valid[k == k[0]] = False     # the first run is the null group
+    groups, data, validity = _first_rows(k, valid, np.int32(num_rows))
+    want, first = np.unique(k[:num_rows], return_index=True)
+    assert int(groups) == len(want)
+    np.testing.assert_array_equal(np.asarray(data)[:len(want)], want)
+    np.testing.assert_array_equal(np.asarray(validity)[:len(want)],
+                                  valid[first])
+
+
+def test_group_first_rows_takes_strings_whole(rng):
+    n = 64
+    words = sorted(f"k{i:02d}" for i in rng.integers(0, 9, n))
+    b = ColumnBatch.from_numpy({"s": words},
+                               T.Schema([T.Field("s", T.STRING)]))
+    layout = seg.group_layout(b, [0])
+    col = seg.group_first_rows(b.columns[0], layout)
+    got = ColumnBatch(b.schema, [col], layout.num_groups,
+                      b.capacity).to_numpy()["s"]
+    assert got == [w.encode() for w in sorted(set(words))]

@@ -41,7 +41,8 @@ ONE_CHIP = {w["name"]: w for w in _json(REPO, "BENCHMARK.json")["workloads"]
 
 def test_the_manifest_has_the_one_chip_cells_this_file_names():
     assert set(ONE_CHIP) == {"sf10_q03_bhj", "sf1_q06core_agg",
-                             "sf1_q03_nobhj"}
+                             "sf1_q03_nobhj", "sf10_q06core_agg",
+                             "sf1_q06core_agg_dec"}
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +92,8 @@ def one_chip(monkeypatch):
     monkeypatch.setattr(jax, "devices", lambda *a, **k: real(*a, **k)[:1])
 
 
-@pytest.mark.parametrize("name", ["sf10_q03_bhj", "sf1_q06core_agg"])
+@pytest.mark.parametrize("name", ["sf10_q03_bhj", "sf1_q06core_agg",
+                                  "sf10_q06core_agg", "sf1_q06core_agg_dec"])
 def test_cell_equals_its_reference_and_nothing_is_refused(
         cells, one_chip, name):
     wrong, refused = cells(name)()

@@ -523,22 +523,26 @@ def test_float_digit_plane_knob_precision(rng):
 
 def test_decimal_aggs_whole_stage(rng):
     """int64-backed decimal sum/avg/min ride the dense MXU path (exact
-    int digit planes; avg = unscaled floor-div like the streaming
-    finalize). Wide decimals (p>18) keep the streaming path."""
-    dec = T.decimal(12, 2)
+    int digit planes) and end in the streaming path's own finalize
+    (ops/agg.finalize_sum / finalize_avg): both equal an integer
+    reference, avg HALF_UP at Spark's scale + 4. Types are Spark's for a
+    decimal(8,2) input; wide decimals (p>18, or an avg whose sum buffer
+    is) keep the streaming path."""
+    dec = T.decimal(8, 2)
     schema = T.Schema([T.Field("k", T.INT64), T.Field("d", dec)])
-    calls = [AggCall("sum", (col("d"),), dec, "s"),
-             AggCall("avg", (col("d"),), dec, "a"),
+    calls = [AggCall("sum", (col("d"),), T.decimal(18, 2), "s"),
+             AggCall("avg", (col("d"),), T.decimal(12, 6), "a"),
              AggCall("min", (col("d"),), dec, "mn"),
              AggCall("count", (col("d"),), T.INT64, "c")]
-    batches = []
+    batches, rows = [], []
     for _ in range(3):
         n = 400
+        k = rng.integers(0, 50, n).astype(np.int64)
+        d = rng.integers(-10**6, 10**6, n)
+        ok = rng.random(n) > 0.2
+        rows += [(int(a), int(b)) for a, b, v in zip(k, d, ok) if v]
         batches.append(ColumnBatch.from_numpy(
-            {"k": rng.integers(0, 50, n).astype(np.int64),
-             "d": rng.integers(-10**6, 10**6, n)},
-            schema,
-            validity={"d": rng.random(n) > 0.2}, capacity=1024))
+            {"k": k, "d": d}, schema, validity={"d": ok}, capacity=1024))
     node = MemorySourceExec(batches, schema)
     for mode in (AggMode.PARTIAL, AggMode.FINAL):
         node = AggExec(node, [col("k")], ["k"], calls, mode)
@@ -557,3 +561,14 @@ def test_decimal_aggs_whole_stage(rng):
     for name in ("s", "a", "mn", "c"):
         assert [None if x is None else int(x) for x in gd[name]] == \
             [None if x is None else int(x) for x in wd[name]], name
+
+    def half_up(num, den):       # Python ints: ties away from zero
+        q, r = divmod(abs(num), den)
+        return (q + (2 * r >= den)) * (1 if num >= 0 else -1)
+
+    for i, key in enumerate(int(x) for x in gd["k"]):
+        vals = [d for k, d in rows if k == key]
+        assert int(gd["c"][i]) == len(vals)
+        assert int(gd["s"][i]) == sum(vals)
+        assert int(gd["mn"][i]) == min(vals)
+        assert int(gd["a"][i]) == half_up(sum(vals) * 10 ** 4, len(vals))

@@ -1,6 +1,6 @@
 """The per-group reductions of ops/segment.py compiled at real capacities
 for a described TPU v5e (the chip's compiler, no chip attached): what the
-chip would run holds no scatter, and the conditional of `_at_run_ends` is
+chip would run holds no scatter, and the conditional of `_at_group_rows` is
 there only past `seg._PLAIN` slots. A compile that passes is not a chip
 run: times are in PERF.md, from the chip.
 
@@ -54,3 +54,37 @@ def test_seg_reductions_compile_for_v5e_without_scatter(one_chip, cap):
     assert " scatter(" not in text
     assert " while(" in text  # the blocked scans
     assert text.count(" conditional(") == (4 if cap > seg._PLAIN else 0)
+
+
+def test_integer_compaction_compiles_for_v5e_without_a_gather(one_chip):
+    """The filter's compaction of q06-core's decimal batch at 2^21 rows, as
+    the chip's compiler leaves it: scatters of one 32-bit operand each (a
+    64-bit scatter is one program of two operands, fifteen times dearer a
+    row on the chip), no gather."""
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column, ColumnBatch
+
+    cap = 1 << 21
+
+    def arg(dtype, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    money = T.decimal(7, 2)
+    schema = T.Schema([T.Field("k", T.INT64), T.Field("p", money),
+                       T.Field("e", money)])
+    batch = ColumnBatch(schema, [
+        Column(T.INT64, arg(jnp.int64), None),
+        Column(money, arg(jnp.int64), arg(jnp.bool_)),
+        Column(money, arg(jnp.int64), arg(jnp.bool_))],
+        arg(jnp.int32, ()), cap)
+
+    def keep_dear(b):
+        c = b.columns[2]
+        return b.compact((c.data > 10000) & c.valid_mask())
+
+    text = jax.jit(keep_dear).lower(batch).compile().as_text()
+    assert " gather(" not in text
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert scatters and all(
+        line.split(" scatter(")[0].count("[2097152]") == 1
+        for line in scatters), scatters[:2]
