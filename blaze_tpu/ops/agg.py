@@ -39,7 +39,10 @@ from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
 from blaze_tpu.ops import segment as seg
-from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
+from blaze_tpu.ops.base import (
+    BatchStream, ExecContext, Operator, batch_tap, count_stream,
+)
+from blaze_tpu.ops.basic import FilterExec
 from blaze_tpu.ops.common import concat_batches
 from blaze_tpu.ops.sort import truncate
 from blaze_tpu.ops.sort_keys import SortSpec, sort_batch
@@ -52,6 +55,9 @@ from blaze_tpu.runtime import compile_service, jit_cache
 _SEG_FORMS: dict = {}
 
 AGG_BUF_PREFIX = "#9223372036854775807"  # ref agg/mod.rs:38
+# the last plane of a raw work batch whose filter was not run on its own:
+# the filter's verdict on each slot (AggExec._mask_filter)
+KEEP_PLANE = "filter.keep"
 
 
 class AggMode(enum.Enum):
@@ -339,11 +345,13 @@ class _AggState:
         self.states.append(s)
         self.state_bytes += self._M.batch_nbytes(s)
 
-    def add_raw(self, work: ColumnBatch) -> None:
+    def add_raw(self, work: ColumnBatch, rows: Optional[int] = None) -> None:
+        """`rows`: the batch's rows that count, where the caller has them on
+        the host already (a carried filter's kept count)."""
         # op_lock: serialize against host-driven release() (bn_spill)
         with self.manager.op_lock:
             self.raw.append(work)
-            self.raw_rows += int(work.num_rows)
+            self.raw_rows += int(work.num_rows) if rows is None else rows
             self.raw_bytes += self._M.batch_nbytes(work)
             if self.raw_rows >= self.op.collapse_threshold:
                 self._collapse_all()
@@ -455,17 +463,25 @@ class AggExec(Operator):
             manager = M.get_manager(ctx)
             state = _AggState(self, manager)
             seen = False
+            filt = self._mask_filter()
             try:
-                for batch in self.children[0].execute(ctx):
+                # (batch, None), or (work batch, rows kept) where the
+                # child is a filter whose mask the collapse carries
+                inputs = (self._masked_work(filt, ctx) if filt is not None
+                          else ((b, None)
+                                for b in self.children[0].execute(ctx)))
+                for batch, kept in inputs:
                     ctx.check_running()
-                    if int(batch.num_rows) == 0:
+                    if (int(batch.num_rows) if kept is None else kept) == 0:
                         continue
                     seen = True
                     with self.metrics.timer():
                         if self._is_state_input():
                             state.add_state(batch)
-                        else:
+                        elif kept is None:
                             state.add_raw(self._to_work(batch))
+                        else:
+                            state.add_raw(batch, kept)
                 if not seen:
                     if not self.group_exprs:
                         yield self._empty_global_result()
@@ -485,50 +501,97 @@ class AggExec(Operator):
 
         return count_stream(self, gen())
 
-    def _to_work(self, batch: ColumnBatch) -> ColumnBatch:
+    def _mask_filter(self):
+        """The FilterExec whose mask this aggregate carries into its
+        collapse instead of reading the filter's compacted batches, or None:
+        a PARTIAL aggregate fed directly by a filter that stays on the
+        device. A compaction never shrinks a capacity, and the collapse
+        sorts whatever it is handed with the dead slots last, so at every
+        selectivity the filter's gathers bought the collapse nothing."""
+        child = self.children[0]
+        if (self.mode == AggMode.PARTIAL and isinstance(child, FilterExec)
+                and child.jit_safe()):
+            return child
+        return None
+
+    def _masked_work(self, filt, ctx: ExecContext):
+        """The absorbed filter's input, batch by batch, as (work batch with
+        the filter's keep plane, rows it kept). The filter runs no program
+        of its own, so its output boundary is kept here: counters, trace,
+        history, progress and fault point see the kept count, which is
+        pulled where `add_raw` would have pulled the work batch's rows."""
+        note_filter = batch_tap(filt)
+        for batch in filt.child.execute(ctx):
+            with self.metrics.timer():
+                work, kept = self._to_work(batch, filt)
+                compile_service.note_filter_batches(carried=1)
+                kept = int(kept)
+            note_filter(batch, kept)
+            yield work, kept
+
+    def _to_work(self, batch: ColumnBatch, filt=None):
         """Project child rows into the (group cols + per-agg inputs | state)
-        working layout."""
+        working layout. With `filt` (`_mask_filter`) the batch is the
+        filter's INPUT: the same program evaluates the filter's predicates
+        and the work batch takes their verdict as one more plane, KEEP_PLANE,
+        its `num_rows` still the physical rows; returns (work, kept count)."""
         if self.mode != AggMode.PARTIAL:
             return batch  # already group+state layout
         key = ("agg_work", self._work_jit, self.plan_key(),
                batch.shape_key())
-
-        def make():
-            from blaze_tpu.exprs.compiler import cse_scope
-
-            gfns, ifns = self._group_fns, self._input_fns
-
-            def run(b: ColumnBatch) -> ColumnBatch:
-                with cse_scope():
-                    cols = [fn(b) for fn in gfns]
-                    fields = list(self._group_fields)
-                    for call, fns in zip(self.aggs, ifns):
-                        for j, fn in enumerate(fns):
-                            c = fn(b)
-                            cols.append(c)
-                            fields.append(
-                                Field(f"in.{call.name}.{j}", c.dtype))
-                return b.with_columns(Schema(fields), cols)
-
-            return run
-
-        return jit_cache.get_or_compile(key, make,
+        return jit_cache.get_or_compile(key, lambda: self._work_fn(filt),
                                         jit=self._work_jit)(batch)
+
+    def _work_fn(self, filt=None):
+        """The program of `_to_work`, to be jitted."""
+        from blaze_tpu.exprs.compiler import cse_scope
+
+        gfns, ifns = self._group_fns, self._input_fns
+        keep_of = None if filt is None else filt.make_keep_fn()
+
+        def run(b: ColumnBatch):
+            with cse_scope():
+                cols = [fn(b) for fn in gfns]
+                fields = list(self._group_fields)
+                for call, fns in zip(self.aggs, ifns):
+                    for j, fn in enumerate(fns):
+                        c = fn(b)
+                        cols.append(c)
+                        fields.append(Field(f"in.{call.name}.{j}", c.dtype))
+            if keep_of is None:
+                return b.with_columns(Schema(fields), cols)
+            with cse_scope(), jax.named_scope(filt.label()):
+                keep = keep_of(b)
+            cols.append(Column(T.BOOLEAN, keep, None))
+            fields.append(Field(KEEP_PLANE, T.BOOLEAN, nullable=False))
+            return (b.with_columns(Schema(fields), cols),
+                    jnp.sum(keep & b.row_mask(), dtype=jnp.int32))
+
+        return run
 
     def _collapse(self, batches: List[ColumnBatch], raw_input: bool
                   ) -> ColumnBatch:
         big = batches[0] if len(batches) == 1 else concat_batches(batches)
         big = compile_service.canonical_batch(big, "agg_collapse")
         key = ("agg_collapse", raw_input, self.plan_key(), big.shape_key())
+        # raw work batches of a carried filter end in its keep plane
+        masked = raw_input and self._mask_filter() is not None
 
         def make():
             def run(b: ColumnBatch) -> ColumnBatch:
                 ngroups = len(self._group_fields)
                 specs = [SortSpec(i) for i in range(ngroups)]
+                live = None
+                if masked:
+                    # the rows the filter dropped are dead slots to the
+                    # sort, as padding is: it sends them last, and the
+                    # keep plane itself is not permuted
+                    live = b.row_mask() & b.columns[-1].data
+                    b = b.select(range(len(b.columns) - 1))
                 # the phases of the collapse, as named scopes (the sort
                 # brings its own: sort.encode_keys / sort / permute)
                 with jax.named_scope("collapse.sort"):
-                    sb = sort_batch(b, specs)
+                    sb = sort_batch(b, specs, live=live)
                 with jax.named_scope("collapse.group_layout"):
                     layout = seg.group_layout(sb, list(range(ngroups)))
                 with jax.named_scope("collapse.group_keys"):

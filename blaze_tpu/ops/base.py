@@ -133,8 +133,15 @@ def add_compute_split(op: Operator, ns: int, device: bool) -> None:
                    ns)
 
 
-def count_stream(op: Operator, stream: BatchStream) -> BatchStream:
-    """Wrap a stream updating the operator's baseline metrics.
+def batch_tap(op: Operator) -> Callable[..., None]:
+    """The bookkeeping of one operator's output boundary, as a function
+    to call once a batch: `note(batch)` reads the batch's rows,
+    `note(batch, rows)` takes them from a caller that has them on the host.
+
+    `count_stream` calls it for every batch of a stream. An operator whose
+    consumer ran its work for it (a FilterExec whose mask a partial
+    aggregate carried, ops/agg) has no output stream to wrap: the consumer
+    calls it with the count the operator would have produced.
 
     With `conf.enable_input_batch_statistics` (the reference's
     batch_statisitcs module: per-exec input-batch stat metrics behind
@@ -168,22 +175,34 @@ def count_stream(op: Operator, stream: BatchStream) -> BatchStream:
     else:
         progress = None
     fault_point = "op." + op.name()  # chaos injection at the op boundary
+
+    def note(batch: ColumnBatch, rows: Optional[int] = None) -> None:
+        if conf.fault_injection_spec:
+            faults.inject(fault_point)
+        if rows is None:
+            rows = int(batch.num_rows)
+        if conf.trace_enabled:
+            trace.on_batch(op, rows)
+        if history is not None:
+            history.observe_rows(op, rows)
+        if progress is not None:
+            progress.on_batch(op, rows)
+        op.metrics.add("output_batches", 1)
+        op.metrics.add("output_rows", rows)
+        if stats:
+            op.metrics.add("stat_bytes", batch_nbytes(batch))
+            op.metrics.set_max("stat_max_batch_rows", rows)
+
+    return note
+
+
+def count_stream(op: Operator, stream: BatchStream) -> BatchStream:
+    """Wrap a stream updating the operator's baseline metrics, trace,
+    history and progress taps and its fault point (`batch_tap`)."""
+    note = batch_tap(op)
     try:
         for batch in stream:
-            if conf.fault_injection_spec:
-                faults.inject(fault_point)
-            rows = int(batch.num_rows)
-            if conf.trace_enabled:
-                trace.on_batch(op, rows)
-            if history is not None:
-                history.observe_rows(op, rows)
-            if progress is not None:
-                progress.on_batch(op, rows)
-            op.metrics.add("output_batches", 1)
-            op.metrics.add("output_rows", rows)
-            if stats:
-                op.metrics.add("stat_bytes", batch_nbytes(batch))
-                op.metrics.set_max("stat_max_batch_rows", rows)
+            note(batch)
             yield batch
     finally:
         # deterministic teardown: when the consumer abandons the stream

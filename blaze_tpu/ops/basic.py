@@ -107,16 +107,29 @@ class FilterExec(MapLikeOp):
     def jit_safe(self) -> bool:
         return not any(ir.contains_host_fn(p) for p in self.predicates)
 
-    def make_batch_fn(self) -> Callable[[ColumnBatch], ColumnBatch]:
+    def make_keep_fn(self) -> Callable[[ColumnBatch], jnp.ndarray]:
+        """The rows the predicates keep, as one boolean plane over the
+        batch's slots (true AND valid, ANDed over the predicates; padding
+        slots are the caller's to mask). `make_batch_fn` compacts by it; a
+        partial aggregate fed directly by this filter carries it into its
+        collapse instead (ops/agg)."""
         fns = self._fns
 
-        def run(batch: ColumnBatch) -> ColumnBatch:
+        def keep_of(batch: ColumnBatch):
             keep = None
             for fn in fns:
                 c = fn(batch)
                 m = c.data.astype(jnp.bool_) & c.valid_mask()
                 keep = m if keep is None else (keep & m)
-            return batch.compact(keep)
+            return keep
+
+        return keep_of
+
+    def make_batch_fn(self) -> Callable[[ColumnBatch], ColumnBatch]:
+        keep_of = self.make_keep_fn()
+
+        def run(batch: ColumnBatch) -> ColumnBatch:
+            return batch.compact(keep_of(batch))
 
         return run
 

@@ -126,14 +126,16 @@ def encode_column(col: Column, asc: bool, nulls_first: bool,
 
 def batch_sort_keys(batch: ColumnBatch, specs: Sequence[SortSpec],
                     max_string_words: int = DEFAULT_MAX_STRING_WORDS,
-                    ) -> List[Array]:
+                    live: Optional[Array] = None) -> List[Array]:
     """All key arrays for a multi-column sort, padding rows last.
 
     The leading liveness key forces padding rows (>= num_rows) to the end
     regardless of direction/null flags, so sorted outputs stay front-compact.
+    `live` names the live rows where they are not the first `num_rows`
+    slots (a filter's mask that nobody compacted: `sort_batch`).
     """
     with jax.named_scope("sort.encode_keys"):
-        mask = batch.row_mask()
+        mask = batch.row_mask() if live is None else live
         keys: List[Array] = [jnp.where(mask, jnp.uint8(0), jnp.uint8(1))]
         for spec in specs:
             keys.extend(encode_column(batch.columns[spec.col], spec.asc,
@@ -144,10 +146,19 @@ def batch_sort_keys(batch: ColumnBatch, specs: Sequence[SortSpec],
 
 def sort_batch(batch: ColumnBatch, specs: Sequence[SortSpec],
                max_string_words: int = DEFAULT_MAX_STRING_WORDS,
-               ) -> ColumnBatch:
-    """Reorder all rows by the sort specs (jit-safe, shape-preserving)."""
-    keys = batch_sort_keys(batch, specs, max_string_words)
-    return permute_by_keys(batch, keys)
+               live: Optional[Array] = None) -> ColumnBatch:
+    """Reorder all rows by the sort specs (jit-safe, shape-preserving).
+
+    `live` (a boolean plane, already ANDed with the batch's row mask) says
+    which slots hold rows when they are scattered among dead ones: the sort
+    sends the dead behind the live as it sends padding, stably, so the
+    output is front-compact with `num_rows` = the live count and the sort
+    has done a compaction's work on the way."""
+    keys = batch_sort_keys(batch, specs, max_string_words, live)
+    out = permute_by_keys(batch, keys)
+    if live is None:
+        return out
+    return out.with_num_rows(jnp.sum(live, dtype=jnp.int32))
 
 
 def permute_by_keys(batch: ColumnBatch, keys: List[Array]) -> ColumnBatch:

@@ -36,7 +36,8 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
   compile_count / compile_ns / cache_hits / cache_misses /
   canonicalization_waste_rows / stage_attempts / stage_compiled /
   agg_pallas_traces / agg_xla_traces / seg_scan_reductions /
-  seg_scatter_reductions and the derived whole_stage_coverage_pct,
+  seg_scatter_reductions / filter_masks_carried / filter_compactions and
+  the derived whole_stage_coverage_pct,
   exported as an extra `MetricNode` child by `executor.metric_tree` and
   as a summary line by `tracing.metric_report`.
 """
@@ -69,6 +70,7 @@ _COUNTERS = (
     "agg_pallas_traces", "agg_xla_traces",
     "seg_scan_reductions", "seg_scatter_reductions",
     "seg_sums", "seg_int_sums",
+    "filter_masks_carried", "filter_compactions",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -123,6 +125,16 @@ def note_seg_reductions(scan: int = 0, scatter: int = 0, sums: int = 0,
     TELEMETRY.add("seg_scatter_reductions", scatter)
     TELEMETRY.add("seg_sums", sums)
     TELEMETRY.add("seg_int_sums", int_sums)
+
+
+def note_filter_batches(carried: int = 0, compacted: int = 0) -> None:
+    """A FilterExec's verdict on one batch was dispatched: `compacted` by
+    the filter's own program (`fused.filter…`, which moves every kept row
+    of every plane to its rank), `carried` as a mask inside the work batch
+    of the partial aggregate it feeds, whose collapse sorts the dropped
+    rows behind the kept ones and moves nothing twice (ops/agg)."""
+    TELEMETRY.add("filter_masks_carried", carried)
+    TELEMETRY.add("filter_compactions", compacted)
 
 
 def telemetry_summary() -> str:

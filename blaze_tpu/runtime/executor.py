@@ -22,7 +22,10 @@ from blaze_tpu.ops.base import (
     BatchStream, ExecContext, MapLikeOp, Operator, add_compute_split,
     count_stream,
 )
-from blaze_tpu.runtime import faults, jit_cache, monitor, trace
+from blaze_tpu.ops.basic import FilterExec
+from blaze_tpu.runtime import (
+    compile_service, faults, jit_cache, monitor, trace,
+)
 from blaze_tpu.runtime.metrics import MetricNode
 
 
@@ -265,6 +268,8 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
     # execution order, from the plan's structure only
     names = [c.label() for c in chain]
     program = ".".join(["fused"] + names)
+    # each filter of the chain compacts every batch the chain is handed
+    compactions = sum(isinstance(c, FilterExec) for c in chain)
 
     def make():
         from blaze_tpu.exprs.compiler import cse_scope
@@ -293,6 +298,8 @@ def execute_fused(op: MapLikeOp, ctx: ExecContext) -> BatchStream:
                 out = fused(batch)
             batch_ns = time.perf_counter_ns() - t0
             add_compute_split(op, batch_ns, device=jit)
+            if compactions:
+                compile_service.note_filter_batches(compacted=compactions)
             if conf.monitor_enabled:
                 # unjitted chains (host kernels: digests/JSON/UDF) bill
                 # host_compute; a fused jit dispatch bills fused_dispatch:
@@ -445,8 +452,6 @@ _pack_pins: dict = {}
 
 
 def metric_tree(root: Operator) -> MetricNode:
-    from blaze_tpu.runtime import compile_service
-
     node = MetricNode.from_operator(root)
     # process-global compile + resilience counters ride along as extra
     # children (no handler of their own: embedders that only set the root

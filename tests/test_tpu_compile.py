@@ -88,3 +88,46 @@ def test_integer_compaction_compiles_for_v5e_without_a_gather(one_chip):
     assert scatters and all(
         line.split(" scatter(")[0].count("[2097152]") == 1
         for line in scatters), scatters[:2]
+
+
+def test_the_masked_work_program_compiles_for_v5e_without_moving_a_row(
+        one_chip):
+    """q06-core's filter, carried by its partial aggregate (ops/agg
+    `_mask_filter`): the program that builds the work batch at 2^21 rows
+    evaluates the predicate beside the planes it passes on, and holds no
+    gather, no scatter and no sort: the double planes the filter's own
+    program gathered (41-55 ms each on the chip) are not moved before the
+    collapse's sort moves them once."""
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column, ColumnBatch
+    from blaze_tpu.exprs import ir
+    from blaze_tpu.ops.agg import KEEP_PLANE, AggCall, AggExec, AggMode
+    from blaze_tpu.ops.basic import FilterExec, MemorySourceExec
+
+    cap = 1 << 21
+
+    def arg(dtype, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    schema = T.Schema([T.Field("ss_item_sk", T.INT64),
+                       T.Field("ss_sales_price", T.FLOAT64),
+                       T.Field("ss_ext_sales_price", T.FLOAT64)])
+    batch = ColumnBatch(schema, [
+        Column(T.INT64, arg(jnp.int64), None),
+        Column(T.FLOAT64, arg(jnp.float64), arg(jnp.bool_)),
+        Column(T.FLOAT64, arg(jnp.float64), arg(jnp.bool_))],
+        arg(jnp.int32, ()), cap)
+    filt = FilterExec(MemorySourceExec([], schema), [ir.Binary(
+        ir.BinOp.GT, ir.col("ss_ext_sales_price"), ir.lit(100.0))])
+    partial = AggExec(filt, [ir.col("ss_item_sk")], ["item"], [
+        AggCall("sum", (ir.col("ss_ext_sales_price"),), T.FLOAT64, "total"),
+        AggCall("count", (ir.col("ss_ext_sales_price"),), T.INT64, "cnt"),
+        AggCall("avg", (ir.col("ss_sales_price"),), T.FLOAT64, "avg_price"),
+    ], AggMode.PARTIAL)
+    assert partial._mask_filter() is filt
+    lowered = jax.jit(partial._work_fn(filt)).lower(batch)
+    work, kept = lowered.out_info
+    assert work.schema.names()[-1] == KEEP_PLANE and kept.shape == ()
+    text = lowered.compile().as_text()
+    for moved in (" gather(", " scatter(", " sort("):
+        assert moved not in text, moved
