@@ -98,6 +98,11 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     lock step. Only the calling (driver) thread launches the collective
     program; task threads launch single-device programs only.
 
+    `stats` receives what the stage did: `bytes` (live-row-scaled),
+    `devices`, `host_bytes`, and what it holds for the reduce side:
+    `pinned_bytes` on the fullest chip, `slices` and `slice_rows` kept in
+    HBM, `file_batches` that left it.
+
     Returns False — with nothing registered, nothing executed — only when
     the stage can't ride the mesh at all (shape/keys/partition count).
     """
@@ -434,5 +439,12 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                     for b, n in parts)
         total += sum(os.path.getsize(d) for d, _ in file_outputs)
         stats["bytes"] = int(total)
+        # nothing is released inside a stage, so the fullest chip's pinned
+        # bytes at its end are the stage's high-water
+        stats["pinned_bytes"] = max(pinned)
+        stats["slices"] = sum(len(parts) for parts in recv_parts)
+        stats["slice_rows"] = sum(n for parts in recv_parts
+                                  for _, n in parts)
+        stats["file_batches"] = len(file_outputs)
     resources.put(f"{namespace}shuffle:{stage_id}", provider)
     return True

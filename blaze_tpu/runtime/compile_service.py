@@ -71,6 +71,7 @@ _COUNTERS = (
     "seg_scan_reductions", "seg_scatter_reductions",
     "seg_sums", "seg_int_sums",
     "filter_masks_carried", "filter_compactions",
+    "exchange_slices_kept", "exchange_rows_kept",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -135,6 +136,15 @@ def note_filter_batches(carried: int = 0, compacted: int = 0) -> None:
     rows behind the kept ones and moves nothing twice (ops/agg)."""
     TELEMETRY.add("filter_masks_carried", carried)
     TELEMETRY.add("filter_compactions", compacted)
+
+
+def note_exchange_kept(slices: int, rows: int) -> None:
+    """A shuffle_map stage's in-HBM exchange (parallel/stage_exchange, local
+    and mesh transports alike) kept `slices` non-empty per-partition slices
+    holding `rows` live rows for its reduce tasks: rows / slices is what one
+    program of a reduce task is handed."""
+    TELEMETRY.add("exchange_slices_kept", slices)
+    TELEMETRY.add("exchange_rows_kept", rows)
 
 
 def telemetry_summary() -> str:

@@ -31,7 +31,7 @@ from blaze_tpu.plan import decode_plan, fingerprint_plan
 from blaze_tpu.plan import plan_pb2 as pb
 from blaze_tpu.plan.fingerprint import fingerprint_query
 from blaze_tpu.runtime import artifacts, faults, history, journal, monitor
-from blaze_tpu.runtime import placement, resources, trace
+from blaze_tpu.runtime import compile_service, placement, resources, trace
 from blaze_tpu.runtime import supervisor as supervisor_mod
 from blaze_tpu.runtime.executor import execute_plan, run_task_with_resilience
 from blaze_tpu.runtime.supervisor import Supervisor, TaskSpec
@@ -64,7 +64,11 @@ def run_plan(root: SparkPlan, num_partitions: int = 4,
     no overflow possible).
 
     run_info: optional dict populated with execution-path counters
-    ("mesh_stages", "file_stages", "broadcast_stages", "mesh_devices" —
+    ("mesh_stages", "file_stages" — a stage that went through files whole,
+    or an in-HBM stage any of whose batches did, past the memory budget or
+    the quota: such a stage counts in "mesh_stages" as well, so the two may
+    sum to more than the plan's shuffle stages —, "broadcast_stages",
+    "mesh_devices" —
     the fewest devices any mesh exchange's output sat on — and
     "mesh_host_bytes" — bytes of mesh-exchanged partitions that crossed
     the host on their way to the consuming task, 0 where each is consumed
@@ -418,8 +422,16 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                                 run_info.get("mesh_devices", ndev), ndev)
                             if ndev > 1:
                                 resident.add(stage.stage_id)
+                            if stats["file_batches"]:
+                                # past the budget or the quota a batch
+                                # left HBM through files: the stage is a
+                                # file stage too
+                                run_info["file_stages"] += 1
+                            compile_service.note_exchange_kept(
+                                stats["slices"], stats["slice_rows"])
                             sp.set(transport="mesh",
                                    bytes=stats.get("bytes", 0),
+                                   pinned_bytes=stats["pinned_bytes"],
                                    **monitor.stage_span_attrs(
                                        run_info["query_id"],
                                        stage.stage_id))

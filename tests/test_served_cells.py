@@ -42,7 +42,7 @@ ONE_CHIP = {w["name"]: w for w in _json(REPO, "BENCHMARK.json")["workloads"]
 def test_the_manifest_has_the_one_chip_cells_this_file_names():
     assert set(ONE_CHIP) == {"sf10_q03_bhj", "sf1_q06core_agg",
                              "sf1_q03_nobhj", "sf10_q06core_agg",
-                             "sf1_q06core_agg_dec"}
+                             "sf1_q06core_agg_dec", "sf10_q03_nobhj"}
 
 
 @pytest.fixture(scope="module")
@@ -93,7 +93,8 @@ def one_chip(monkeypatch):
 
 
 @pytest.mark.parametrize("name", ["sf10_q03_bhj", "sf1_q06core_agg",
-                                  "sf10_q06core_agg", "sf1_q06core_agg_dec"])
+                                  "sf10_q06core_agg", "sf1_q06core_agg_dec",
+                                  "sf10_q03_nobhj"])
 def test_cell_equals_its_reference_and_nothing_is_refused(
         cells, one_chip, name):
     wrong, refused = cells(name)()
