@@ -332,6 +332,36 @@ class Column:
             v = index_valid if v is None else (v & index_valid)
         return Column(self.dtype, data, v)
 
+    @property
+    def row_aligned(self) -> bool:
+        """Every plane holds row r at index r: true of all storage but a
+        list's elements (its children's, where a struct holds one)."""
+        if self.is_struct:
+            return all(ch.row_aligned for ch in self.data.children)
+        return not self.is_list
+
+    def slice_rows(self, start: Array, cap: int) -> "Column":
+        """Rows [start, start + cap) as a column of capacity `cap`: a
+        contiguous copy of every plane (`_row_range`), where `take` of the
+        same range is a gather by computed index. `start` is a traced
+        int32 in [0, capacity]; the column must be `row_aligned`."""
+        v = self.validity
+        if self.is_struct:
+            data = StructData([ch.slice_rows(start, cap)
+                               for ch in self.data.children])
+        elif self.is_dict:
+            # the codes alone, as in `take`: the dictionary stays shared
+            data = DictData(_row_range(self.data.codes, start, cap),
+                            self.data.dict_bytes, self.data.dict_lengths)
+        elif self.is_string:
+            data = StringData(_row_range(self.data.bytes, start, cap),
+                              _row_range(self.data.lengths, start, cap))
+        else:
+            assert not self.is_list, "a list's elements are not row-aligned"
+            data = _row_range(self.data, start, cap)
+        return Column(self.dtype, data,
+                      None if v is None else _row_range(v, start, cap))
+
     def tree_flatten(self):
         return (self.data, self.validity), self.dtype
 
@@ -414,6 +444,23 @@ class ColumnBatch:
         cols = [c.take(indices, index_valid=index_valid) for c in self.columns]
         cap = int(indices.shape[0])
         return ColumnBatch(self.schema, cols, jnp.asarray(num_rows, jnp.int32), cap)
+
+    @property
+    def row_aligned(self) -> bool:
+        return all(c.row_aligned for c in self.columns)
+
+    def slice_rows(self, start, cap: int, num_rows) -> "ColumnBatch":
+        """Rows [start, start + cap) as a batch of static capacity `cap`
+        holding `num_rows` live rows, cut out by contiguous copies: what
+        `take(arange(cap) + start, num_rows)` gathers row by row. `start`
+        may be traced, and `start + cap` may pass the capacity: the window
+        is never moved back over rows before `start`, and slots past the
+        input's last are, like all past `num_rows`, nobody's to read.
+        Only for a `row_aligned` batch (`ops.common.slice_batch` asks)."""
+        start = jnp.asarray(start, jnp.int32)
+        cols = [c.slice_rows(start, cap) for c in self.columns]
+        return ColumnBatch(self.schema, cols,
+                           jnp.asarray(num_rows, jnp.int32), cap)
 
     def compact(self, keep: Array) -> "ColumnBatch":
         """Filter: keep rows where `keep & row_mask`, compacted to the front.
@@ -567,6 +614,15 @@ def _col_shape_key(c: Column) -> tuple:
     if c.is_string:
         return ("s", c.data.width, c.validity is not None)
     return (str(c.data.dtype), c.validity is not None)
+
+
+def _row_range(x: Array, start: Array, cap: int) -> Array:
+    """`x[start : start + cap]` along the row axis for a traced `start` in
+    [0, len(x)]. `lax.dynamic_slice` alone clamps a window that passes the
+    end back over the rows before `start`; over the plane padded by `cap`
+    it never has to, and XLA:TPU compiles the pair to copies."""
+    pad = [(0, cap)] + [(0, 0)] * (x.ndim - 1)
+    return jax.lax.dynamic_slice_in_dim(jnp.pad(x, pad), start, cap, axis=0)
 
 
 def _list_take(ld: ListData, idx: Array) -> ListData:

@@ -227,26 +227,36 @@ def _concat_list_columns(parts, idx, field, cap):
     return Column(field.dtype, ListData(new_off, elems), validity)
 
 
-def slice_batch(batch: ColumnBatch, start: int, count: int) -> ColumnBatch:
-    """Slice of live rows [start, start+count) into a fresh batch.
+def slice_batch(batch: ColumnBatch, start: int, count: int,
+                cap: Optional[int] = None) -> ColumnBatch:
+    """Slice of live rows [start, start+count) into a fresh batch of
+    capacity `cap` (the rows' own bucket unless given).
 
     Jitted per (schema, input shape, output bucket) with start/count
     traced — per-partition slicing in the exchange paths calls this with
     many different offsets and must not compile (or eagerly dispatch) per
-    column per call."""
-    cap = bucket_capacity(count)
-    from blaze_tpu.runtime import jit_cache
+    column per call.
 
+    The rows lie next to each other, so every plane that holds row r at
+    index r is cut out by a contiguous copy (`ColumnBatch.slice_rows`); a
+    batch with a list column, whose elements lie elsewhere, is gathered
+    by `take`. The batch's own columns decide, nothing else."""
+    cap = bucket_capacity(count) if cap is None else cap
+    from blaze_tpu.runtime import compile_service, jit_cache
+
+    copies = batch.row_aligned
+    compile_service.note_slice(copies)
     key = ("slice", cap, tuple(batch.schema.fields), batch.shape_key())
 
     def make():
         def run(b, start, count):
-            idx = jnp.arange(cap, dtype=jnp.int64) + start
-            return b.take(
-                jnp.clip(idx, 0, b.capacity - 1),
-                jnp.minimum(jnp.maximum(b.num_rows - start, 0), count))
+            rows = jnp.minimum(jnp.maximum(b.num_rows - start, 0), count)
+            if copies:
+                return b.slice_rows(start, cap, rows)
+            # `take` clips the indices that pass the capacity
+            return b.take(jnp.arange(cap, dtype=jnp.int32) + start, rows)
 
         return run
 
     return jit_cache.get_or_compile(key, make)(
-        batch, jnp.asarray(start, jnp.int64), jnp.asarray(count, jnp.int32))
+        batch, jnp.asarray(start, jnp.int32), jnp.asarray(count, jnp.int32))

@@ -200,8 +200,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         with placement.on_device(placement.device_of(batch.columns)):
             for i, dev in enumerate(mesh_devs):
                 rows = min(max(n - i * per, 0), per)
-                cut = batch.take(
-                    jnp.arange(cap, dtype=jnp.int32) + i * per, rows)
+                cut = slice_batch(batch, i * per, rows, cap)
                 shards.append((jax.device_put(cut, dev), rows))
         return shards
 
@@ -214,9 +213,8 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
 
         def make():
             def run(x):
-                idx = jnp.minimum(jnp.arange(cap, dtype=jnp.int32),
-                                  x.capacity - 1)
-                return x.take(idx, x.num_rows)
+                # no nested column rides the mesh: every plane is copied
+                return x.slice_rows(0, cap, x.num_rows)
 
             return run
 

@@ -36,7 +36,8 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
   compile_count / compile_ns / cache_hits / cache_misses /
   canonicalization_waste_rows / stage_attempts / stage_compiled /
   agg_pallas_traces / agg_xla_traces / seg_scan_reductions /
-  seg_scatter_reductions / filter_masks_carried / filter_compactions and
+  seg_scatter_reductions / filter_masks_carried / filter_compactions /
+  slice_copies / slice_gathers and
   the derived whole_stage_coverage_pct,
   exported as an extra `MetricNode` child by `executor.metric_tree` and
   as a summary line by `tracing.metric_report`.
@@ -72,6 +73,7 @@ _COUNTERS = (
     "seg_sums", "seg_int_sums",
     "filter_masks_carried", "filter_compactions",
     "exchange_slices_kept", "exchange_rows_kept",
+    "slice_copies", "slice_gathers",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -145,6 +147,13 @@ def note_exchange_kept(slices: int, rows: int) -> None:
     program of a reduce task is handed."""
     TELEMETRY.add("exchange_slices_kept", slices)
     TELEMETRY.add("exchange_rows_kept", rows)
+
+
+def note_slice(copies: bool) -> None:
+    """One call of `ops.common.slice_batch`: the rows were cut out by
+    contiguous copies of every plane (`ColumnBatch.slice_rows`), or, for
+    a batch with a list column, gathered by `take`."""
+    TELEMETRY.add("slice_copies" if copies else "slice_gathers", 1)
 
 
 def telemetry_summary() -> str:

@@ -25,6 +25,34 @@ def rng():
     return np.random.default_rng(42)
 
 
+# Every program jax compiles for the CPU backend stays mapped (three mappings
+# an executable, one set a virtual device) for as long as its jitted function
+# caches it, and a process may hold vm.max_map_count (65,530) mappings: past
+# it jaxlib segfaults inside a compile. The worker that runs test_tpcds_smj.py
+# in a whole six-worker run was read at 59,618 (the parent of PR 35) and
+# 64,745 mappings near the file's end, and two of five such runs lost that
+# worker there. So a worker past _MAP_BUDGET drops jax's caches between two
+# tests: what is still needed compiles again.
+_MAP_BUDGET = 40_000
+
+
+@pytest.fixture(autouse=True)
+def _bounded_mappings():
+    yield
+    try:
+        with open("/proc/self/maps") as fh:
+            mapped = sum(1 for _ in fh)
+    except OSError:   # no procfs: nothing to read, nothing to bound
+        return
+    if mapped > _MAP_BUDGET:
+        import gc
+
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
+
+
 # xdist's loadfile hands files to its workers in collection order, so a long
 # file late in the alphabet (test_tpcds_smj.py: minutes) ends alone on one
 # worker after the others have drained; the longest files go out first.

@@ -242,7 +242,10 @@ def test_pool_sigkill_recovery_and_dossier(fast_death_conf, tmp_path,
         assert st["tasks_done"] == 4  # displaced attempts count ONCE
         # seat respawned: capacity dipped to 2 then recovered to 4
         deadline = time.monotonic() + 20
-        while pool.live_count() < 2 and time.monotonic() < deadline:
+        # the membership callback runs on the respawning thread, after
+        # the seat counts as live: wait for its report too
+        while ((pool.live_count() < 2 or caps[-1:] != [4])
+               and time.monotonic() < deadline):
             time.sleep(0.05)
         assert pool.live_count() == 2 and pool.capacity() == 4
         assert 2 in caps and caps[-1] == 4
