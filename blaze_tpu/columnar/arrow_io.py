@@ -18,6 +18,7 @@ import pyarrow as pa
 
 from blaze_tpu.columnar.batch import (
     Column, ColumnBatch, StringData, bucket_capacity, bucket_width, _pad_validity,
+    pull_rows,
 )
 from blaze_tpu.columnar import types as T
 
@@ -239,7 +240,7 @@ def batch_from_arrow(rb: pa.RecordBatch, capacity: Optional[int] = None,
 
 
 def batch_to_arrow(batch: ColumnBatch) -> pa.RecordBatch:
-    n = int(batch.num_rows)
+    n = pull_rows(batch, "d2h.arrow_rows")
     arrays: List[pa.Array] = []
     for f, c in zip(batch.schema, batch.columns):
         valid = np.asarray(c.valid_mask())[:n]

@@ -45,6 +45,47 @@ def bucket_capacity(n: int) -> int:
     return cap
 
 
+# -- the control pulls' seam ---------------------------------------------------
+# Between two device programs the driver reads a small value back to decide
+# what to run next: a batch's live rows, an exchange's bounds or counts, a
+# join's pair total. Every such read goes through `pull_rows` / `pull_array`,
+# so that with tracing on each is one `wait` span (runtime/trace.SPAN_KINDS)
+# named by its `site`: a static string, `<layer>.<purpose>`, listed in PERF.md
+# section 3. Off, the cost is the call and one truthiness check.
+
+
+def pull_rows(batch: "ColumnBatch", site: str) -> int:
+    """`batch`'s live rows as a Python int. The host blocks here until the
+    device has made `num_rows` and the value has crossed."""
+    if conf.trace_enabled:
+        return _waited(batch.num_rows, site, int)
+    return int(batch.num_rows)
+
+
+def pull_array(x, site: str) -> np.ndarray:
+    """A small device array (bounds, counts, a total) as numpy; blocks like
+    `pull_rows`."""
+    if conf.trace_enabled:
+        return _waited(x, site, np.asarray)
+    return np.asarray(x)
+
+
+def _waited(x, site: str, pull):
+    """`pull(x)` inside a `wait` span. `ready` is read just before the
+    pull: True, the device had finished when the host asked and the span
+    is the round trip alone; False, the host got there first and stands
+    still for the device's work as well. A value that is a host number
+    already (or a tracer, whose pull raises as it always did) opens no
+    span."""
+    is_ready = getattr(x, "is_ready", None)
+    if is_ready is None:
+        return pull(x)
+    from blaze_tpu.runtime import trace
+
+    with trace.span("wait", site=site, ready=bool(is_ready())):
+        return pull(x)
+
+
 def nonzero_i32(mask: Array, size: int, fill_value: int = 0) -> Array:
     """`jnp.nonzero(mask, size=size, fill_value=fill_value)[0]` in 32-bit
     arithmetic: int32 (size,) positions of the True entries in order,
@@ -526,7 +567,7 @@ class ColumnBatch:
         return out
 
     def _pull_numpy(self) -> Dict[str, object]:
-        n = int(self.num_rows)
+        n = pull_rows(self, "d2h.numpy_rows")
         out: Dict[str, object] = {}
         for f, c in zip(self.schema, self.columns):
             valid = np.asarray(c.valid_mask())[:n]

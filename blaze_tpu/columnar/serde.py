@@ -42,7 +42,9 @@ from typing import BinaryIO, Iterator, List, Optional
 import numpy as np
 import zstandard
 
-from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    ColumnBatch, bucket_capacity, pull_rows,
+)
 from blaze_tpu.columnar.types import Schema, TypeKind
 from blaze_tpu.config import conf
 from blaze_tpu.runtime import faults, monitor, trace
@@ -239,7 +241,7 @@ def to_host(batch: ColumnBatch) -> HostBatch:
     # the d2h span starts before the row-count pull: that pull is where
     # the host waits for the device to finish the batch
     with trace.span("d2h", what="to_host") as sp:
-        n = int(batch.num_rows)
+        n = pull_rows(batch, "d2h.rows")
         hb = HostBatch(batch.schema,
                        [_host_col(c, n) for c in batch.columns], n)
         if conf.monitor_enabled or conf.trace_enabled:

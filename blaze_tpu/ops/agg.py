@@ -32,16 +32,14 @@ import numpy as np
 
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.batch import (
-    Column, ColumnBatch, bucket_capacity, nonzero_i32,
+    Column, ColumnBatch, bucket_capacity, nonzero_i32, pull_array, pull_rows,
 )
 from blaze_tpu.columnar.types import DataType, Field, Schema, TypeKind
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
 from blaze_tpu.ops import segment as seg
-from blaze_tpu.ops.base import (
-    BatchStream, ExecContext, Operator, batch_tap, count_stream,
-)
+from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, batch_tap
 from blaze_tpu.ops.basic import FilterExec
 from blaze_tpu.ops.common import concat_batches
 from blaze_tpu.ops.sort import truncate
@@ -316,7 +314,7 @@ class _AggState:
         freed = self.state_bytes
         sf = self._M.SpillFile(self.op._state_schema, manager=self.manager)
         for s in self.states:
-            sf.write(truncate(s, max(int(s.num_rows), 1)))
+            sf.write(truncate(s, max(pull_rows(s, "agg.state_rows"), 1)))
         self.spills.append(sf)
         self.spill_files_used += 1
         self.states, self.state_bytes = [], 0
@@ -351,7 +349,8 @@ class _AggState:
         # op_lock: serialize against host-driven release() (bn_spill)
         with self.manager.op_lock:
             self.raw.append(work)
-            self.raw_rows += int(work.num_rows) if rows is None else rows
+            self.raw_rows += (pull_rows(work, "agg.raw_rows")
+                              if rows is None else rows)
             self.raw_bytes += self._M.batch_nbytes(work)
             if self.raw_rows >= self.op.collapse_threshold:
                 self._collapse_all()
@@ -464,6 +463,7 @@ class AggExec(Operator):
             state = _AggState(self, manager)
             seen = False
             filt = self._mask_filter()
+            note = batch_tap(self)  # fed here: the output's rows are pulled here
             try:
                 # (batch, None), or (work batch, rows kept) where the
                 # child is a filter whose mask the collapse carries
@@ -472,7 +472,8 @@ class AggExec(Operator):
                                 for b in self.children[0].execute(ctx)))
                 for batch, kept in inputs:
                     ctx.check_running()
-                    if (int(batch.num_rows) if kept is None else kept) == 0:
+                    if (pull_rows(batch, "agg.input_rows") if kept is None
+                            else kept) == 0:
                         continue
                     seen = True
                     with self.metrics.timer():
@@ -484,7 +485,9 @@ class AggExec(Operator):
                             state.add_raw(batch, kept)
                 if not seen:
                     if not self.group_exprs:
-                        yield self._empty_global_result()
+                        out = self._empty_global_result()
+                        note(out)
+                        yield out
                     return
                 with self.metrics.timer():
                     merged = state.merged()
@@ -494,12 +497,14 @@ class AggExec(Operator):
                         out = merged
                 self.metrics.add("collapses", state.collapses)
                 self.metrics.add("spill_count", state.spill_files_used)
-                out = truncate(out, max(int(out.num_rows), 1))
+                rows = pull_rows(out, "agg.out_rows")
+                out = truncate(out, max(rows, 1))
+                note(out, rows)
                 yield out
             finally:
                 state.close()
 
-        return count_stream(self, gen())
+        return gen()
 
     def _mask_filter(self):
         """The FilterExec whose mask this aggregate carries into its
@@ -525,7 +530,7 @@ class AggExec(Operator):
             with self.metrics.timer():
                 work, kept = self._to_work(batch, filt)
                 compile_service.note_filter_batches(carried=1)
-                kept = int(kept)
+                kept = int(pull_array(kept, "agg.kept_rows"))
             note_filter(batch, kept)
             yield work, kept
 

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Iterator, List, Optional
 
-from blaze_tpu.columnar.batch import ColumnBatch
+from blaze_tpu.columnar.batch import ColumnBatch, pull_rows
 from blaze_tpu.columnar.types import Schema
 from blaze_tpu.runtime.metrics import MetricsSet
 
@@ -141,7 +141,10 @@ def batch_tap(op: Operator) -> Callable[..., None]:
     `count_stream` calls it for every batch of a stream. An operator whose
     consumer ran its work for it (a FilterExec whose mask a partial
     aggregate carried, ops/agg) has no output stream to wrap: the consumer
-    calls it with the count the operator would have produced.
+    calls it with the count the operator would have produced. An operator
+    whose generator has just pulled an output batch's rows (a join dropping
+    an empty batch) calls `note(batch, rows)` before each yield instead of
+    being wrapped, so the batch is pulled once: streams hold batches only.
 
     With `conf.enable_input_batch_statistics` (the reference's
     batch_statisitcs module: per-exec input-batch stat metrics behind
@@ -180,7 +183,7 @@ def batch_tap(op: Operator) -> Callable[..., None]:
         if conf.fault_injection_spec:
             faults.inject(fault_point)
         if rows is None:
-            rows = int(batch.num_rows)
+            rows = pull_rows(batch, "op.output_rows")
         if conf.trace_enabled:
             trace.on_batch(op, rows)
         if history is not None:

@@ -15,12 +15,14 @@ from typing import Callable, List, Optional, Sequence
 
 import jax.numpy as jnp
 
-from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity, pull_rows
 from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
-from blaze_tpu.ops.base import BatchStream, ExecContext, MapLikeOp, Operator, count_stream
+from blaze_tpu.ops.base import (
+    BatchStream, ExecContext, MapLikeOp, Operator, batch_tap, count_stream,
+)
 from blaze_tpu.ops.common import concat_batches
 
 logger = logging.getLogger(__name__)
@@ -175,19 +177,23 @@ class LocalLimitExec(Operator):
 
     def execute(self, ctx: ExecContext) -> BatchStream:
         def gen():
+            note = batch_tap(self)  # fed here: a passed batch's rows are known
             remaining = self.limit
             for batch in self.children[0].execute(ctx):
                 if remaining <= 0:
                     break
-                n = int(batch.num_rows)
+                n = pull_rows(batch, "limit.input_rows")
                 if n <= remaining:
                     remaining -= n
+                    note(batch, n)
                     yield batch
                 else:
-                    yield batch.with_num_rows(remaining)
+                    batch = batch.with_num_rows(remaining)
+                    note(batch)
+                    yield batch
                     remaining = 0
 
-        return count_stream(self, gen())
+        return gen()
 
 
 class GlobalLimitExec(LocalLimitExec):
@@ -253,10 +259,11 @@ class CoalesceBatchesExec(Operator):
         target = self.batch_size or ctx.batch_size or conf.batch_size
 
         def gen():
+            note = batch_tap(self)  # fed here: a passed batch's rows are known
             pending: List[ColumnBatch] = []
             pending_rows = 0
             for batch in self.children[0].execute(ctx):
-                n = int(batch.num_rows)
+                n = pull_rows(batch, "coalesce.input_rows")
                 if n == 0:
                     continue
                 staged = False
@@ -265,14 +272,19 @@ class CoalesceBatchesExec(Operator):
                     pending_rows += n
                     staged = True
                 if pending_rows >= target:
-                    yield concat_batches(pending, self.schema)
+                    out = concat_batches(pending, self.schema)
+                    note(out)
+                    yield out
                     pending, pending_rows = [], 0
                 if not staged:
+                    note(batch, n)
                     yield batch
             if pending:
-                yield concat_batches(pending, self.schema)
+                out = concat_batches(pending, self.schema)
+                note(out)
+                yield out
 
-        return count_stream(self, gen())
+        return gen()
 
 
 class DebugExec(Operator):
@@ -290,7 +302,7 @@ class DebugExec(Operator):
         def gen():
             for i, batch in enumerate(self.children[0].execute(ctx)):
                 logger.info("[DEBUG %s] batch %d: %d rows\n%s", self.tag, i,
-                            int(batch.num_rows), batch.to_numpy())
+                            pull_rows(batch, "debug.rows"), batch.to_numpy())
                 yield batch
 
         return count_stream(self, gen())

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from blaze_tpu.columnar.batch import (
-    Column, ColumnBatch, StringData, bucket_capacity,
+    Column, ColumnBatch, StringData, bucket_capacity, pull_rows,
 )
 from blaze_tpu.columnar.types import Schema, TypeKind
 from blaze_tpu.exprs import strings as S
@@ -83,7 +83,7 @@ def concat_batches(batches: List[ColumnBatch], schema: Optional[Schema] = None,
     materializes memory tables)."""
     assert batches, "concat_batches needs at least one batch"
     schema = schema or batches[0].schema
-    counts = [int(b.num_rows) for b in batches]
+    counts = [pull_rows(b, "concat.rows") for b in batches]
     total = sum(counts)
     cap = capacity or bucket_capacity(total)
 

@@ -17,12 +17,14 @@ import jax.numpy as jnp
 
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.batch import (
-    Column, ColumnBatch, ListData, bucket_capacity,
+    Column, ColumnBatch, ListData, bucket_capacity, pull_array, pull_rows,
 )
 from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
-from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
+from blaze_tpu.ops.base import (
+    BatchStream, ExecContext, Operator, batch_tap, count_stream,
+)
 from blaze_tpu.runtime import jit_cache
 
 Array = jax.Array
@@ -128,15 +130,18 @@ class GenerateExec(Operator):
 
     def execute(self, ctx: ExecContext) -> BatchStream:
         def gen():
+            note = batch_tap(self)  # fed here: the rows are pulled here
             for batch in self.children[0].execute(ctx):
                 ctx.check_running()
-                if int(batch.num_rows) == 0:
+                if pull_rows(batch, "expand.input_rows") == 0:
                     continue
                 out = self._explode(batch)
-                if out is not None and int(out.num_rows) > 0:
+                rows = 0 if out is None else pull_rows(out, "expand.out_rows")
+                if rows:
+                    note(out, rows)
                     yield out
 
-        return count_stream(self, gen())
+        return gen()
 
     def _explode(self, batch: ColumnBatch) -> Optional[ColumnBatch]:
         lcol: Column = self._list_fn(batch)
@@ -145,7 +150,7 @@ class GenerateExec(Operator):
         lens = jnp.where(mask & lcol.valid_mask(), ld.lengths(), 0)
         eff = jnp.maximum(lens, 1) if self.outer else lens
         eff = jnp.where(mask, eff, 0)
-        total = int(jnp.sum(eff))
+        total = int(pull_array(jnp.sum(eff), "expand.total"))
         if total == 0:
             return None
         out_cap = bucket_capacity(total)

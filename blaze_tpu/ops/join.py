@@ -47,14 +47,14 @@ import jax.numpy as jnp
 
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.batch import (
-    Column, ColumnBatch, bucket_capacity, nonzero_i32,
+    Column, ColumnBatch, bucket_capacity, nonzero_i32, pull_array, pull_rows,
 )
 from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
 from blaze_tpu.ops import segment as seg
-from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
+from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, batch_tap
 from blaze_tpu.ops.common import concat_batches
 from blaze_tpu.ops.sort_keys import encode_column
 from blaze_tpu.runtime import compile_service, jit_cache
@@ -372,9 +372,12 @@ class HashJoinLikeExec(Operator):
         return (self.children[0], self.children[1], lcols, rcols)
 
     def execute(self, ctx: ExecContext) -> BatchStream:
-        return count_stream(self, self._gen(ctx))
+        # the generators pull each output batch's rows to drop an empty
+        # one, so they feed the operator's tap themselves (one pull, one
+        # `wait` span a batch) and are not wrapped in count_stream
+        return self._gen(ctx, batch_tap(self))
 
-    def _gen(self, ctx: ExecContext):
+    def _gen(self, ctx: ExecContext, note):
         probe_op, build_op, probe_cols, build_cols = self._probe_build()
         jt = self.join_type
         probe_is_left = not self.build_is_left
@@ -387,7 +390,8 @@ class HashJoinLikeExec(Operator):
         if build_batches:
             build = concat_batches(build_batches, build_op.schema)
             build = compile_service.canonical_batch(
-                build, "join_build", raw_rows=int(build.num_rows))
+                build, "join_build",
+                raw_rows=pull_rows(build, "join.build_rows"))
         else:
             build = ColumnBatch.empty(build_op.schema)
 
@@ -408,13 +412,13 @@ class HashJoinLikeExec(Operator):
                            JoinType.LEFT_ANTI, JoinType.EXISTENCE)):
             from blaze_tpu.runtime.memory import batch_nbytes
 
-            build_rows = int(build.num_rows)
+            build_rows = pull_rows(build, "join.build_rows")
             build_bytes = batch_nbytes(build)
             if (build_rows > conf.bhj_fallback_rows_threshold
                     or build_bytes > conf.bhj_fallback_mem_threshold):
                 self.metrics.add("bhj_fallback_to_smj", 1)
                 yield from self._gen_chunked_build(
-                    ctx, probe_op, build, probe_cols, build_cols, jt)
+                    ctx, probe_op, build, probe_cols, build_cols, jt, note)
                 return
 
         null_safe = [k.null_safe for k in self.keys]
@@ -435,7 +439,7 @@ class HashJoinLikeExec(Operator):
 
         for probe in probe_op.execute(ctx):
             ctx.check_running()
-            if int(probe.num_rows) == 0:
+            if pull_rows(probe, "join.probe_rows") == 0:
                 continue
             # per-batch flag layout: either side nullable -> flag key
             force_flags = [
@@ -447,22 +451,28 @@ class HashJoinLikeExec(Operator):
                     force_flags, probe_is_left, build_side_semi)
             if need_build_matched:
                 build_matched = build_matched | matched
-            if out is not None and int(out.num_rows) > 0:
+            rows = 0 if out is None else pull_rows(out, "join.out_rows")
+            if rows:
+                note(out, rows)
                 yield out
 
         if build_side_semi:
             out = self._build_side_semi_result(build_sorted, build_matched)
-            if out is not None and int(out.num_rows) > 0:
+            rows = 0 if out is None else pull_rows(out, "join.out_rows")
+            if rows:
+                note(out, rows)
                 yield out
         elif need_build_matched:
             out = self._unmatched_build(build_sorted, build_matched,
                                         probe_is_left, probe_op.schema)
-            if out is not None and int(out.num_rows) > 0:
+            rows = 0 if out is None else pull_rows(out, "join.out_rows")
+            if rows:
+                note(out, rows)
                 yield out
 
     def _gen_chunked_build(self, ctx: ExecContext, probe_op: Operator,
                            build: ColumnBatch, probe_cols: List[int],
-                           build_cols: List[int], jt: JoinType):
+                           build_cols: List[int], jt: JoinType, note):
         """Bounded-memory join against an oversized build side: the build
         rows are processed in sorted chunks (each chunk's sort stays under
         the fallback threshold). Inner outputs union across chunks; semi/
@@ -472,7 +482,7 @@ class HashJoinLikeExec(Operator):
         from blaze_tpu.runtime.memory import batch_nbytes
 
         null_safe = [k.null_safe for k in self.keys]
-        nrows = int(build.num_rows)
+        nrows = pull_rows(build, "join.build_rows")
         # chunk rows bound by BOTH thresholds: a byte-triggered fallback
         # (huge rows, few of them) must not end up with one whole-build
         # chunk — that would be the resident path wearing a fallback
@@ -497,7 +507,7 @@ class HashJoinLikeExec(Operator):
                            JoinType.EXISTENCE)
         for probe in probe_op.execute(ctx):
             ctx.check_running()
-            if int(probe.num_rows) == 0:
+            if pull_rows(probe, "join.probe_rows") == 0:
                 continue
             cnt_total = jnp.zeros((probe.capacity,), jnp.int64)
             for piece in chunks:
@@ -526,11 +536,15 @@ class HashJoinLikeExec(Operator):
                     out, _ = self._join_batch(
                         probe, piece, probe_cols, build_cols, null_safe,
                         force_flags, not self.build_is_left, False)
-                if out is not None and int(out.num_rows) > 0:
+                rows = 0 if out is None else pull_rows(out, "join.out_rows")
+                if rows:
+                    note(out, rows)
                     yield out
             if semi_like:
                 out = self._semi_like(probe, cnt_total, jt)
-                if out is not None and int(out.num_rows) > 0:
+                rows = 0 if out is None else pull_rows(out, "join.out_rows")
+                if rows:
+                    note(out, rows)
                     yield out
 
     def _sort_build(self, build: ColumnBatch, build_cols: List[int],
@@ -592,7 +606,8 @@ class HashJoinLikeExec(Operator):
                           (jt == JoinType.RIGHT and not probe_is_left) or
                           jt == JoinType.FULL)
         eff = jnp.maximum(cnt, 1) if emit_unmatched else cnt
-        total = int(jnp.sum(jnp.where(probe.row_mask(), eff, 0)))
+        total = int(pull_array(
+            jnp.sum(jnp.where(probe.row_mask(), eff, 0)), "join.pair_total"))
         if total == 0:
             return None, matched_now
         out_cap = bucket_capacity(total)
@@ -655,7 +670,8 @@ class HashJoinLikeExec(Operator):
             JoinType.LEFT_SEMI, JoinType.LEFT_ANTI, JoinType.EXISTENCE)
 
         eff = jnp.maximum(cnt, 1) if probe_outer else cnt
-        total = int(jnp.sum(jnp.where(probe.row_mask(), eff, 0)))
+        total = int(pull_array(
+            jnp.sum(jnp.where(probe.row_mask(), eff, 0)), "join.pair_total"))
         no_matched = jnp.zeros((capB,), jnp.bool_)
         # the filter always sees left-fields + right-fields, regardless of
         # the join's OUTPUT schema (semi/anti/existence outputs omit the
@@ -736,7 +752,7 @@ class HashJoinLikeExec(Operator):
                          probe_schema) -> Optional[ColumnBatch]:
         keep = (~build_matched) & build_sorted.row_mask()
         picked = build_sorted.compact(keep)
-        n = int(picked.num_rows)
+        n = pull_rows(picked, "join.unmatched_rows")
         if n == 0:
             return None
         # null columns for the probe side
@@ -803,6 +819,13 @@ class BroadcastNestedLoopJoinExec(Operator):
                 self.children[0].plan_key(), self.children[1].plan_key())
 
     def execute(self, ctx: ExecContext) -> BatchStream:
+        # feeds its own tap, like HashJoinLikeExec.execute
+        note = batch_tap(self)
+
+        def emit(batch, rows=None):
+            note(batch, rows)
+            return batch
+
         def gen():
             from blaze_tpu.config import conf
             from blaze_tpu.ops.common import slice_batch
@@ -813,22 +836,25 @@ class BroadcastNestedLoopJoinExec(Operator):
                   else ColumnBatch.empty(self.children[0].schema))
             rs = (concat_batches(right_b, self.children[1].schema) if right_b
                   else ColumnBatch.empty(self.children[1].schema))
-            nl, nr = int(ls.num_rows), int(rs.num_rows)
+            nl = pull_rows(ls, "nlj.side_rows")
+            nr = pull_rows(rs, "nlj.side_rows")
             jt = self.join_type
 
             if nl == 0 or nr == 0:
                 if jt in (JoinType.LEFT, JoinType.FULL) and nl > 0:
-                    yield self._one_side_nulls(ls, rs.schema, left_side=True)
+                    yield emit(self._one_side_nulls(ls, rs.schema,
+                                                    left_side=True))
                 if jt in (JoinType.RIGHT, JoinType.FULL) and nr > 0:
-                    yield self._one_side_nulls(rs, ls.schema, left_side=False)
+                    yield emit(self._one_side_nulls(rs, ls.schema,
+                                                    left_side=False))
                 if jt == JoinType.LEFT_ANTI and nl > 0:
-                    yield ls.with_columns(self._schema, ls.columns)
+                    yield emit(ls.with_columns(self._schema, ls.columns))
                 if jt == JoinType.EXISTENCE and nl > 0:
                     cols = ls.columns + [Column(
                         T.BOOLEAN, jnp.zeros((ls.capacity,), jnp.bool_),
                         None)]
-                    yield ColumnBatch(self._schema, cols, ls.num_rows,
-                                      ls.capacity)
+                    yield emit(ColumnBatch(self._schema, cols, ls.num_rows,
+                                           ls.capacity))
                 return
 
             # every left row matches all right rows — expand the cartesian
@@ -850,32 +876,36 @@ class BroadcastNestedLoopJoinExec(Operator):
                             else ~lmatched)
                     part = lc.with_columns(self._schema,
                                            lc.columns).compact(keep)
-                    if int(part.num_rows):
-                        yield part
+                    rows = pull_rows(part, "nlj.out_rows")
+                    if rows:
+                        yield emit(part, rows)
                     continue
                 if jt == JoinType.EXISTENCE:
                     cols = lc.columns + [Column(
                         T.BOOLEAN, lmatched & lc.row_mask(), None)]
-                    yield ColumnBatch(self._schema, cols, lc.num_rows,
-                                      lc.capacity)
+                    yield emit(ColumnBatch(self._schema, cols, lc.num_rows,
+                                           lc.capacity))
                     continue
-                if out is not None and int(out.num_rows):
-                    yield out
+                rows = 0 if out is None else pull_rows(out, "nlj.out_rows")
+                if rows:
+                    yield emit(out, rows)
                 if jt in (JoinType.LEFT, JoinType.FULL):
                     un = lc.compact((~lmatched) & lc.row_mask())
-                    if int(un.num_rows):
-                        yield self._one_side_nulls(un, rs.schema,
-                                                   left_side=True)
+                    n = pull_rows(un, "nlj.unmatched_rows")
+                    if n:  # null-extended, the same rows
+                        yield emit(self._one_side_nulls(
+                            un, rs.schema, left_side=True), n)
             if jt in (JoinType.RIGHT, JoinType.FULL):
                 un = rs.compact((~rmatched_total) & rs.row_mask())
-                if int(un.num_rows):
-                    yield self._one_side_nulls(un, ls.schema,
-                                               left_side=False)
+                n = pull_rows(un, "nlj.unmatched_rows")
+                if n:
+                    yield emit(self._one_side_nulls(
+                        un, ls.schema, left_side=False), n)
 
-        return count_stream(self, gen())
+        return gen()
 
     def _expand_nlj(self, ls, rs, start, cnt):
-        total = int(jnp.sum(cnt))
+        total = int(pull_array(jnp.sum(cnt), "nlj.pair_total"))
         if total == 0:
             capL, capR = ls.capacity, rs.capacity
             return None, jnp.zeros((capL,), jnp.bool_), jnp.zeros(

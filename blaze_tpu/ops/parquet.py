@@ -27,7 +27,7 @@ import pyarrow.parquet as pq
 from blaze_tpu.columnar import types as T
 from blaze_tpu.columnar.arrow_io import (batch_from_arrow, batch_to_arrow,
     schema_to_arrow)
-from blaze_tpu.columnar.batch import ColumnBatch
+from blaze_tpu.columnar.batch import ColumnBatch, pull_rows
 from blaze_tpu.columnar.types import Field, Schema
 from blaze_tpu.config import conf
 from blaze_tpu.exprs import ir
@@ -330,12 +330,13 @@ class ParquetSinkExec(Operator):
             try:
                 for batch in child.execute(ctx):
                     ctx.check_running()
-                    if int(batch.num_rows) == 0:
+                    n = pull_rows(batch, "parquet_sink.input_rows")
+                    if n == 0:
                         continue
                     with self.metrics.timer("io_time_ns"):
                         writer.write_batch(batch_to_arrow(batch),
                                            row_group_size=self.row_group_rows)
-                    rows += int(batch.num_rows)
+                    rows += n
             finally:
                 writer.close()
                 if not isinstance(sink, str) and hasattr(sink, "close"):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from blaze_tpu.columnar import serde
-from blaze_tpu.columnar.batch import ColumnBatch
+from blaze_tpu.columnar.batch import ColumnBatch, pull_array, pull_rows
 from blaze_tpu.columnar.types import Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
@@ -85,12 +85,11 @@ def round_robin_start(task_partition: int, num_partitions: int) -> int:
     with the task's partitionId so retries land rows identically;
     we derive it from spark-murmur3 of the partition id — deterministic
     and well-spread, though not bit-identical to java.util.Random)."""
-    import numpy as np
-
     from blaze_tpu.exprs.hash import hash_int32
 
-    h = int(np.asarray(hash_int32(jnp.asarray([task_partition], jnp.int32),
-                                  jnp.uint32(SPARK_SHUFFLE_SEED))[0]))
+    h = int(pull_array(
+        hash_int32(jnp.asarray([task_partition], jnp.int32),
+                   jnp.uint32(SPARK_SHUFFLE_SEED))[0], "shuffle.rr_start"))
     return h % num_partitions
 
 
@@ -196,7 +195,8 @@ class ShuffleWriterExec(Operator):
 
             for batch in execute_stage_or_plan(self.children[0], ctx):
                 ctx.check_running()
-                if int(batch.num_rows) == 0:
+                n = pull_rows(batch, "shuffle.input_rows")
+                if n == 0:
                     continue
                 with self.metrics.timer():
                     fn = jit_cache.get_or_compile(
@@ -207,13 +207,15 @@ class ShuffleWriterExec(Operator):
                         jit=keys_jit)
                     sb, counts = fn(batch, jnp.asarray(row_offset,
                                                        jnp.int64))
-                    row_offset += int(batch.num_rows)
+                    row_offset += n
                     cap = max(batch.capacity, 1)
                     self.metrics.add(
                         "shuffle_logical_bytes",
-                        M.batch_nbytes(batch) * int(batch.num_rows) // cap)
+                        M.batch_nbytes(batch) * n // cap)
                     hb = serde.to_host(sb)
-                    sink.submit((hb, np.asarray(counts)), host_nbytes(hb))
+                    sink.submit(
+                        (hb, pull_array(counts, "shuffle.part_counts")),
+                        host_nbytes(hb))
             # drain every pending frame (re-raising any pool-side error)
             # BEFORE the crash-atomic commit sees the buffers
             sink.close()
@@ -419,7 +421,8 @@ class RssShuffleWriterExec(ShuffleWriterExec):
         row_offset = 0
         for batch in self.children[0].execute(ctx):
             ctx.check_running()
-            if int(batch.num_rows) == 0:
+            n = pull_rows(batch, "shuffle.input_rows")
+            if n == 0:
                 continue
             with self.metrics.timer():
                 fn = jit_cache.get_or_compile(
@@ -429,9 +432,9 @@ class RssShuffleWriterExec(ShuffleWriterExec):
                         row_offset=off, rr_start=rr)),
                     jit=keys_jit)
                 sb, counts = fn(batch, jnp.asarray(row_offset, jnp.int64))
-                row_offset += int(batch.num_rows)
+                row_offset += n
                 hb = serde.to_host(sb)
-                counts = np.asarray(counts)
+                counts = pull_array(counts, "shuffle.part_counts")
                 offs = np.concatenate([[0], np.cumsum(counts)])
                 for p in range(P):
                     if counts[p]:
@@ -651,7 +654,7 @@ class IpcWriterExec(Operator):
         total = 0
         for batch in self.children[0].execute(ctx):
             ctx.check_running()
-            if int(batch.num_rows) == 0:
+            if pull_rows(batch, "ipc.input_rows") == 0:
                 continue
             with self.metrics.timer():
                 buf = serde.serialize_batch(batch)

@@ -15,7 +15,9 @@ from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
 
-from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    ColumnBatch, bucket_capacity, pull_array, pull_rows,
+)
 from blaze_tpu.columnar.types import Schema
 from blaze_tpu.config import conf
 from blaze_tpu.ops.base import BatchStream, ExecContext, Operator, count_stream
@@ -120,11 +122,11 @@ class ExternalSorter:
         row_bytes = max(self._M.batch_nbytes(big) // cap, 1)
         budget_rows = max(self.manager.total // (8 * row_bytes), 1024)
         frame = int(min(int(conf.spill_frame_rows), budget_rows))
-        for lo in range(0, max(int(sb.num_rows), 1), frame):
+        for lo in range(0, max(pull_rows(sb, "sort.run_rows"), 1), frame):
             from blaze_tpu.ops.common import slice_batch
 
             chunk = slice_batch(sb, lo, frame)
-            if int(chunk.num_rows) == 0:
+            if pull_rows(chunk, "sort.run_rows") == 0:
                 break
             run.write(chunk)
         self.runs.append(run)
@@ -177,12 +179,10 @@ class ExternalSorter:
     # trip per pooled frame; the host merge is dispatch-free. Schemas
     # with list storage keep the device merge.
     def _head_key(self, batch: ColumnBatch, row: int) -> tuple:
-        import numpy as np
-
         from blaze_tpu.ops.sort_keys import batch_sort_keys
 
         keys = batch_sort_keys(batch, self.specs)
-        return tuple(int(np.asarray(k[row])) for k in keys)
+        return tuple(int(pull_array(k[row], "sort.head_key")) for k in keys)
 
     def _split_leq(self, pool: ColumnBatch, bound: tuple):
         import jax.numpy as jnp
@@ -227,13 +227,14 @@ class ExternalSorter:
         while True:
             active = [i for i, c in enumerate(current) if c is not None]
             if not active:
-                if carry is not None and int(carry.num_rows) > 0:
+                if (carry is not None and
+                        pull_rows(carry, "sort.merge_rows") > 0):
                     yield carry
                 return
             i_min = min(active, key=lambda i: current[i][1])
             head_batch = current[i_min][0]
             parts = ([carry] if carry is not None and
-                     int(carry.num_rows) > 0 else [])
+                     pull_rows(carry, "sort.merge_rows") > 0 else [])
             parts.append(head_batch)
             pool = (parts[0] if len(parts) == 1 else
                     concat_batches(parts, self.schema))
@@ -241,7 +242,7 @@ class ExternalSorter:
             current[i_min] = pull(i_min)
             others = [i for i in active if i != i_min]
             if not others and current[i_min] is None:
-                if int(pool.num_rows) > 0:
+                if pull_rows(pool, "sort.merge_rows") > 0:
                     yield pool
                 carry = None
                 continue
@@ -250,7 +251,7 @@ class ExternalSorter:
                 bounds.append(current[i_min][1])
             bound = min(bounds)
             emit, carry = self._split_leq(pool, bound)
-            if int(emit.num_rows) > 0:
+            if pull_rows(emit, "sort.merge_rows") > 0:
                 yield emit
 
 
@@ -287,7 +288,7 @@ class SortExec(Operator):
             try:
                 for batch in child.execute(ctx):
                     ctx.check_running()
-                    if int(batch.num_rows):
+                    if pull_rows(batch, "sort.input_rows"):
                         with self.metrics.timer():
                             sorter.add(batch)
                 with self.metrics.timer():

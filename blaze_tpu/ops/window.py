@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from blaze_tpu.columnar import types as T
-from blaze_tpu.columnar.batch import Column, ColumnBatch
+from blaze_tpu.columnar.batch import (
+    Column, ColumnBatch, pull_array, pull_rows,
+)
 from blaze_tpu.columnar.types import DataType, Field, Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.compiler import compile_expr
@@ -150,7 +152,7 @@ class WindowExec(Operator):
             try:
                 for b in self.children[0].execute(ctx):
                     ctx.check_running()
-                    if int(b.num_rows) == 0:
+                    if pull_rows(b, "window.input_rows") == 0:
                         continue
                     wkey = ("window_work", jit, self.plan_key(),
                             b.shape_key())
@@ -172,7 +174,7 @@ class WindowExec(Operator):
                     # collect the sorted chunks ONCE (re-concatenating a
                     # growing carry per chunk would be O(n^2) in copies)
                     chunks = [sb for sb in sorter.finish()
-                              if int(sb.num_rows) > 0]
+                              if pull_rows(sb, "window.chunk_rows") > 0]
                     if chunks:
                         yield compute(
                             chunks[0] if len(chunks) == 1
@@ -184,7 +186,7 @@ class WindowExec(Operator):
                     ctx.check_running()
                     chunk = (sb if carry is None
                              else concat_batches([carry, sb], work_schema))
-                    n = int(chunk.num_rows)
+                    n = pull_rows(chunk, "window.chunk_rows")
                     split = self._last_partition_start(chunk, part_idx)
                     if split <= 0:
                         carry = chunk
@@ -192,7 +194,8 @@ class WindowExec(Operator):
                     done = slice_batch(chunk, 0, split)
                     carry = slice_batch(chunk, split, n - split)
                     yield compute(done)
-                if carry is not None and int(carry.num_rows) > 0:
+                if (carry is not None and
+                        pull_rows(carry, "window.chunk_rows") > 0):
                     yield compute(carry)
                 self.metrics.add("spill_count", sorter.spill_count)
             finally:
@@ -204,12 +207,10 @@ class WindowExec(Operator):
                               part_idx: List[int]) -> int:
         """Row index where the final (possibly incomplete) partition begins
         — one host pull per merge chunk."""
-        import numpy as np
-
         starts = seg.group_starts(chunk, part_idx)
         iota = jnp.arange(chunk.capacity, dtype=jnp.int32)
         last = jnp.max(jnp.where(starts, iota, -1))
-        return int(np.asarray(last))
+        return int(pull_array(last, "window.last_start"))
 
     # ---- the fused kernel (input already in sorted work layout) ----
     def _compute_sorted(self, sb: ColumnBatch) -> ColumnBatch:

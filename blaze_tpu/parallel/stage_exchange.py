@@ -29,7 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from blaze_tpu.columnar.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    ColumnBatch, bucket_capacity, pull_array, pull_rows,
+)
 from blaze_tpu.columnar.types import Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.ops.base import ExecContext
@@ -182,7 +184,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         with trace.span("exchange", transport="local", partitions=Pn,
                         capacity=batch.capacity) as sp:
             sb, bounds = jit_cache.get_or_compile(key, make)(batch)
-            bounds = np.asarray(bounds)
+            bounds = pull_array(bounds, "exchange.local_bounds")
             for p in range(Pn):
                 n = int(bounds[p + 1]) - int(bounds[p])
                 if n:
@@ -274,13 +276,14 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
             out_cols, out_counts, overflow = run(
                 jax.tree.map(glue, *cols),
                 jax.device_put(np.asarray(rows, np.int32), sharding))
-        if int(np.asarray(overflow).sum()) > 0:
+        if int(pull_array(overflow, "exchange.mesh_overflow").sum()) > 0:
             return False
         leaves, treedef = jax.tree.flatten(out_cols)
         if stats is not None:
             # devices the shard_map's output actually sits on
             stats["devices"] = len(leaves[0].devices())
-        out_counts = np.asarray(out_counts)  # (use_d, kpd)
+        # (use_d, kpd)
+        out_counts = pull_array(out_counts, "exchange.mesh_counts")
         recv_cap = use_d * q  # per-device received capacity
         local = [{sh.device: sh.data for sh in x.addressable_shards}
                  for x in leaves]
@@ -347,7 +350,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         op = decode_plan(writer.input)  # fresh operator state per task
         out = []
         for batch in execute_stage_or_plan(op, ctx):
-            n = int(batch.num_rows)
+            n = pull_rows(batch, "exchange.map_rows")
             if n:
                 out.append((batch, n))
         return out
@@ -361,7 +364,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
             op = decode_plan(writer.input)
             for batch in execute_stage_or_plan(
                     op, ExecContext(partition=task, num_partitions=ntasks)):
-                n = int(batch.num_rows)
+                n = pull_rows(batch, "exchange.map_rows")
                 if n == 0:
                     continue
                 if mesh is not None:

@@ -31,7 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from blaze_tpu.columnar import types as T
-from blaze_tpu.columnar.batch import Column, ColumnBatch, bucket_capacity
+from blaze_tpu.columnar.batch import (
+    Column, ColumnBatch, bucket_capacity, pull_array, pull_rows,
+)
 from blaze_tpu.columnar.types import TypeKind
 from blaze_tpu.config import conf
 from blaze_tpu.ops import mxu_agg
@@ -333,7 +335,7 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
             ("stage_probe", root.plan_key(), shape0, len(batches)),
             make_probe)
         kmins_v, kmaxs_v, vmaxs_v, bad_v = probe(*batches)
-        if bool(bad_v):
+        if bool(pull_array(bad_v, "stage.probe")):
             return None  # null grouping keys: dense slots can't hold them
         # fixed float scales: 2 spare bits of headroom under the digit
         # capacity (8*planes-2) over the probed max, so values drifting
@@ -341,13 +343,15 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
         # in-program overflow flag re-probes
         cap_bits = 8.0 * mxu_agg.f64_chunks() - 4.0
         scales = []
+        vmaxs_np = pull_array(vmaxs_v, "stage.probe")
         for j, ci in enumerate(float_calls):
-            vmax = float(np.asarray(vmaxs_v)[j])
+            vmax = float(vmaxs_np[j])
             exp = (math.floor(math.log2(vmax)) + 1.0
                    if vmax > 0.0 else -996.0)
             scales.append((ci, min(cap_bits - exp, 1000.0)))
         spans, kmins = [], []
-        for lo, hi in zip(np.asarray(kmins_v), np.asarray(kmaxs_v)):
+        for lo, hi in zip(pull_array(kmins_v, "stage.probe"),
+                          pull_array(kmaxs_v, "stage.probe")):
             # power-of-two headroom per key: exact spans would invalidate
             # the memo on ANY later dataset with one new key value (the
             # padding only wastes dense slots; packing and unpacking use
@@ -753,7 +757,7 @@ def try_run_stage(root: Operator, ctx: ExecContext, deferred: bool = False,
                 compile_service.note_stage_compiled()
 
             return out, flags, retry, commit_metrics
-        flags_np = np.asarray(flags)
+        flags_np = pull_array(flags, "stage.flags")
         nrows = int(flags_np[1])
         if not bool(flags_np[0]):
             break
@@ -835,11 +839,12 @@ def _run_chain_stage(root: Operator, chain: List[MapLikeOp],
     _warn_stats_once()
     for op in chain:
         op.metrics.add("output_batches", 1)
-    root.metrics.add("output_rows", int(out.num_rows))
+    rows = pull_rows(out, "stage.output_rows")
+    root.metrics.add("output_rows", rows)
     root.metrics.add("stage_compiled", 1)
     compile_service.note_stage_compiled()
     # chain stages have no group key — record output cardinality only
-    _note_stage_stats(root, None, dense=True, rows=int(out.num_rows))
+    _note_stage_stats(root, None, dense=True, rows=rows)
     return out
 
 

@@ -32,15 +32,20 @@ compilation makes its own instrumentation blind (arxiv 1703.08219):
               process keep that process's ids beside their `exec`
               stamp). Kinds, where each is opened, and the
               benchmark metric that reads it: SPAN_KINDS below. No span
-              reads a device value for an attr or waits on a result:
-              `dispatch`/`h2d` are the host's time in the call, device
-              time comes from the profiler trace only. While a span is
-              open it also holds a jax.profiler.TraceAnnotation
-              "blaze:<kind>" (inert without a profiler session), so a
-              device trace taken over the block carries the program's
-              spans on /host:CPU, on the clock the device plane is laid
-              against; the `clock_anchor` event pairs TRACE.clock() with
-              TRACE.wall() once per process for traces taken elsewhere.
+              adds a pull or a wait of its own: `dispatch`/`h2d` are the
+              host's time in the call, and where the program itself
+              reads a device value back (a `d2h`, or a control pull
+              through columnar.batch.pull_rows / pull_array) the `wait`
+              span around it is the host's time blocked there, with
+              `ready` telling whether the device had finished first.
+              Device time comes from the profiler trace only. While a
+              span is open it also holds a jax.profiler.TraceAnnotation
+              "blaze:<kind>" (inert without a profiler session; a
+              `wait`'s carries its `site`), so a device trace taken over
+              the block carries the program's spans on /host:CPU, on the
+              clock the device plane is laid against; the `clock_anchor`
+              event pairs TRACE.clock() with TRACE.wall() once per
+              process for traces taken elsewhere.
 
   events      `event(kind, **attrs)` records a point: retries, ladder
               rungs, heartbeat misses, deadline kills, speculation
@@ -332,6 +337,14 @@ SPAN_KINDS = (
     "stage",         # executor: shuffle-map/broadcast/result stage
     "task_attempt",  # supervisor: one per (task, attempt); device = the
                      # chip its programs were sent to (runtime/placement)
+    "wait",          # columnar.batch.pull_rows / pull_array: one control
+                     # pull of a device value (a batch's rows, an
+                     # exchange's bounds or counts, a join's pair total),
+                     # the host blocked until it has crossed. site: the
+                     # caller, `<layer>.<purpose>`; ready: the device had
+                     # the value when the host asked. host_wait_s,
+                     # blocking_waits_per_query, wait_ready_share,
+                     # stage_self_share; explain_analyze's stage lines
 )
 
 # run-record wire format (ledger lines + history records). Bump on
@@ -524,9 +537,11 @@ def _annotation(sp: "_Span"):
     profiler session is active; only reached with tracing on."""
     import jax
 
-    return jax.profiler.TraceAnnotation(
-        "blaze:" + sp.kind, span_id=sp.id,
-        **{k: str(v) for k, v in sp.ids.items() if v is not None})
+    extra = {k: str(v) for k, v in sp.ids.items() if v is not None}
+    if "site" in sp.attrs:  # a `wait`: which pull the host is in
+        extra["site"] = sp.attrs["site"]
+    return jax.profiler.TraceAnnotation("blaze:" + sp.kind, span_id=sp.id,
+                                        **extra)
 
 
 def span(kind: str, **attrs):
@@ -839,7 +854,8 @@ def explain_analyze(root, run_info: Optional[dict] = None,
     """EXPLAIN ANALYZE-style report: the operator tree with per-operator
     counters (bytes humanized, times in ms, row throughput), then
     per-stage span wall-times with resilience annotations, histogram
-    percentiles and the process telemetry summaries.
+    percentiles and the process telemetry summaries. A stage's line
+    carries its `wait` spans: `wait 1.23 s in 410 pulls (388 blocking)`.
 
     `root` is an executed Operator tree (its MetricsSet snapshots are
     read under their locks); `records` defaults to the global TraceLog —
@@ -894,6 +910,16 @@ def explain_analyze(root, run_info: Optional[dict] = None,
                 pct = round(100.0 * cp / mv) if mv else 0
                 head += (f" moved {human_bytes(mv)}, copied "
                          f"{human_bytes(cp)} ({pct}%)")
+            # a host-paced stage shows here: the driver's time blocked on
+            # control pulls inside the stage, on every thread
+            waits = [r for r in recs if r["type"] == "span"
+                     and r["kind"] == "wait" and r.get("stage_id") == sid
+                     and r.get("query_id") == sp.get("query_id")]
+            if waits:
+                blocking = sum(1 for w in waits
+                               if not w.get("attrs", {}).get("ready"))
+                head += (f" wait {sum(w['dur'] for w in waits) / 1e9:.2f} s"
+                         f" in {len(waits)} pulls ({blocking} blocking)")
             notes = _stage_annotations(
                 [r for r in recs if r["type"] == "event"
                  and r.get("stage_id") == sid

@@ -24,7 +24,7 @@ import sys
 import tempfile
 from typing import Dict, List, Optional
 
-from blaze_tpu.columnar.batch import ColumnBatch
+from blaze_tpu.columnar.batch import ColumnBatch, pull_rows
 from blaze_tpu.ops.base import ExecContext
 from blaze_tpu.ops.common import concat_batches
 from blaze_tpu.plan import decode_plan, fingerprint_plan
@@ -1108,7 +1108,7 @@ def _collect_result(stage: Stage, op, split, batches: List[ColumnBatch],
             # pull). A pure function of `batches`, so a failed device
             # pull/upload mid-merge simply re-runs.
             hbs = [serde.to_host(b) for b in batches
-                   if int(b.num_rows) > 0]
+                   if pull_rows(b, "collect.rows") > 0]
             if not hbs:
                 return ColumnBatch.empty(op.schema)
             hb = host_sort.host_concat(hbs)

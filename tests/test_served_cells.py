@@ -2,8 +2,10 @@
 own plan (benchmarks/queries) over its own generator's tables through
 run_plan equals its plain reference with nothing refused
 (benchmarks/harness/evidence.py), and once warm a query compiles nothing:
-the `compiles_in_window` contract of BENCHMARK.json. The cells, their
-configurations and their traffic are read from the manifest."""
+the `compiles_in_window` contract of BENCHMARK.json; traced, every
+shuffle_map stage records the driver's `wait` spans and the four readers of
+them give numbers. The cells, their configurations and their traffic are read
+from the manifest."""
 
 import importlib.util
 import json
@@ -12,7 +14,8 @@ import os
 import jax
 import pytest
 
-from blaze_tpu.runtime import compile_service
+from blaze_tpu.config import conf
+from blaze_tpu.runtime import compile_service, trace
 from blaze_tpu.spark.local_runner import run_plan
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -112,3 +115,52 @@ def test_a_warm_query_compiles_nothing(cells, one_chip, name):
     assert run() == (None, [])
     assert compile_service.TELEMETRY.snapshot().get(
         "compile_count", 0) == before
+
+
+WAIT_READERS = ("host_wait_s", "blocking_waits_per_query",
+                "wait_ready_share", "stage_self_share")
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP))
+def test_traced_every_map_stage_records_waits_and_the_readers_read_them(
+        cells, one_chip, name):
+    manifest = {m["name"]: m
+                for m in _json(REPO, "BENCHMARK.json")["per_layer"]}
+    assert all(name in manifest[r]["workloads"] for r in WAIT_READERS)
+    saved = conf.trace_enabled
+    trace.reset()
+    conf.trace_enabled = True
+    try:
+        assert cells(name)() == (None, [])
+        spans = [r for r in trace.TRACE.snapshot() if r["type"] == "span"]
+    finally:
+        conf.trace_enabled = saved
+        trace.reset()
+    stages = [s for s in spans if s["kind"] == "stage"
+              and s["attrs"]["stage_kind"] == "shuffle_map"]
+    waits = [s for s in spans if s["kind"] == "wait"]
+    assert stages and waits
+    by_id = {s["id"]: s for s in spans}
+    for st in stages:
+        mine = [w for w in waits if w.get("stage_id") == st["stage_id"]]
+        assert mine, f"stage {st['stage_id']} recorded no wait"
+        for w in mine:
+            assert type(w["attrs"]["ready"]) is bool and w["attrs"]["site"]
+            assert w["query_id"] == st["query_id"]
+            assert by_id[w["parent"]]["kind"] != "query"
+            assert st["ts"] <= w["ts"] <= st["ts"] + st["dur"]
+    # a wait is never a direct child of the query span: query_self_share
+    # reads as before
+    query = next(s for s in spans if s["kind"] == "query")
+    assert all(w["parent"] != query["id"] for w in waits)
+    run = {"window": [{"spans": spans}], "profiled": []}
+    values = {r: _load(f"metrics/{r}.py").read(run) for r in WAIT_READERS}
+    assert values["host_wait_s"] > 0
+    assert values["blocking_waits_per_query"] >= 0
+    assert 0 <= values["wait_ready_share"] <= 100
+    assert 0 < values["stage_self_share"] < 100
+    # on a run from before the span the four are silent
+    old = {"window": [{"spans": [s for s in spans if s["kind"] != "wait"]}],
+           "profiled": []}
+    assert [_load(f"metrics/{r}.py").read(old)
+            for r in WAIT_READERS] == [None] * 4
