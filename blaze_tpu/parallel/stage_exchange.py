@@ -30,14 +30,16 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from blaze_tpu.columnar.batch import (
-    ColumnBatch, bucket_capacity, pull_array, pull_rows,
+    Column, ColumnBatch, bucket_capacity, pull_array, pull_rows,
 )
 from blaze_tpu.columnar.types import Schema
 from blaze_tpu.exprs import ir
 from blaze_tpu.ops.base import ExecContext
+from blaze_tpu.ops.common import adaptive_batch_rows
 from blaze_tpu.plan import plan_pb2 as pb
-from blaze_tpu.runtime import placement, resources, trace
+from blaze_tpu.runtime import jit_cache, placement, resources, trace
 from blaze_tpu.runtime.executor import execute_plan
+from blaze_tpu.runtime.memory import batch_nbytes, get_manager
 
 
 _collective_lock = threading.Lock()
@@ -46,9 +48,132 @@ _collective_lock = threading.Lock()
 def live_nbytes(batch: ColumnBatch, nrows: int) -> int:
     """Bytes of a batch's live rows: batch_nbytes counts the padded
     capacity bucket."""
-    from blaze_tpu.runtime.memory import batch_nbytes
-
     return batch_nbytes(batch) * nrows // max(batch.capacity, 1)
+
+
+def slice_layout(batch: ColumnBatch) -> tuple:
+    """What two batches must share for their planes to be laid side by
+    side: the columns' storage, validity present or not, string widths."""
+    return (jax.tree.structure(batch.columns), batch.shape_key()[1:])
+
+
+def _own_dictionary(c: Column) -> bool:
+    if c.is_struct:
+        return any(_own_dictionary(ch) for ch in c.data.children)
+    return c.is_dict
+
+
+def packable(batch: ColumnBatch) -> bool:
+    """Every plane holds row r at index r and means the same in another
+    batch of its layout: not so a list's elements, nor a dictionary
+    column's codes, which index the batch's own dictionary."""
+    return batch.row_aligned and not any(
+        _own_dictionary(c) for c in batch.columns)
+
+
+def pack_slices(slices: List[tuple], schema: Schema,
+                device=None) -> ColumnBatch:
+    """The live rows of `slices`, in order, as one batch at their total's
+    capacity bucket. `slices` are (batch, live rows) with the rows as host
+    integers, `packable` and of one `slice_layout`: nothing is pulled. On a
+    host of several chips `device` is the chip they lie on, and the batch
+    is made there.
+
+    One program a group: every plane of every slice is written whole into
+    the output at the running sum of live rows (`dynamic_update_slice`, the
+    twin of `ColumnBatch.slice_rows`), in order, so that a slice's padding
+    is overwritten by the slice after it; no plane is gathered. The offsets
+    are traced, and the key holds capacities and layouts only."""
+    batches = [b for b, _ in slices]
+    starts = np.cumsum([0] + [n for _, n in slices]).astype(np.int32)
+    cap = bucket_capacity(int(starts[-1]))
+    key = ("exchange_pack", cap, tuple(schema.fields),
+           tuple(b.shape_key() for b in batches))
+
+    def make():
+        def run(starts, *bs):
+            def plane(*xs):
+                # room for the last slice's padding past `cap`: an update
+                # that passed the end would be moved back over live rows
+                room = max(x.shape[0] for x in xs)
+                out = jnp.zeros((cap + room,) + xs[0].shape[1:], xs[0].dtype)
+                for i, x in enumerate(xs):
+                    out = jax.lax.dynamic_update_slice_in_dim(
+                        out, x, starts[i], axis=0)
+                return out[:cap]
+
+            cols = jax.tree.map(plane, *[b.columns for b in bs])
+            return ColumnBatch(schema, cols, starts[-1], cap)
+
+        return run
+
+    with placement.on_device(device):
+        return jit_cache.get_or_compile(key, make)(starts, *batches)
+
+
+class KeptPartitions:
+    """What an in-HBM exchange holds for its reduce tasks: per partition
+    the kept batches with their live rows, in the order the rows came, and
+    the bytes they pin on each chip.
+
+    A reduce task runs its programs once a batch it is handed, so the
+    slices the exchange cuts are packed as they are kept (`pack_slices`):
+    consecutive slices of a partition join its open group until the next
+    one's live rows would take the group past `adaptive_batch_rows`, the
+    size a scan hands on; the group is then sealed into one batch and its
+    slices dropped. Whole slices only; a group of one stays the batch it
+    is; a slice that is not `packable`, or of another layout than the open
+    group, does not share a group. `pinned` counts a group's slices and its
+    packed batch while both are alive; `high_water` is the most any chip
+    held at a time."""
+
+    def __init__(self, schema: Schema, partitions: int, chips: int,
+                 per_chip: int):
+        self.parts: List[List[tuple]] = [[] for _ in range(partitions)]
+        self.pinned = [0] * chips
+        self.high_water = 0
+        self.cut = 0        # non-empty slices kept
+        self.packed = 0     # those of them that went into a packed batch
+        self._schema, self._per_chip = schema, per_chip
+        self._target = adaptive_batch_rows(schema)
+        self._open: List[List[tuple]] = [[] for _ in range(partitions)]
+
+    def _pin(self, p: int, nbytes: int) -> None:
+        d = p // self._per_chip
+        self.pinned[d] += nbytes
+        self.high_water = max(self.high_water, self.pinned[d])
+
+    def keep(self, p: int, b: ColumnBatch, nrows: int) -> None:
+        self.cut += 1
+        self._pin(p, batch_nbytes(b))
+        if not packable(b):
+            self.seal(p)
+            self.parts[p].append((b, nrows))
+            return
+        group = self._open[p]
+        if group and (slice_layout(b) != slice_layout(group[0][0])
+                      or sum(n for _, n in group) + nrows > self._target):
+            self.seal(p)
+        self._open[p].append((b, nrows))
+
+    def seal(self, p: int) -> None:
+        """Close partition `p`'s open group: its one slice as it is, or
+        its slices packed into one batch, where they lie."""
+        group, self._open[p] = self._open[p], []
+        if len(group) > 1:
+            batch = pack_slices(
+                group, self._schema,
+                placement.device_of(group[0][0].columns)
+                if len(self.pinned) > 1 else None)
+            self._pin(p, batch_nbytes(batch))
+            self._pin(p, -sum(batch_nbytes(b) for b, _ in group))
+            self.packed += len(group)
+            group = [(batch, sum(n for _, n in group))]
+        self.parts[p].extend(group)
+
+    def seal_all(self) -> None:
+        for p in range(len(self.parts)):
+            self.seal(p)
 
 
 def mesh_key_indices(writer: pb.ShuffleWriterNode,
@@ -100,10 +225,17 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     lock step. Only the calling (driver) thread launches the collective
     program; task threads launch single-device programs only.
 
+    What is kept for a partition is packed as it is kept
+    (`KeptPartitions`): a reduce task is handed batches of up to the size a
+    scan hands on, not one sliver a map-side batch.
+
     `stats` receives what the stage did: `bytes` (live-row-scaled),
     `devices`, `host_bytes`, and what it holds for the reduce side:
-    `pinned_bytes` on the fullest chip, `slices` and `slice_rows` kept in
-    HBM, `file_batches` that left it.
+    `pinned_bytes` (the most a chip held at a time), `slices` and
+    `slice_rows` kept in HBM (`slices` are the batches a reduce task is
+    handed: packed ones, and slices left as they were), `slices_cut` (the
+    non-empty slices the exchange cut) and `slices_packed` (those of them
+    that went into a packed batch), `file_batches` that left HBM.
 
     Returns False — with nothing registered, nothing executed — only when
     the stage can't ride the mesh at all (shape/keys/partition count).
@@ -119,9 +251,8 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     from blaze_tpu.ops.shuffle import ShuffleWriterExec, read_shuffle_partition
     from blaze_tpu.plan import decode_plan
     from blaze_tpu.plan.from_proto import _partitioning
-    from blaze_tpu.runtime import faults, jit_cache
+    from blaze_tpu.runtime import faults
     from blaze_tpu.runtime.executor import execute_stage_or_plan
-    from blaze_tpu.runtime.memory import batch_nbytes, get_manager
 
     writer = stage_plan.shuffle_writer
     Pn = writer.partitioning.num_partitions
@@ -147,18 +278,14 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     mesh_devs = devices[:use_d]
     mesh = Mesh(np.array(mesh_devs), ("p",)) if use_d > 1 else None
     # (batch, live rows) per partition: the exchange hands the counts over
-    recv_parts: List[List[tuple]] = [[] for _ in range(Pn)]
+    kept = KeptPartitions(schema, Pn, use_d, kpd)
+    keep, pinned = kept.keep, kept.pinned
     file_outputs: List[tuple] = []
     # Exchanged partitions stay PINNED in HBM until the consuming stage
     # finishes, so the mesh path honors the memory budget, chip by chip:
     # once the bytes pinned on any chip pass half of a chip's budget, what
     # is left takes the file path (the reduce side reads both alike).
     budget = get_manager().total // 2
-    pinned = [0] * use_d
-
-    def keep(p: int, b: ColumnBatch, nrows: int) -> None:
-        recv_parts[p].append((b, nrows))
-        pinned[p // kpd] += batch_nbytes(b)
 
     def exchange_local(batch: ColumnBatch) -> bool:
         """Single-device exchange: group by partition id on device, slice
@@ -396,12 +523,13 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
             rounds: dict = {}
             for d, q in enumerate(queues):
                 if r < len(q):
-                    b = q[r][0]
-                    layout = (jax.tree.structure(b.columns),
-                              b.shape_key()[1:])
-                    rounds.setdefault(layout, [None] * use_d)[d] = q[r]
+                    rounds.setdefault(slice_layout(q[r][0]),
+                                      [None] * use_d)[d] = q[r]
             for shards in rounds.values():
                 send(shards)
+
+    kept.seal_all()
+    recv_parts = kept.parts
 
     def provider(partition: int):
         # defaulted extra args would miscount as task-context params in
@@ -440,12 +568,14 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                     for b, n in parts)
         total += sum(os.path.getsize(d) for d, _ in file_outputs)
         stats["bytes"] = int(total)
-        # nothing is released inside a stage, so the fullest chip's pinned
-        # bytes at its end are the stage's high-water
-        stats["pinned_bytes"] = max(pinned)
+        # the most a chip held at a time, a sealed group's slices and its
+        # packed batch both counted while both were alive
+        stats["pinned_bytes"] = kept.high_water
         stats["slices"] = sum(len(parts) for parts in recv_parts)
         stats["slice_rows"] = sum(n for parts in recv_parts
                                   for _, n in parts)
+        stats["slices_cut"] = kept.cut
+        stats["slices_packed"] = kept.packed
         stats["file_batches"] = len(file_outputs)
     resources.put(f"{namespace}shuffle:{stage_id}", provider)
     return True

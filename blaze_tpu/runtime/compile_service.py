@@ -73,6 +73,7 @@ _COUNTERS = (
     "seg_sums", "seg_int_sums",
     "filter_masks_carried", "filter_compactions",
     "exchange_slices_kept", "exchange_rows_kept",
+    "exchange_slices_cut", "exchange_slices_packed",
     "slice_copies", "slice_gathers",
 )
 for _c in _COUNTERS:
@@ -140,13 +141,18 @@ def note_filter_batches(carried: int = 0, compacted: int = 0) -> None:
     TELEMETRY.add("filter_compactions", compacted)
 
 
-def note_exchange_kept(slices: int, rows: int) -> None:
+def note_exchange_kept(slices: int, rows: int, cut: int,
+                       packed: int) -> None:
     """A shuffle_map stage's in-HBM exchange (parallel/stage_exchange, local
-    and mesh transports alike) kept `slices` non-empty per-partition slices
-    holding `rows` live rows for its reduce tasks: rows / slices is what one
-    program of a reduce task is handed."""
+    and mesh transports alike) kept `slices` batches holding `rows` live
+    rows for its reduce tasks: rows / slices is what one program of a reduce
+    task is handed. It had cut `cut` non-empty per-partition slices, and
+    `packed` of them went into a batch packed of several; the others were
+    kept as they were cut."""
     TELEMETRY.add("exchange_slices_kept", slices)
     TELEMETRY.add("exchange_rows_kept", rows)
+    TELEMETRY.add("exchange_slices_cut", cut)
+    TELEMETRY.add("exchange_slices_packed", packed)
 
 
 def note_slice(copies: bool) -> None:

@@ -428,7 +428,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                                 # file stage too
                                 run_info["file_stages"] += 1
                             compile_service.note_exchange_kept(
-                                stats["slices"], stats["slice_rows"])
+                                stats["slices"], stats["slice_rows"],
+                                stats["slices_cut"], stats["slices_packed"])
                             sp.set(transport="mesh",
                                    bytes=stats.get("bytes", 0),
                                    pinned_bytes=stats["pinned_bytes"],

@@ -164,3 +164,47 @@ def test_traced_every_map_stage_records_waits_and_the_readers_read_them(
            "profiled": []}
     assert [_load(f"metrics/{r}.py").read(old)
             for r in WAIT_READERS] == [None] * 4
+
+
+SORT_MERGE = ("sf10_q03_nobhj", "sf1_q03_nobhj")
+PACK_COUNTERS = ("exchange_slices_cut", "exchange_slices_packed",
+                 "exchange_slices_kept")
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP))
+def test_only_the_sort_merge_plans_pack_what_their_joins_are_handed(
+        cells, one_chip, name):
+    """A join stage's tasks each hand on a batch, and the next exchange
+    cuts every one into a slice a partition: the task that reads a
+    partition is handed them as one batch and probes once. Where every
+    exchange carries one batch (the broadcast and the aggregate plans) a
+    partition's one slice is left as it lies and no pack program runs."""
+    width = _json(BENCH, "configs", ONE_CHIP[name]["config"] + ".json")[
+        "settings"]["exchange_width"]
+    saved = conf.trace_enabled
+    trace.reset()
+    conf.trace_enabled = True
+    before = compile_service.TELEMETRY.snapshot()
+    try:
+        assert cells(name)() == (None, [])
+        spans = [r for r in trace.TRACE.snapshot() if r["type"] == "span"]
+    finally:
+        conf.trace_enabled = saved
+        trace.reset()
+    after = compile_service.TELEMETRY.snapshot()
+    cut, packed, kept = (after[k] - before.get(k, 0) for k in PACK_COUNTERS)
+    programs = [(s["attrs"]["program"], s.get("stage_id")) for s in spans
+                if s["kind"] == "dispatch"]
+    packs = [stage for program, stage in programs
+             if program == "exchange_pack"]
+    if name not in SORT_MERGE:
+        assert not packs and packed == 0 and kept == cut > 0
+        return
+    # the two join stages (2 and 4) exchange their tasks' outputs: the
+    # date join's, a pack a partition; the item join's few groups (three
+    # at these rows) where a partition got more than one
+    assert packs.count(2) == width and set(packs) <= {2, 4}
+    assert packed >= width * width and kept == cut - packed + len(packs)
+    # the item join's tasks probe once each, not once a date-join task
+    probes = [stage for program, stage in programs if program == "join_match"]
+    assert probes.count(4) == width < width * width

@@ -2,8 +2,9 @@
 published q3 with both joins as exchange + sort-merge over a fact table that
 arrives in many macro-batches (SF10's fourteen; here 50,000 rows cut into
 2,048-row batches), on one device. The answer is the reference's and the
-broadcast arm's; the exchange says what it kept for its reduce tasks
-(TELEMETRY exchange_slices_kept / exchange_rows_kept) and pinned (the
+broadcast arm's; the exchange says what it cut, packed and kept for its
+reduce tasks (TELEMETRY exchange_slices_cut / exchange_slices_packed /
+exchange_slices_kept / exchange_rows_kept) and pinned (the
 `stage` span's pinned_bytes); and a stage pushed past its memory
 budget goes through files, which the benchmark's evidence refuses: the
 configuration's guarantee is checked, not assumed."""
@@ -23,7 +24,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
 ROWS, BATCH_ROWS, SEED, WIDTH = 50_000, 2_048, 11, 4
 PARAMS = {"month": 11, "manufact": 128}
-COUNTERS = ("exchange_slices_kept", "exchange_rows_kept")
+COUNTERS = ("exchange_slices_kept", "exchange_rows_kept",
+            "exchange_slices_cut", "exchange_slices_packed")
 
 
 def _load(rel: str):
@@ -156,8 +158,22 @@ def test_the_counters_say_what_each_exchange_kept(deployment, served):
     # empty; a later batch is kept as one to WIDTH non-empty slices
     batches = [len(exchanges) for _, exchanges in stages]
     assert batches[0] == -(-ROWS // BATCH_ROWS) >= 13
-    assert (WIDTH * batches[0] + sum(batches[1:])
-            <= served["kept"]["exchange_slices_kept"] <= WIDTH * sum(batches))
+    cut = served["kept"]["exchange_slices_cut"]
+    assert (WIDTH * batches[0] + sum(batches[1:]) <= cut
+            <= WIDTH * sum(batches))
+    # a partition's slices are packed up to the rows a scan hands on (here
+    # BATCH_ROWS): a group holds no more, and two groups in a row hold more
+    # between them, so a stage of r rows leaves a partition's share of
+    # r / BATCH_ROWS to 2 r / BATCH_ROWS + WIDTH batches
+    kept = served["kept"]["exchange_slices_kept"]
+    assert (sum(-(-r // BATCH_ROWS) for r in rows) <= kept
+            <= sum(2 * r // BATCH_ROWS + WIDTH for r in rows) < cut)
+    # the fact table's hundred slices went into packed batches, but for a
+    # partition's odd one out at the end; a kept batch is a slice left as
+    # it was cut or a batch packed of two or more
+    packed = served["kept"]["exchange_slices_packed"]
+    assert WIDTH * (batches[0] - 1) <= packed <= cut
+    assert packed >= 2 * (kept - (cut - packed)) > 0
 
 
 def test_a_stage_pins_at_least_the_live_bytes_it_kept(served):
@@ -191,5 +207,10 @@ def test_past_the_budget_the_stage_takes_files_and_is_refused(
                for r in deployment["refusals"](forced["info"]))
     # the first fact batch was kept, the others were not
     assert 0 < forced["kept"]["exchange_rows_kept"] < ROWS
+    assert (forced["kept"]["exchange_slices_cut"]
+            < served["kept"]["exchange_slices_cut"] - WIDTH * 12)
     assert (forced["kept"]["exchange_slices_kept"]
-            < served["kept"]["exchange_slices_kept"] - WIDTH * 12)
+            < served["kept"]["exchange_slices_kept"])
+    fact = _map_stages(forced["spans"])[0][1]
+    assert [x["attrs"]["transport"] for x in fact] == (
+        ["local"] + ["file"] * (len(fact) - 1))

@@ -250,21 +250,25 @@ def test_exchange_local_keeps_each_partitions_rows_in_sorted_order(
         monkeypatch):
     """One chip: a batch is sorted by partition id (a stable sort: a
     partition's rows keep the batch's order) and cut at the bounds, one
-    slice a non-empty partition a batch, in the order the batches came."""
+    slice a non-empty partition a batch, in the order the batches came;
+    what a partition is handed is those slices' rows in that order, in
+    fewer batches where slices of one layout were packed (the first batch's
+    strings are narrower than the third's)."""
     sizes = [700, 64, 1500]
     batches, pids, got, stats, (copies, gathers) = _exchange(
         monkeypatch, 1, sizes, 935)
-    assert stats["slices"] == copies and gathers == 0
+    assert stats["slices_cut"] == copies == 12 and gathers == 0
+    assert stats["slices"] < 12 and stats["slices_packed"] > 0
     assert stats["slice_rows"] == sum(sizes)
     for p in range(4):
-        want = []
+        want = {name: [] for name in ("k", "v", "s")}
         for b, pid in zip(batches, pids):
-            rows = np.flatnonzero(pid == p)
-            if len(rows):
-                full = _live(b)
-                want.append({name: [vals[r] for r in rows]
-                             for name, vals in full.items()})
-        assert got[p] == want
+            full = _live(b)
+            for name, vals in full.items():
+                want[name] += [vals[r] for r in np.flatnonzero(pid == p)]
+        have = {name: [v for piece in got[p] for v in piece[name]]
+                for name in want}
+        assert have == want
 
 
 def test_deal_out_and_the_chips_cuts_keep_every_row(monkeypatch):
@@ -276,7 +280,8 @@ def test_deal_out_and_the_chips_cuts_keep_every_row(monkeypatch):
     batches, pids, got, stats, (copies, gathers) = _exchange(
         monkeypatch, 4, sizes, 936)
     assert stats["devices"] == 4 and gathers == 0
-    assert copies == 4 * len(sizes) + stats["slices"]
+    assert copies == 4 * len(sizes) + stats["slices_cut"]
+    assert stats["slices"] <= stats["slices_cut"]
     for p in range(4):
         # `v` is the row's number, unique and never null
         want = sorted(

@@ -172,3 +172,55 @@ def test_slice_batch_compiles_for_v5e_to_copies(one_chip, monkeypatch):
     text = lowered.compile().as_text()
     assert " gather(" not in text and " scatter(" not in text
     assert " dynamic-slice(" in text
+
+
+@pytest.mark.parametrize("caps, out", [
+    # a partition's fact slices of q3 at SF10, four to a 2^21 batch
+    ([1 << 20, 1 << 19, 1 << 20, 1 << 19], 1 << 21),
+    # sixteen date-join outputs' slices, one 2^20 probe of the item join
+    ([1 << 16] * 16, 1 << 20),
+])
+def test_pack_slices_compiles_for_v5e_to_copies(one_chip, monkeypatch, caps,
+                                                out):
+    """A reduce partition's kept slices packed into one batch (three
+    nullable 8-byte columns, offsets traced), as the chip's compiler leaves
+    `stage_exchange.pack_slices`' program: dynamic update slices of whole
+    planes; no gather and no scatter (`concat_batches` gathers every plane
+    of the concatenated capacities by a computed index: 16-34 ms a 2^21
+    plane on the chip, PERF.md)."""
+    import numpy as np
+
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column, ColumnBatch
+    from blaze_tpu.parallel.stage_exchange import pack_slices
+    from blaze_tpu.runtime import jit_cache
+
+    def arg(dtype, shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    schema = T.Schema([T.Field("ss_sold_date_sk", T.INT64),
+                       T.Field("ss_item_sk", T.INT64),
+                       T.Field("ss_ext_sales_price", T.FLOAT64)])
+
+    def batch(cap):
+        return ColumnBatch(schema, [
+            Column(f.dtype, arg(f.dtype.np_dtype(), (cap,)),
+                   arg(jnp.bool_, (cap,))) for f in schema.fields],
+            arg(jnp.int32, ()), cap)
+
+    slices = [(batch(cap), cap * 5 // 8) for cap in caps]
+    made = {}
+
+    def capture(key, make):
+        made[key[:2]] = make()
+        return lambda starts, *bs: starts
+
+    monkeypatch.setattr(jit_cache, "get_or_compile", capture)
+    starts = pack_slices(slices, schema)
+    assert starts.dtype == np.int32 and starts[-1] == sum(caps) * 5 // 8
+    lowered = jax.jit(made["exchange_pack", out]).lower(
+        arg(jnp.int32, starts.shape), *[b for b, _ in slices])
+    assert lowered.out_info.capacity == out
+    text = lowered.compile().as_text()
+    assert " gather(" not in text and " scatter(" not in text
+    assert " dynamic-update-slice(" in text
