@@ -507,30 +507,50 @@ class ColumnBatch:
         """Filter: keep rows where `keep & row_mask`, compacted to the front.
 
         Static-shape: output capacity equals input capacity (a later
-        coalesce can re-bucket downward). Flag and integer planes are
-        scattered to their rows' ranks (`rows_to_ranks`), every other kind
-        of column is gathered at the kept rows' positions; slots past the
-        kept rows hold zeros and nulls in the first case, row 0 in the
-        second, and are nobody's to read.
+        coalesce can re-bucket downward). The kept rows go to their ranks
+        by `place_rows`.
         """
         mask = keep & self.row_mask()
         n = jnp.sum(mask, dtype=jnp.int32)
         # a kept row's rank among the kept; a dropped row aims past the end
         dest = jnp.where(mask, jnp.cumsum(mask, dtype=jnp.int32) - 1,
                          self.capacity)
+        return self.place_rows(dest, n)
+
+    def place_rows(self, dest: Array, num_rows,
+                   tally: Optional[dict] = None) -> "ColumnBatch":
+        """Row i of every column in slot `dest[i]`, holding `num_rows`
+        live rows: a filter's compaction when `dest` is a kept row's rank
+        among the kept, the exchange's grouping by partition when it is a
+        row's partition's start plus its rank there. Rows aimed past the
+        end drop; no two rows may share a slot.
+
+        A column of flags or 32/64-bit integers is scattered plane by plane
+        (`rows_to_ranks`); any other is gathered whole at the row that
+        lands in each slot, found by one scatter of the row numbers. Slots
+        no row reaches hold zeros and nulls in the first case, row 0 in the
+        second, and are nobody's to read. `tally`, where given, gains each
+        column's planes (its data, and its validity if any) under "ranked"
+        or "gathered": what the program moved each way."""
         idx, cols = None, []
         for c in self.columns:
-            if _ranks_well(c.data):
+            ranked = _ranks_well(c.data)
+            if ranked:
                 v = c.validity
                 cols.append(Column(c.dtype, rows_to_ranks(c.data, dest),
                                    None if v is None
                                    else rows_to_ranks(v, dest)))
-                continue
-            if idx is None:   # nonzero_i32(mask, capacity), off `dest`
-                idx = rows_to_ranks(
-                    jnp.arange(self.capacity, dtype=jnp.int32), dest)
-            cols.append(c.take(idx))
-        return ColumnBatch(self.schema, cols, n, self.capacity)
+            else:
+                if idx is None:   # the row that lands in each slot
+                    idx = rows_to_ranks(
+                        jnp.arange(self.capacity, dtype=jnp.int32), dest)
+                cols.append(c.take(idx))
+            if tally is not None:
+                form = "ranked" if ranked else "gathered"
+                tally[form] = (tally.get(form, 0)
+                               + (1 if c.validity is None else 2))
+        return ColumnBatch(self.schema, cols,
+                           jnp.asarray(num_rows, jnp.int32), self.capacity)
 
     def normalized(self) -> "ColumnBatch":
         return self.with_columns(self.schema, [c.normalized() for c in self.columns])

@@ -240,6 +240,14 @@ def _restarting_sum(v: Array, starts: Array) -> Array:
     return out.T.reshape(n + pad)[:n]
 
 
+def running_count(flags: Array) -> Array:
+    """Inclusive running count of the True entries of `flags` (int32): the
+    blocked scan of `_restarting_sum` with no restart, where an int32
+    `cumsum` of 2^21 rows costs ~12 s of compile on this chip."""
+    return _restarting_sum(flags.astype(jnp.int32),
+                           jnp.zeros(flags.shape, jnp.bool_))
+
+
 def _at_group_rows(x: Array, idx: Array, layout: GroupLayout) -> Array:
     """`x` at row `idx[g]` in slot g, exactly 0 past `num_groups`: with
     `layout.end_idx` a run's last row (`seg_sum`), with `layout.start_idx`

@@ -37,6 +37,7 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
   canonicalization_waste_rows / stage_attempts / stage_compiled /
   agg_pallas_traces / agg_xla_traces / seg_scan_reductions /
   seg_scatter_reductions / filter_masks_carried / filter_compactions /
+  exchange_planes_ranked / exchange_planes_gathered /
   slice_copies / slice_gathers and
   the derived whole_stage_coverage_pct,
   exported as an extra `MetricNode` child by `executor.metric_tree` and
@@ -74,6 +75,7 @@ _COUNTERS = (
     "filter_masks_carried", "filter_compactions",
     "exchange_slices_kept", "exchange_rows_kept",
     "exchange_slices_cut", "exchange_slices_packed",
+    "exchange_planes_ranked", "exchange_planes_gathered",
     "slice_copies", "slice_gathers",
 )
 for _c in _COUNTERS:
@@ -153,6 +155,15 @@ def note_exchange_kept(slices: int, rows: int, cut: int,
     TELEMETRY.add("exchange_rows_kept", rows)
     TELEMETRY.add("exchange_slices_cut", cut)
     TELEMETRY.add("exchange_slices_packed", packed)
+
+
+def note_exchange_planes(ranked: int = 0, gathered: int = 0) -> None:
+    """The one-chip exchange dispatched one `local_xchg` program, which
+    moved a batch's planes (each column's data and validity) into
+    partition order: `ranked` by scatters to the rows' ranks, `gathered`
+    by a gather (parallel/stage_exchange.group_by_partition)."""
+    TELEMETRY.add("exchange_planes_ranked", ranked)
+    TELEMETRY.add("exchange_planes_gathered", gathered)
 
 
 def note_slice(copies: bool) -> None:

@@ -248,8 +248,9 @@ def _exchange(monkeypatch, ndev, sizes, stage_id):
 
 def test_exchange_local_keeps_each_partitions_rows_in_sorted_order(
         monkeypatch):
-    """One chip: a batch is sorted by partition id (a stable sort: a
-    partition's rows keep the batch's order) and cut at the bounds, one
+    """One chip: a batch is grouped by partition id, each row scattered to
+    its partition's start plus its rank among that partition's rows (so a
+    partition's rows keep the batch's order), and cut at the bounds, one
     slice a non-empty partition a batch, in the order the batches came;
     what a partition is handed is those slices' rows in that order, in
     fewer batches where slices of one layout were packed (the first batch's

@@ -224,3 +224,42 @@ def test_pack_slices_compiles_for_v5e_to_copies(one_chip, monkeypatch, caps,
     text = lowered.compile().as_text()
     assert " gather(" not in text and " scatter(" not in text
     assert " dynamic-update-slice(" in text
+
+
+def test_the_exchanges_grouping_compiles_for_v5e_without_a_sort(one_chip):
+    """q3's fact batch grouped by partition on one chip (three nullable
+    8-byte columns, 2^21 slots, murmur3 over the date key, four
+    partitions), as the chip's compiler leaves `local_xchg`'s program
+    (`stage_exchange.group_by_partition`): the ranks by blocked scans and
+    no sort of the program's own (the compiler sorts the indices of a
+    scatter of flags, and only those); every scatter of one operand; a
+    gather for the double."""
+    from blaze_tpu.columnar import types as T
+    from blaze_tpu.columnar.batch import Column, ColumnBatch
+    from blaze_tpu.parallel.shuffle import partition_ids
+    from blaze_tpu.parallel.stage_exchange import group_by_partition
+
+    cap = 1 << 21
+
+    def arg(dtype, shape=(cap,)):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    schema = T.Schema([T.Field("ss_sold_date_sk", T.INT64),
+                       T.Field("ss_item_sk", T.INT64),
+                       T.Field("ss_ext_sales_price", T.FLOAT64)])
+    batch = ColumnBatch(schema, [
+        Column(f.dtype, arg(f.dtype.np_dtype()), arg(jnp.bool_))
+        for f in schema.fields], arg(jnp.int32, ()), cap)
+
+    def grouped(b):
+        return group_by_partition(b, partition_ids(b, [0], 4), 4)
+
+    text = jax.jit(grouped).lower(batch).compile().as_text()
+    assert " while(" in text
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert all('/scatter"' in line for line in sorts), sorts[:1]
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert scatters and all(
+        line.split(" scatter(")[0].count("[2097152]") == 1
+        for line in scatters), scatters[:2]
+    assert text.count(" gather(") >= 1
