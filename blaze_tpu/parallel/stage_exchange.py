@@ -235,6 +235,90 @@ class KeptPartitions:
             self.seal(p)
 
 
+def _file_segments(files: List[tuple], partition: int, schema: Schema):
+    """Partition `partition`'s rows of the batches an exchange sent through
+    files, each (data, index) pair's segment in turn."""
+    from blaze_tpu.ops.host_sort import host_supported
+    from blaze_tpu.ops.shuffle import (
+        read_shuffle_partition, read_shuffle_partition_host,
+    )
+
+    read = (read_shuffle_partition_host if host_supported(schema)
+            else read_shuffle_partition)
+    for data, index in files:
+        yield from read(data, index, partition, schema)
+
+
+class GroupedBatches:
+    """What a one-chip in-HBM exchange of an adaptive plan (spark/aqe.py)
+    holds for its reduce side until the stage that reads it is planned:
+    each batch grouped by partition with its bounds, which the exchange
+    pulled already, pinned as it came; and each partition's live bytes, the
+    statistic the coalescing rule reads.
+
+    The reduce tasks read ranges of partitions, and a range's partitions lie
+    side by side in every grouped batch: `take` cuts one slice per (grouped
+    batch, range), packs a range's slices as `KeptPartitions` packs a
+    partition's, and drops each grouped batch once it is cut. Read by
+    partition (a stage whose reads were not coalesced) it cuts the one
+    partition each call, and serves the rows that went through files after
+    the grouped ones."""
+
+    def __init__(self, schema: Schema, partitions: int):
+        self.schema = schema
+        self.batches: List[tuple] = []      # (grouped batch, bounds)
+        self.files: List[tuple] = []        # (data, index) past the budget
+        self.pinned = [0]
+        self.high_water = 0
+        self.partition_bytes = np.zeros(partitions, np.int64)
+
+    def add(self, batch: ColumnBatch, bounds: np.ndarray) -> None:
+        nbytes = batch_nbytes(batch)
+        self.batches.append((batch, bounds))
+        self.pinned[0] += nbytes
+        self.high_water = max(self.high_water, self.pinned[0])
+        # live bytes as live_nbytes reckons them
+        self.partition_bytes += (nbytes * np.diff(bounds).astype(np.int64)
+                                 // max(batch.capacity, 1))
+
+    def sizes(self) -> Optional[np.ndarray]:
+        """Each partition's live bytes; None where rows went through files,
+        whose bytes are not of the same measure."""
+        return None if self.files else self.partition_bytes
+
+    def _cut(self, ranges: List[tuple], drop: bool) -> List[List[tuple]]:
+        from blaze_tpu.ops.common import slice_batch
+
+        if self.batches is None:
+            raise RuntimeError("an exchange read by ranges is read once")
+        kept = KeptPartitions(self.schema, len(ranges), 1, len(ranges))
+        for i, (batch, bounds) in enumerate(self.batches):
+            for r, (s, e) in enumerate(ranges):
+                n = int(bounds[e]) - int(bounds[s])
+                if n:
+                    kept.keep(r, slice_batch(batch, int(bounds[s]), n), n)
+            if drop:    # the slices are copies: free the batch as it is cut
+                self.batches[i] = None
+        if drop:
+            self.batches = None
+        kept.seal_all()
+        compile_service.note_exchange_kept(
+            sum(len(part) for part in kept.parts),
+            sum(n for part in kept.parts for _, n in part),
+            kept.cut, kept.packed)
+        return kept.parts
+
+    def take(self, ranges: List[tuple]) -> List[List[ColumnBatch]]:
+        """The batches a task reading range r = [start, end) of partitions
+        is handed, for each range; once. No row went through files."""
+        return [[b for b, _ in part] for part in self._cut(ranges, True)]
+
+    def __call__(self, partition: int):
+        for b, _ in self._cut([(partition, partition + 1)], False)[0]:
+            yield b
+        yield from _file_segments(self.files, partition, self.schema)
+
+
 def mesh_key_indices(writer: pb.ShuffleWriterNode,
                      schema: Schema) -> Optional[List[int]]:
     """Key column indices for the mesh partition kernel, or None when the
@@ -261,7 +345,8 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                            work_dir: Optional[str] = None,
                            stats: Optional[dict] = None,
                            namespace: str = "", sup=None,
-                           task_devices: Optional[list] = None) -> bool:
+                           task_devices: Optional[list] = None,
+                           by_ranges: bool = False) -> bool:
     """Execute one shuffle_map stage's exchange over the device mesh.
 
     STREAMS: each map-output batch is exchanged as it is produced — the
@@ -286,7 +371,11 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
 
     What is kept for a partition is packed as it is kept
     (`KeptPartitions`): a reduce task is handed batches of up to the size a
-    scan hands on, not one sliver a map-side batch.
+    scan hands on, not one sliver a map-side batch. With `by_ranges` (an
+    adaptive plan's exchange, whose reduce tasks read ranges of partitions
+    decided when the consuming stage is planned) a one-chip exchange cuts
+    nothing: it keeps each grouped batch with its bounds (`GroupedBatches`,
+    the provider it registers), to be cut per range.
 
     `stats` receives what the stage did: `bytes` (live-row-scaled),
     `devices`, `host_bytes`, and what it holds for the reduce side:
@@ -307,7 +396,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     from blaze_tpu.config import conf
     from blaze_tpu.ops.basic import MemorySourceExec
     from blaze_tpu.ops.common import slice_batch
-    from blaze_tpu.ops.shuffle import ShuffleWriterExec, read_shuffle_partition
+    from blaze_tpu.ops.shuffle import ShuffleWriterExec
     from blaze_tpu.plan import decode_plan
     from blaze_tpu.plan.from_proto import _partitioning
     from blaze_tpu.runtime import faults
@@ -338,8 +427,11 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     mesh = Mesh(np.array(mesh_devs), ("p",)) if use_d > 1 else None
     # (batch, live rows) per partition: the exchange hands the counts over
     kept = KeptPartitions(schema, Pn, use_d, kpd)
-    keep, pinned = kept.keep, kept.pinned
-    file_outputs: List[tuple] = []
+    grouped = (GroupedBatches(schema, Pn) if by_ranges and mesh is None
+               else None)
+    keep = kept.keep
+    pinned = kept.pinned if grouped is None else grouped.pinned
+    file_outputs: List[tuple] = [] if grouped is None else grouped.files
     # Exchanged partitions stay PINNED in HBM until the consuming stage
     # finishes, so the mesh path honors the memory budget, chip by chip:
     # once the bytes pinned on any chip pass half of a chip's budget, what
@@ -371,10 +463,13 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
             sb, bounds = jit_cache.get_or_compile(key, make)(batch)
             compile_service.note_exchange_planes(**_PLANE_FORMS.get(key, {}))
             bounds = pull_array(bounds, "exchange.local_bounds")
-            for p in range(Pn):
-                n = int(bounds[p + 1]) - int(bounds[p])
-                if n:
-                    keep(p, slice_batch(sb, int(bounds[p]), n), n)
+            if grouped is not None:
+                grouped.add(sb, bounds)
+            else:
+                for p in range(Pn):
+                    n = int(bounds[p + 1]) - int(bounds[p])
+                    if n:
+                        keep(p, slice_batch(sb, int(bounds[p]), n), n)
             sp.set(rows=int(bounds[Pn]) - int(bounds[0]))
         return True
 
@@ -593,9 +688,6 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
     def provider(partition: int):
         # defaulted extra args would miscount as task-context params in
         # _call_provider's arity dispatch — close over state instead
-        from blaze_tpu.ops.host_sort import host_supported
-        from blaze_tpu.ops.shuffle import read_shuffle_partition_host
-
         for b, _ in recv_parts[partition]:
             if mesh is not None:
                 # The one place an exchanged batch is re-placed: a
@@ -611,13 +703,7 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
                                 host_bytes=0):
                     b = placement.put(b, here)
             yield b
-        for data, index in file_outputs:
-            if host_supported(schema):
-                yield from read_shuffle_partition_host(data, index,
-                                                       partition, schema)
-            else:
-                yield from read_shuffle_partition(data, index, partition,
-                                                  schema)
+        yield from _file_segments(file_outputs, partition, schema)
 
     if stats is not None:
         # live-row-scaled logical bytes: batch_nbytes counts the padded
@@ -626,15 +712,19 @@ def run_mesh_shuffle_stage(stage_plan: pb.PlanNode, stage_id: int,
         total = sum(live_nbytes(b, n) for parts in recv_parts
                     for b, n in parts)
         total += sum(os.path.getsize(d) for d, _ in file_outputs)
+        if grouped is not None:
+            total += int(grouped.partition_bytes.sum())
         stats["bytes"] = int(total)
         # the most a chip held at a time, a sealed group's slices and its
         # packed batch both counted while both were alive
-        stats["pinned_bytes"] = kept.high_water
+        stats["pinned_bytes"] = (kept.high_water if grouped is None
+                                 else grouped.high_water)
         stats["slices"] = sum(len(parts) for parts in recv_parts)
         stats["slice_rows"] = sum(n for parts in recv_parts
                                   for _, n in parts)
         stats["slices_cut"] = kept.cut
         stats["slices_packed"] = kept.packed
         stats["file_batches"] = len(file_outputs)
-    resources.put(f"{namespace}shuffle:{stage_id}", provider)
+    resources.put(f"{namespace}shuffle:{stage_id}",
+                  provider if grouped is None else grouped)
     return True

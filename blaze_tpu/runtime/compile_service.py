@@ -38,7 +38,7 @@ SystemML's dedicated fusion-plan layer in PAPERS.md):
   agg_pallas_traces / agg_xla_traces / seg_scan_reductions /
   seg_scatter_reductions / filter_masks_carried / filter_compactions /
   exchange_planes_ranked / exchange_planes_gathered /
-  slice_copies / slice_gathers and
+  slice_copies / slice_gathers / aqe_reduce_tasks and
   the derived whole_stage_coverage_pct,
   exported as an extra `MetricNode` child by `executor.metric_tree` and
   as a summary line by `tracing.metric_report`.
@@ -77,6 +77,7 @@ _COUNTERS = (
     "exchange_slices_cut", "exchange_slices_packed",
     "exchange_planes_ranked", "exchange_planes_gathered",
     "slice_copies", "slice_gathers",
+    "aqe_reduce_tasks",
 )
 for _c in _COUNTERS:
     TELEMETRY.values[_c] = 0
@@ -164,6 +165,12 @@ def note_exchange_planes(ranked: int = 0, gathered: int = 0) -> None:
     by a gather (parallel/stage_exchange.group_by_partition)."""
     TELEMETRY.add("exchange_planes_ranked", ranked)
     TELEMETRY.add("exchange_planes_gathered", gathered)
+
+
+def note_aqe_tasks(tasks: int) -> None:
+    """A stage of an adaptive plan launches `tasks` reduce tasks, each over
+    a coalesced range of shuffle partitions (spark/aqe.py)."""
+    TELEMETRY.add("aqe_reduce_tasks", tasks)
 
 
 def note_slice(copies: bool) -> None:

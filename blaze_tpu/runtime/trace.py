@@ -306,6 +306,10 @@ EVENT_KINDS = (
 )
 
 SPAN_KINDS = (
+    "aqe",           # spark/aqe.read_ranges: one per stage of an adaptive
+                     # plan whose reads by partition were coalesced, the
+                     # ranges cut and packed (partitions, tasks, bytes,
+                     # target_bytes, shuffles); reduce_tasks_per_query
     "collect",       # local_runner._run_result_stage: tasks done -> result
                      # batch (pulls, host sort, merge, re-upload); collect_s
     "d2h",           # serde.to_host / ColumnBatch.to_numpy: a device->host

@@ -13,15 +13,29 @@ a completed shuffle with total bytes <= `conf.aqe_broadcast_threshold`
 is replaced by a `broadcast_join` building from the small side — the
 already-shuffled data is reused by reading ALL partitions of that shuffle
 on every task (Spark's local-shuffle-reader + broadcast conversion).
+
+A plan whose root is `plan_model.adaptive` (Spark's AdaptiveSparkPlanExec
+with the session's AQE settings) also coalesces shuffle partitions, as
+Spark's CoalesceShufflePartitions does: before each stage that reads
+shuffles by partition, contiguous partitions are read together by one
+task up to the advisory size (`coalesce_partitions`, Spark 3.0's
+ShufflePartitionsUtil rule), the same ranges for every shuffle the stage
+reads by partition, so co-partitioning holds. On one chip only: the
+statistic is the in-HBM exchange's own (parallel/stage_exchange
+.GroupedBatches), and a range across owner chips is not cut.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import dataclasses
+import re
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from blaze_tpu.config import conf
 from blaze_tpu.plan import plan_pb2 as pb
-from blaze_tpu.runtime import resources
+from blaze_tpu.runtime import compile_service, resources, trace
 
 
 def _reader_shuffle_sid(node: pb.PlanNode) -> Optional[Tuple[int, str]]:
@@ -44,10 +58,18 @@ def _reader_shuffle_sid(node: pb.PlanNode) -> Optional[Tuple[int, str]]:
 
 def _all_partitions_resource(rid: str, nparts: int) -> str:
     """Register (once) a provider that chains every partition of a shuffle
-    — the broadcast build side needs the WHOLE relation on each task."""
+    — the broadcast build side needs the WHOLE relation on each task. An
+    adaptive plan's exchange is cut and packed once, as one range, and the
+    batches handed whole to every task."""
+    from blaze_tpu.parallel.stage_exchange import GroupedBatches
+
     all_rid = f"{rid}:all"
     if resources.try_get(all_rid) is None:
         base = resources.get(rid)
+        if isinstance(base, GroupedBatches) and base.sizes() is not None:
+            (whole,) = base.take([(0, nparts)])
+            resources.put(all_rid, lambda _partition: iter(whole))
+            return all_rid
 
         def provider(_partition: int):
             for p in range(nparts):
@@ -57,6 +79,123 @@ def _all_partitions_resource(rid: str, nparts: int) -> str:
 
         resources.put(all_rid, provider)
     return all_rid
+
+
+def _serve(parts: List[list]):
+    """A provider handing task t the batches of parts[t]; one positional
+    argument, as _call_provider counts."""
+    def provider(task: int):
+        return iter(parts[task])
+
+    return provider
+
+
+def unwrap_adaptive(root) -> Tuple[object, Optional[dict]]:
+    """(the plan under an adaptive root, its AQE settings), or (root, None)
+    for a plan without one."""
+    if root.kind != "AdaptiveSparkPlanExec":
+        return root, None
+    return root.children[0], dict(root.attrs)
+
+
+def coalesce_partitions(sizes: Sequence[Sequence[int]], advisory: int,
+                        min_partitions: int) -> Tuple[List[Tuple[int, int]],
+                                                      int]:
+    """Spark 3.0's ShufflePartitionsUtil.coalescePartitions: the ranges
+    [start, end) of partition indices one task each reads, and the target.
+
+    `sizes[j][i]` is the bytes of shuffle j's partition i. An index's size is
+    summed over the shuffles; the target is the advisory size, or less where
+    `min_partitions` ranges must come of the total (never under 16 bytes, so
+    an empty input is one range). Walking the indices in order, a range ends
+    before index i > its start when its size with i's would pass the
+    target."""
+    per_index = np.asarray(sizes, np.int64).sum(axis=0)
+    total = int(per_index.sum())
+    target = min(int(advisory), max(-(-total // int(min_partitions)), 16))
+    ranges, start, size = [], 0, 0
+    for i, n in enumerate(per_index.tolist()):
+        if i > start and size + n > target:
+            ranges.append((start, i))
+            start, size = i, n
+        else:
+            size += n
+    ranges.append((start, len(per_index)))
+    return ranges, target
+
+
+@dataclasses.dataclass
+class Coalesced:
+    """A stage's reads by partition, coalesced: one task per range of
+    partition indices, the same ranges for each shuffle in `rids`."""
+
+    ranges: List[Tuple[int, int]]
+    rids: List[str]
+    total_bytes: int
+    target_bytes: int
+
+
+def ipc_readers(node: pb.PlanNode):
+    """Every ipc_reader under `node`."""
+    which = node.WhichOneof("node")
+    if which == "ipc_reader":
+        yield node.ipc_reader
+    elif which is not None:
+        for fd, val in getattr(node, which).ListFields():
+            if (fd.message_type is not None
+                    and fd.message_type.name == "PlanNode"):
+                for child in (val if fd.is_repeated else [val]):
+                    yield from ipc_readers(child)
+
+
+def _partition_readers(node: pb.PlanNode) -> List[pb.IpcReaderNode]:
+    """The ipc_readers under `node` that read a shuffle by partition (not
+    the whole of it, as a converted broadcast join's build side does)."""
+    return [r for r in ipc_readers(node) if re.fullmatch(
+        r"shuffle:\d+", r.provider_resource_id.rsplit("/", 1)[-1])]
+
+
+def plan_coalescing(stage, settings: dict) -> Optional[Coalesced]:
+    """Coalesce `stage`'s reads by partition, after dynamic join selection:
+    the ranges by the rule over each partition index's live bytes summed
+    over the shuffles the stage reads by partition; its readers then read
+    `<rid>:ranges`, one task a range (`stage.tasks`). None, and the stage
+    as it was, where the stage reads no shuffle by partition, or one of
+    them has no statistic (not a one-chip in-HBM exchange, or rows that
+    went through files). `minPartitionNum` is defaultParallelism, one task
+    slot: 1."""
+    from blaze_tpu.parallel.stage_exchange import GroupedBatches
+
+    readers = _partition_readers(stage.plan)
+    rids = sorted({r.provider_resource_id for r in readers})
+    bases = [resources.try_get(rid) for rid in rids]
+    if not bases or not all(isinstance(b, GroupedBatches)
+                            and b.sizes() is not None for b in bases):
+        return None
+    sizes = [b.sizes() for b in bases]
+    if len({len(s) for s in sizes}) != 1:
+        return None
+    ranges, target = coalesce_partitions(
+        sizes, settings["advisory_partition_bytes"], 1)
+    for r in readers:
+        r.provider_resource_id += ":ranges"
+        r.num_partitions = len(ranges)
+    stage.tasks = len(ranges)
+    return Coalesced(ranges, rids, int(np.sum(sizes)), target)
+
+
+def read_ranges(co: Coalesced) -> None:
+    """Cut each shuffle a coalesced stage reads by partition per range,
+    pack a range's slices, and register what task t is handed as
+    `<rid>:ranges`; one `aqe` span, counted where the tasks are launched."""
+    partitions = co.ranges[-1][1]
+    with trace.span("aqe", partitions=partitions, tasks=len(co.ranges),
+                    bytes=co.total_bytes, target_bytes=co.target_bytes,
+                    shuffles=len(co.rids)):
+        for rid in co.rids:
+            resources.put(f"{rid}:ranges",
+                          _serve(resources.get(rid).take(co.ranges)))
+    compile_service.note_aqe_tasks(len(co.ranges))
 
 
 def _rewrite_reader(node: pb.PlanNode, all_rid: str) -> None:

@@ -200,6 +200,11 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                     session=None) -> ColumnBatch:
     if run_info is None:
         run_info = {}
+    from blaze_tpu.spark import aqe
+
+    # an adaptive root carries the session's AQE settings: the stages
+    # below read coalesced ranges of partitions (spark/aqe.py)
+    root, adaptive = aqe.unwrap_adaptive(root)
     run_info.setdefault("mesh_stages", 0)
     run_info.setdefault("file_stages", 0)
     run_info.setdefault("broadcast_stages", 0)
@@ -280,8 +285,6 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
         # owner chips: the tasks that read them are placed there
         resident: set = set()
 
-        from blaze_tpu.spark.aqe import apply_dynamic_join_selection
-
         # the task supervisor owns this query's worker pool, watchdog (hang
         # detection + deadlines), straggler speculation and the per-operator
         # circuit breaker (runtime/supervisor.py); disabled it degrades each
@@ -309,15 +312,23 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
         for stage in stages:
             # re-optimize THIS stage with the statistics of completed
             # shuffles before running it (ref: AQE per-stage re-entry)
-            if shuffle_bytes:
-                n = apply_dynamic_join_selection(stage.plan, shuffle_bytes,
-                                                 shuffle_parts)
-                if n:
-                    import logging
+            with trace.context(stage_id=stage.stage_id):
+                n = (aqe.apply_dynamic_join_selection(
+                    stage.plan, shuffle_bytes, shuffle_parts)
+                     if shuffle_bytes else 0)
+                coalesced = (aqe.plan_coalescing(stage, adaptive)
+                             if adaptive is not None
+                             and stage.kind != "broadcast" else None)
+            if n:
+                import logging
 
-                    logging.getLogger(__name__).info(
-                        "AQE: converted %d SMJ(s) to broadcast join "
-                        "(stage %d)", n, stage.stage_id)
+                logging.getLogger(__name__).info(
+                    "AQE: converted %d SMJ(s) to broadcast join "
+                    "(stage %d)", n, stage.stage_id)
+            if coalesced is not None and stage.source is not None:
+                # the row interpreter's rung reads what the tasks read
+                stage.source = _copy_tree_readers(
+                    stage.source, _read_forms(stage.plan).get)
             # canonical plan fingerprint (plan/fingerprint.py), computed
             # AFTER AQE re-optimization — the executed shape is the one
             # history statistics must key on. Skipped when nothing
@@ -338,6 +349,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                                    stage_kind="shuffle_map",
                                    fingerprint=fp,
                                    tasks=_input_tasks(stage, stages)) as sp:
+                    if coalesced is not None:
+                        aqe.read_ranges(coalesced)
                     if jnl is not None and fp:
                         # a crashed driver's verified stage commit for
                         # this fingerprint? reuse it — zero map tasks run
@@ -402,7 +415,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                                 work_dir=work_dir, stats=stats,
                                 namespace=ns, sup=sup,
                                 task_devices=_task_devices(
-                                    stage, ntasks, resident))
+                                    stage, ntasks, resident),
+                                by_ranges=adaptive is not None)
                         except Exception as e:  # noqa: BLE001 — classified
                             cat = faults.classify(e)
                             if cat in ("killed", "fatal", "plan"):
@@ -477,6 +491,8 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
                         trace.span("stage", stage_id=stage.stage_id,
                                    stage_kind="result",
                                    fingerprint=fp, tasks=parts) as sp:
+                    if coalesced is not None:
+                        aqe.read_ranges(coalesced)
                     out = _run_result_stage(stage, parts, sup, run_info,
                                             resident)
                     sp.set(**monitor.stage_span_attrs(
@@ -511,6 +527,7 @@ def _run_plan_inner(root: SparkPlan, num_partitions: int,
         for stage in stages:
             for key in (f"{ns}shuffle:{stage.stage_id}",
                         f"{ns}shuffle:{stage.stage_id}:all",
+                        f"{ns}shuffle:{stage.stage_id}:ranges",
                         f"{ns}broadcast:{stage.stage_id}",
                         f"{ns}broadcast_sink:{stage.stage_id}"):
                 resources.pop(key)
@@ -542,8 +559,11 @@ def _merge_fallback_root_sort(root: SparkPlan, out: ColumnBatch,
 
 def _input_tasks(stage: Stage, stages: List[Stage],
                  fallback: int = 1) -> int:
-    """Task count for a stage = its upstream shuffle partition count;
-    `fallback` when it has dependencies but none are shuffles (scans -> 1)."""
+    """Task count for a stage = its upstream shuffle partition count, or the
+    ranges an adaptive plan coalesced its reads into; `fallback` when it has
+    dependencies but none are shuffles (scans -> 1)."""
+    if stage.tasks is not None:
+        return stage.tasks
     if not stage.depends_on:
         return 1
     upstream = [stages[d].num_partitions for d in stage.depends_on
@@ -763,7 +783,10 @@ def _pool_stage_rids(stage: Stage) -> Optional[List[str]]:
                     walk(v)
             elif fd.name == "provider_resource_id":
                 local = local_resource_id(val)
-                if (local.startswith("shuffle:")
+                if local.endswith(":ranges"):
+                    # coalesced reads are cut on the driver (spark/aqe.py)
+                    servable = False
+                elif (local.startswith("shuffle:")
                         or local.startswith("broadcast:")):
                     rids.append(val)
                 else:
@@ -953,22 +976,46 @@ def _fallback_broadcast_task(stage: Stage, stages: List[Stage],
 def _copy_tree_readers_all(plan: SparkPlan, stages: List[Stage]) -> SparkPlan:
     """Copy a SparkPlan tree, pointing shuffle __IpcReaders at the
     all-partitions resource (the SparkPlan twin of
-    _rewrite_shuffle_readers_all; copies because stage.source is shared
-    with future retries)."""
+    _rewrite_shuffle_readers_all)."""
     from blaze_tpu.spark.aqe import _all_partitions_resource
 
-    attrs = dict(plan.attrs)
-    if plan.kind == "__IpcReader":
-        rid = attrs.get("resource_id", "")
+    def whole(rid: str):
         local = local_resource_id(rid)
         if local.startswith("shuffle:") and not local.endswith(":all"):
             sid = int(local.split(":")[1])
-            attrs["resource_id"] = _all_partitions_resource(
-                rid, stages[sid].num_partitions)
-            attrs["num_partitions"] = 1
+            return _all_partitions_resource(
+                rid, stages[sid].num_partitions), 1
+        return None
+
+    return _copy_tree_readers(plan, whole)
+
+
+def _copy_tree_readers(plan: SparkPlan, remap) -> SparkPlan:
+    """Copy a SparkPlan tree, each __IpcReader's (resource id, partitions)
+    replaced by `remap(resource id)` where that gives one (copies because
+    stage.source is shared with future retries)."""
+    attrs = dict(plan.attrs)
+    if plan.kind == "__IpcReader":
+        new = remap(attrs.get("resource_id", ""))
+        if new is not None:
+            attrs["resource_id"], attrs["num_partitions"] = new
     return SparkPlan(plan.kind, plan.schema,
-                     [_copy_tree_readers_all(c, stages)
-                      for c in plan.children], attrs)
+                     [_copy_tree_readers(c, remap) for c in plan.children],
+                     attrs)
+
+
+def _read_forms(node: pb.PlanNode) -> Dict[str, tuple]:
+    """shuffle resource id -> (resource id, partitions) of the readers
+    under `node` that read that shuffle whole or by coalesced ranges."""
+    from blaze_tpu.spark.aqe import ipc_readers
+
+    forms = {}
+    for r in ipc_readers(node):
+        base, _, how = r.provider_resource_id.rpartition(":")
+        if how in ("all", "ranges"):
+            forms[base] = (r.provider_resource_id,
+                           1 if how == "all" else r.num_partitions)
+    return forms
 
 
 def _rewrite_shuffle_readers_all(node: pb.PlanNode,

@@ -153,3 +153,14 @@ def generate(child: SparkPlan, generator_expr: ir.Expr, required_cols,
                       "required_cols": list(required_cols),
                       "output_names": list(output_names),
                       "pos": pos, "outer": outer})
+
+
+def adaptive(root: SparkPlan,
+             advisory_partition_bytes: int = 64 << 20) -> SparkPlan:
+    """Spark's AdaptiveSparkPlanExec over `root`, with the session's
+    spark.sql.adaptive.advisoryPartitionSizeInBytes. The runner coalesces
+    the shuffle partitions each stage reads between stages
+    (spark/aqe.py)."""
+    return SparkPlan("AdaptiveSparkPlanExec", root.schema, [root],
+                     {"advisory_partition_bytes":
+                          int(advisory_partition_bytes)})

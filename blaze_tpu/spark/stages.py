@@ -44,6 +44,9 @@ class Stage:
     # the resilience ladder re-runs through the CPU fallback interpreter
     # (spark/fallback.py) when a task exhausts every native rung
     source: Optional[SparkPlan] = None
+    # the tasks an adaptive plan coalesced this stage's reads by partition
+    # into (spark/aqe.py); None: one task per upstream partition
+    tasks: Optional[int] = None
     _op_kinds: Optional[frozenset] = dataclasses.field(
         default=None, repr=False, compare=False)
 
